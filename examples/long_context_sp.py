@@ -12,12 +12,6 @@ Try without TPUs:
     XLA_FLAGS=--xla_force_host_platform_device_count=8 JAX_PLATFORMS=cpu \
         python examples/long_context_sp.py --scheme ulysses --sep 4 --dp 2
 """
-import os
-
-if os.environ.get("JAX_PLATFORMS") == "cpu":
-    import jax
-    jax.config.update("jax_platforms", "cpu")
-
 import argparse
 
 import numpy as np

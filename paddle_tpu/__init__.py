@@ -49,7 +49,9 @@ def in_static_mode():
 
 
 def _import_submodules():
-    """Wire up subpackages lazily-but-eagerly: grown as modules land."""
+    """Bind every subpackage on the namespace. A submodule that fails to
+    import raises here: swallowing it would silently drop e.g.
+    paddle_tpu.distributed after a moved jax import."""
     import importlib
 
     mod_names = [
@@ -88,28 +90,15 @@ def _import_submodules():
     ]
     g = globals()
     for m in mod_names:
-        try:
-            g[m] = importlib.import_module(f".{m}", __name__)
-        except ImportError:
-            pass
+        g[m] = importlib.import_module(f".{m}", __name__)
 
 
 _import_submodules()
 
-# hoist frequently-used entry points when available
-try:
-    from .framework.io import load, save  # noqa: F401
-except ImportError:
-    pass
-try:
-    from .hapi.model import Model  # noqa: F401
-    from .hapi.model_summary import flops, summary  # noqa: F401
-except ImportError:
-    pass
-try:
-    from .nn.initializer._global import set_global_initializer  # noqa: F401
-except ImportError:
-    pass
+# hoist frequently-used entry points
+from .framework.io import load, save  # noqa: F401,E402
+from .hapi.model import Model  # noqa: F401,E402
+from .hapi.model_summary import flops, summary  # noqa: F401,E402
 
 
 # ---------------------------------------------------------------- misc shims

@@ -19,6 +19,7 @@ import numpy as np
 
 __all__ = ["CostModel", "comm_cost", "zero3_cost", "kernel_roofline",
            "pipeline_cost", "ps_pipeline_cost", "DEVICE_PEAKS",
+           "TARGET_DEVICE_KIND", "device_peaks",
            "HOST_OFFLOAD_BANDWIDTH_BPS"]
 
 # effective ICI bandwidth per chip for bandwidth-optimal collectives and the
@@ -28,42 +29,48 @@ __all__ = ["CostModel", "comm_cost", "zero3_cost", "kernel_roofline",
 ICI_BANDWIDTH_BPS = 9e10
 COLLECTIVE_LATENCY_S = 5e-6
 
-# per-device-kind compute/memory peaks for the kernel roofline bound
-# (ops/pallas/autotune.py): {kind_substring: (peak_flops/s, HBM bytes/s)}.
-# Rough public numbers — they only LOWER-BOUND a wall-time measurement so
-# the autotuner can reject timings that beat physics (clock noise, a
-# candidate that silently skipped work); they never rank candidates.
+# THE peaks table: {substring of the PJRT device_kind: (peak bf16 FLOP/s,
+# HBM bytes/s)}, per chip, from the vendor's published figures (v5e: Google
+# Cloud "TPU v5e" — 197 TFLOP/s bf16, 819 GB/s). bench.py's utilization,
+# jit/aot.py's roofline estimate, the pipeline pricer and the autotuner's
+# noise floor all read it. A device that is not here has no published peak:
+# device_peaks() raises, it does not assume one.
 DEVICE_PEAKS = {
-    "v5 lite": (1.97e14, 8.2e11),   # v5e: 197 TFLOP/s bf16, 819 GB/s
-    "v5e": (1.97e14, 8.2e11),
+    "v5 lite": (1.97e14, 8.19e11),   # v5e
+    "v5e": (1.97e14, 8.19e11),
     "v5p": (4.59e14, 2.77e12),
     "v4": (2.75e14, 1.2e12),
     "v6": (9.2e14, 1.6e12),
-    "cpu": (2e11, 5e10),            # host fallback: conservative
 }
-_DEFAULT_PEAKS = (1.97e14, 8.2e11)
+# the chip the planners price for when the caller names none (they run
+# ahead of time, off the chip)
+TARGET_DEVICE_KIND = "TPU v5 lite"
 
 
-def kernel_roofline(flops: float, bytes_accessed: float,
-                    device_kind: str = "cpu",
+def device_peaks(device_kind: str) -> tuple:
+    """(peak FLOP/s, HBM bytes/s) of one chip of this PJRT ``device_kind``
+    (substring match, e.g. ``"TPU v5 lite"``); ValueError if unknown."""
+    kind = (device_kind or "").lower()
+    for sub, peaks in DEVICE_PEAKS.items():
+        if sub in kind:
+            return peaks
+    raise ValueError(
+        f"no published peaks for device kind {device_kind!r} "
+        f"(cost_model.DEVICE_PEAKS knows {sorted(DEVICE_PEAKS)})")
+
+
+def kernel_roofline(flops: float, bytes_accessed: float, device_kind: str,
                     peaks: Optional[tuple] = None) -> float:
     """Roofline LOWER BOUND on one kernel execution, in seconds.
 
-    ``max(flops / peak_flops, bytes / peak_bandwidth)`` with per-device
-    peaks from :data:`DEVICE_PEAKS` (substring match on the PJRT
-    ``device_kind``, e.g. ``"TPU v5 lite"``). A measured time below this
-    bound is physically impossible — the autotune harness
-    (ops/pallas/autotune.py) rejects such measurements as noise instead
-    of persisting them as winners. ``peaks`` overrides the table.
+    ``max(flops / peak_flops, bytes / peak_bandwidth)`` with the chip's
+    peaks from :func:`device_peaks`. A measured time below this bound is
+    physically impossible — the autotune harness (ops/pallas/autotune.py)
+    rejects such measurements as noise instead of persisting them as
+    winners. ``peaks`` overrides the table.
     """
-    if peaks is None:
-        kind = (device_kind or "").lower()
-        peaks = _DEFAULT_PEAKS
-        for sub, p in DEVICE_PEAKS.items():
-            if sub in kind:
-                peaks = p
-                break
-    peak_flops, peak_bw = peaks
+    peak_flops, peak_bw = peaks if peaks is not None \
+        else device_peaks(device_kind)
     return max(float(flops) / peak_flops, float(bytes_accessed) / peak_bw)
 
 # wire bytes per fp32 gradient byte (grad_comm codecs); the blockwise
@@ -227,7 +234,7 @@ def pipeline_cost(*, pipe_degree: int, microbatches: int,
                   stash_slot_bytes: Optional[float] = None,
                   fixed_bytes: float = 0.0,
                   hbm_budget_bytes: Optional[float] = None,
-                  device_kind: str = "cpu",
+                  device_kind: str = TARGET_DEVICE_KIND,
                   peaks: Optional[tuple] = None,
                   host_bandwidth_bps: float = HOST_OFFLOAD_BANDWIDTH_BPS,
                   ) -> dict:
@@ -467,12 +474,7 @@ class CostModel:
         ext_vals = [program.externals[v]._value for v in ext_ids]
         feed_vals = [jnp.asarray(np.asarray(feed[n])) for n in feed_names]
         compiled = jax.jit(replay).lower(ext_vals, feed_vals).compile()
-        ca = compiled.cost_analysis()
-        # jax < 0.5 returns a one-element LIST of per-device dicts;
-        # newer jaxes return the dict itself
-        if isinstance(ca, (list, tuple)):
-            ca = ca[0] if ca else {}
-        ca = ca or {}
+        ca = compiled.cost_analysis() or {}
         return {
             "flops": float(ca.get("flops", 0.0)),
             "bytes_accessed": float(ca.get("bytes accessed", 0.0)),

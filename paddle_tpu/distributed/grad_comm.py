@@ -97,9 +97,7 @@ _WIRE_ITEMSIZE = {"fp32": 4, "bf16": 2, "int8": 1, "int8_block": 1,
 # largest representable magnitude of the wire format (int8 symmetric /
 # float8_e4m3fn max normal)
 _QMAX = {"int8_block": 127.0, "fp8_block": 448.0}
-# fp8 wire dtype — present from jax 0.4.x via ml_dtypes; gated so the
-# config fails loudly (not deep inside a trace) on ancient jax
-_FP8_WIRE = getattr(jnp, "float8_e4m3fn", None)
+_FP8_WIRE = jnp.float8_e4m3fn
 
 _MB = 1024 * 1024
 
@@ -145,10 +143,6 @@ class GradCommConfig:
         if codec not in CODECS:
             raise ValueError(
                 f"unknown grad_comm codec {codec!r}; one of {CODECS}")
-        if codec == "fp8_block" and _FP8_WIRE is None:
-            raise RuntimeError(
-                "fp8_block needs jax.numpy.float8_e4m3fn (jax >= 0.4 with "
-                "ml_dtypes); this jax build has no fp8 wire dtype")
         for name, v in (("comm_buffer_size", comm_buffer_size),
                         ("last_comm_buffer_size", last_comm_buffer_size)):
             try:

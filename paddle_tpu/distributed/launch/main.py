@@ -45,6 +45,19 @@ def _parse_args(argv=None):
     return parser.parse_args(argv)
 
 
+def _one_process_per_chip(n_children: int, what: str):
+    """A chip belongs to one process: every child of this launcher would
+    initialise JAX on the same local chips, and the second one fails or
+    hangs. So more than one device-using child per host is refused unless
+    the children are held to the CPU. (The launcher itself never touches
+    JAX, so it cannot count chips; it reads what the children will read.)"""
+    if n_children > 1 and os.environ.get("JAX_PLATFORMS", "").lower() != "cpu":
+        raise SystemExit(
+            f"{what}={n_children}: one process drives all local chips "
+            f"(the mesh spans jax.devices()); start ONE process per host. "
+            f"For a CPU-only multi-process run export JAX_PLATFORMS=cpu.")
+
+
 def _build_env(rank, nranks, master, endpoints, base_env=None):
     """The PADDLE_TRAINER_* env protocol (launch_utils.py get_cluster)."""
     env = dict(base_env if base_env is not None else os.environ)
@@ -145,6 +158,8 @@ def _launch_ps(args, ips):
     n_servers = int(args.server_num or 1)
     n_workers = int(args.worker_num or 1)
     n_heter = int(args.heter_worker_num or 0)
+    _one_process_per_chip(n_workers + n_heter,
+                          "--worker_num + --heter_worker_num")
     host = ips[0] if ips else "127.0.0.1"
     server_eps = [f"{host}:{_free_port()}" for _ in range(n_servers)]
     heter_eps = [f"{host}:{_free_port()}" for _ in range(n_heter)]
@@ -169,10 +184,12 @@ def _launch_ps(args, ips):
         procs.append(subprocess.Popen(cmd, env=env, stdout=lf, stderr=lf))
 
     for i, ep in enumerate(server_eps):
+        # a server holds its tables on the host: keep it off the chip
         spawn("PSERVER", i, {"PADDLE_PORT": ep.rsplit(":", 1)[1],
                              "POD_IP": host,
                              "PADDLE_PSERVER_ID": str(i),
-                             "PADDLE_TRAINER_ID": str(i)})
+                             "PADDLE_TRAINER_ID": str(i),
+                             "JAX_PLATFORMS": "cpu"})
     server_procs = procs[:]
     procs_before = len(procs)
     for i in range(n_workers):
@@ -214,6 +231,7 @@ def launch(args=None):
 
     if args.run_mode == "ps":
         return _launch_ps(args, ips)
+    _one_process_per_chip(nproc, "--nproc_per_node")
 
     if args.elastic_server:
         return _launch_elastic(args, ips[min(node_rank, len(ips) - 1)],

@@ -83,22 +83,11 @@ def axis_size(axis: str) -> int:
 
 
 def compat_shard_map(fn, mesh, in_specs, out_specs, check=False):
-    """shard_map across jax versions: the top-level `jax.shard_map` (and its
-    `check_vma` kwarg) only exists in newer jax; 0.4/0.5 spell it
-    `jax.experimental.shard_map.shard_map(check_rep=...)`. `check` maps onto
-    whichever replication-tracking kwarg the installed jax has; default off —
+    """jax.shard_map with replication tracking (`check_vma`) off by default:
     most collective-bearing bodies manage their own replication (the 1F1B
-    grad path is the exception, see pipeline.py)."""
-    try:
-        from jax import shard_map as sm
-
-        return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check)
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as sm
-
-        return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=check)
+    grad path is the exception, see pipeline/schedule.py)."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)
 
 
 def named_sharding(*spec) -> NamedSharding:
@@ -135,13 +124,7 @@ def manual_axis_names() -> set:
     """Axis names currently bound MANUALLY (inside a shard_map/pmap body):
     a sharding constraint over such an axis is invalid — the body already
     sees its per-device block — so constrain() drops them."""
-    try:
-        from jax._src import core as _core
-
-        env = _core.get_axis_env()
-        return set(getattr(env, "axis_sizes", {}) or {})
-    except Exception:
-        return set()
+    return set(jax.sharding.get_abstract_mesh().manual_axes)
 
 
 def constrain(tensor, *spec):

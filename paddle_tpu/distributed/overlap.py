@@ -518,7 +518,9 @@ def _fake_params(shapes_dtypes, seed=0):
         p = Tensor(np.zeros(shape, dt))
         p.stop_gradient = False
         p.name = f"p{i}"
-        p.grad = Tensor(rs.standard_normal(shape).astype(dt) * 1e-2)
+        # scale BEFORE the cast: bf16 * python float promotes to float32,
+        # and the communicator refuses a grad dtype != param dtype
+        p.grad = Tensor((rs.standard_normal(shape) * 1e-2).astype(dt))
         params.append(p)
     return params
 

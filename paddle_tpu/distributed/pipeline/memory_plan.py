@@ -17,45 +17,35 @@ pricer with ``pipe_degree=1, microbatches=1`` prices the UNPIPELINED step
 — how a too-big model is shown to not fit before the pipeline is brought
 in (tests/test_memory_plan.py pins both directions).
 
-Host offload is a memory-SPACE move, not an algorithm change: on TPU the
-named space is "pinned_host" (distinct from HBM — real bytes saved); on
-CPU the only space is "unpinned_host" which IS device memory, so
-``host_offload_supported()`` reports False and the planner only selects
-offload when the caller forces ``allow_offload=True`` (the CPU tests do,
-to exercise the lowering; the bytes claim is only made on TPU).
+Host offload is a memory-SPACE move, not an algorithm change: the offload
+tier is the backend's "pinned_host" space (``jax.memory.Space.Host``), and
+the planner selects it only where ``host_offload_supported()`` finds that
+space beside a distinct device memory, or the caller forces
+``allow_offload=True``.
 """
 from __future__ import annotations
 
 import dataclasses
 from typing import Optional, Tuple
 
-from ...cost_model import pipeline_cost
+from ...cost_model import TARGET_DEVICE_KIND, pipeline_cost
 
 __all__ = ["MemoryPlan", "plan_memory", "host_offload_supported",
            "gpt_activation_estimate", "plan_for_gpt"]
 
 
+OFFLOAD_KIND = "pinned_host"   # the memory space the offload tier lowers to
+
+
 def host_offload_supported() -> bool:
     """True when the backend exposes a host memory space DISTINCT from
-    device memory (TPU: "pinned_host" next to "device"). On CPU the
-    default space is already host memory, so there is nothing to offload
-    TO — the planner must not claim bytes it cannot move."""
-    try:
-        import jax
+    its default device memory ("pinned_host" next to "device") — the
+    planner must not claim bytes it cannot move."""
+    import jax
 
-        dev = jax.devices()[0]
-        kinds = {m.kind for m in dev.addressable_memories()}
-        return ("pinned_host" in kinds
-                and dev.default_memory().kind != "pinned_host")
-    except Exception:
-        return False
-
-
-def _offload_kind() -> str:
-    """The memory-space name the offload tier lowers to: the real host
-    space when one exists, else the CPU default space (an exercisable
-    no-op — see module docstring)."""
-    return "pinned_host" if host_offload_supported() else "unpinned_host"
+    dev = jax.devices()[0]
+    kinds = {m.kind for m in dev.addressable_memories()}
+    return OFFLOAD_KIND in kinds and dev.default_memory().kind != OFFLOAD_KIND
 
 
 @dataclasses.dataclass(frozen=True)
@@ -93,7 +83,7 @@ def plan_memory(*, num_layers: int, pipe_degree: int, microbatches: int,
                 layer_flops: float,
                 fixed_bytes: float = 0.0,
                 hbm_budget_bytes: Optional[float] = None,
-                device_kind: str = "cpu",
+                device_kind: str = TARGET_DEVICE_KIND,
                 allow_offload: Optional[bool] = None,
                 host_bandwidth_bps: Optional[float] = None,
                 ) -> MemoryPlan:
@@ -110,8 +100,7 @@ def plan_memory(*, num_layers: int, pipe_degree: int, microbatches: int,
     wins by construction. Returns an INFEASIBLE plan (never raises) when
     even full offload is over budget — ``reason`` carries the priced gap.
 
-    ``allow_offload`` defaults to :func:`host_offload_supported` — on CPU
-    the offload tier saves nothing, so the planner does not pretend.
+    ``allow_offload`` defaults to :func:`host_offload_supported`.
     """
     L_total = int(num_layers)
     P = int(pipe_degree)
@@ -142,7 +131,7 @@ def plan_memory(*, num_layers: int, pipe_degree: int, microbatches: int,
         return MemoryPlan(
             policies=tuple(cost["policies"]),
             stash_offload=stash_off,
-            stash_memory_kind=_offload_kind() if stash_off else None,
+            stash_memory_kind=OFFLOAD_KIND if stash_off else None,
             pipe_degree=P, microbatches=int(microbatches),
             feasible=feasible, reason=reason, cost=cost)
 
@@ -228,7 +217,7 @@ def plan_for_gpt(cfg, *, pipe_degree: int, microbatches: int,
                  hbm_budget_bytes: Optional[float] = None,
                  mesh=None, fixed_bytes: float = 0.0,
                  allow_offload: Optional[bool] = None,
-                 device_kind: str = "cpu") -> MemoryPlan:
+                 device_kind: str = TARGET_DEVICE_KIND) -> MemoryPlan:
     """``plan_memory`` over a GPTConfig: derives the per-layer byte/FLOP
     estimates from the config and the mesh's sharding degrees, with the
     micro-batch size taken from ``global_batch / microbatches`` divided by

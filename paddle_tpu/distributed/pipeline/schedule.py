@@ -46,19 +46,19 @@ from jax.sharding import PartitionSpec as P
 from .. import mesh as mesh_mod
 
 
+_MEMORY_SPACES = {"device": jax.memory.Space.Device,
+                  "pinned_host": jax.memory.Space.Host}
+
+
 def _to_memory_kind(v, kind: Optional[str]):
     """Transfer `v` to a named memory space inside the trace (no-op when
-    kind is None). The stash's host-offload tier rides this: on TPU
-    `kind="pinned_host"` keeps the S input slots out of HBM between their
-    forward write and backward read; on CPU the only space is
-    "unpinned_host" (== device memory), so the path is exercisable but
-    buys no bytes — memory_plan.host_offload_supported() tells the
-    planner which regime it is pricing."""
+    kind is None). The stash's host-offload tier rides this:
+    `kind="pinned_host"` keeps the S input slots out of device memory
+    between their forward write and backward read, `kind="device"` brings
+    one back."""
     if kind is None:
         return v
-    from jax._src.sharding_impls import TransferToMemoryKind
-
-    return jax.device_put(v, TransferToMemoryKind(kind))
+    return jax.device_put(v, _MEMORY_SPACES[kind])
 
 
 def pipeline_spmd(
@@ -247,17 +247,8 @@ def pipeline_1f1b(
     # default pmean skips them so the hook sees per-rank partial grads
     sync_set = (set(a for a in sync_axes if a in mesh_axes)
                 if grad_sync is not None else set())
-    # memory space a consumed stash slot returns to (None = no transfer;
-    # on CPU device memory IS "unpinned_host", so the emulated offload
-    # path skips the identity round trip)
-    fetch_kind = None
-    if stash_memory_kind is not None:
-        try:
-            dev_kind = jax.devices()[0].default_memory().kind
-        except Exception:
-            dev_kind = "device"
-        if dev_kind != stash_memory_kind:
-            fetch_kind = dev_kind
+    # memory space a consumed stash slot returns to (None = no transfer)
+    fetch_kind = "device" if stash_memory_kind is not None else None
 
     def body(params_in, xl, ll, *state):
         stage = jax.lax.axis_index(pipe_axis)
@@ -279,11 +270,8 @@ def pipeline_1f1b(
         #   every tick; per-rank partials reduced once after the scan ride a
         #   single collective instead.
         cast_axes = tuple(a for a in mesh.axis_names if a not in natural_axes)
-        has_vma = hasattr(jax, "typeof")  # pre-vma jax has no typing to cast
 
         def to_varying(a, axes=cast_axes):
-            if not has_vma:
-                return a
             have = set(jax.typeof(a).vma)
             need = tuple(ax for ax in axes if ax not in have)
             return jax.lax.pcast(a, need, to="varying") if need else a
@@ -395,19 +383,17 @@ def pipeline_1f1b(
         # values pipe-varying, a TP psum makes them model-replicated, the
         # sharded micro-batch data makes them batch-varying). Iterate
         # abstractly to the fixed point and pcast the zeros init up to it.
-        # (Pre-vma jax carries no such types — nothing to converge.)
-        if has_vma:
-            for _ in range(len(mesh.axis_names) + 2):
-                out_t = jax.eval_shape(lambda c: tick(c, jnp.int32(0))[0], g0)
-                tgt = jax.tree.map(lambda o: frozenset(o.vma), out_t)
-                cur = jax.tree.map(
-                    lambda a: frozenset(jax.typeof(a).vma), g0)
-                if tgt == cur:
-                    break
-                g0 = jax.tree.map(
-                    lambda a, o: to_varying(a, tuple(sorted(o))), g0, tgt)
-            else:
-                raise ValueError("1F1B carry vma types did not converge")
+        for _ in range(len(mesh.axis_names) + 2):
+            out_t = jax.eval_shape(lambda c: tick(c, jnp.int32(0))[0], g0)
+            tgt = jax.tree.map(lambda o: frozenset(o.vma), out_t)
+            cur = jax.tree.map(
+                lambda a: frozenset(jax.typeof(a).vma), g0)
+            if tgt == cur:
+                break
+            g0 = jax.tree.map(
+                lambda a, o: to_varying(a, tuple(sorted(o))), g0, tgt)
+        else:
+            raise ValueError("1F1B carry vma types did not converge")
 
         # Three specialized segments (identical math to one full scan —
         # the skipped phase is exactly the one whose work every stage
@@ -429,18 +415,11 @@ def pipeline_1f1b(
         def reduce_out(g, owned):
             """One cross-rank reduction per value: psum over pipe (only the
             owning stage produced a non-zero), pmean over every other
-            still-varying axis the value is not intentionally sharded on.
-            Without vma typing (pre-vma jax) reduce unconditionally: psum
-            over pipe is exact (non-owning stages masked their contribution
-            to zero) and pmean over an already-replicated axis is the
-            identity value-wise."""
-            def _vma(a):
-                return (set(jax.typeof(a).vma) if has_vma
-                        else set(mesh.axis_names))
-            if pipe_axis not in owned and pipe_axis in _vma(g):
+            still-varying axis the value is not intentionally sharded on."""
+            if pipe_axis not in owned and pipe_axis in jax.typeof(g).vma:
                 g = jax.lax.psum(g, pipe_axis)
             for ax in sorted(mesh_axes - owned - {pipe_axis}):
-                if int(mesh.shape[ax]) > 1 and ax in _vma(g):
+                if int(mesh.shape[ax]) > 1 and ax in jax.typeof(g).vma:
                     g = jax.lax.pmean(g, ax)
             return g
 
@@ -459,15 +438,7 @@ def pipeline_1f1b(
     # check_vma=True: with replication tracking on, the transpose of the TP
     # psum inside stage_fn is the (correct) identity pass-through — under
     # check_vma=False it would re-psum the already-replicated cotangent and
-    # double every tensor-parallel gradient. Pre-vma jax cannot express that
-    # pass-through (measured: TP grads come back exactly model_degree-fold),
-    # so TP x 1F1B is refused loudly there; pure-pipe meshes are exact.
-    if not hasattr(jax, "typeof") and int(
-            mesh.shape.get("model", 1)) > 1:
-        raise NotImplementedError(
-            "1F1B with tensor parallelism needs vma-typed shard_map "
-            "(jax >= 0.6); this jax would silently double TP gradients. "
-            "Use the GSPMD fill-drain schedule or a pure-pipe mesh.")
+    # double every tensor-parallel gradient.
     in_specs = (param_specs, x_spec, l_spec) + tuple(sync_state_specs)
     out_specs = (P(), param_specs) + tuple(sync_state_specs)
     out = mesh_mod.compat_shard_map(

@@ -102,11 +102,7 @@ def _codec_block():
 def _fp8_np_dtype():
     import jax.numpy as jnp
 
-    fp8 = getattr(jnp, "float8_e4m3fn", None)
-    if fp8 is None:
-        raise RuntimeError("fp8_block needs jnp.float8_e4m3fn "
-                           "(jax>=0.4 with ml_dtypes)")
-    return np.dtype(fp8)
+    return np.dtype(jnp.float8_e4m3fn)
 
 
 def _np_blocks(flat: np.ndarray, bs: int) -> np.ndarray:
@@ -148,11 +144,9 @@ def encode_rows(rows: np.ndarray, codec: str, block: Optional[int] = None):
     if codec == "int8_block":
         qv = np.clip(np.round(q), -127, 127).astype(np.int8)
         wire = qv
-    else:  # fp8_block: the exact fp8 values, bitcast to uint8 for the TLV.
-        # f16 intermediate on purpose: XLA lowers f32->f8E4M3FN through
-        # f16, and bit-parity with the jnp codec (the parity test) needs
-        # the same double rounding; q is <= QMAX=448, far from f16 range.
-        qv = q.astype(np.float16).astype(_fp8_np_dtype())
+    else:  # fp8_block: the exact fp8 values, bitcast to uint8 for the TLV
+        # (one rounding f32->f8E4M3FN, as XLA in jaxlib 0.9.0 does it)
+        qv = q.astype(_fp8_np_dtype())
         wire = qv.view(np.uint8)
     # Only the first ``numel`` quantized elements travel — block padding
     # dequantizes to zeros, so the receiver reconstructs it for free.
@@ -575,17 +569,15 @@ class PsTrainStep:
 
     def _register_artifact(self, key: str, fn):
         """PR-19 artifact tier: always the in-process warm map; the disk
-        tier additionally persists where jax.export exists (probed — its
-        absence is the documented degraded mode)."""
+        tier additionally persists under FLAGS_artifact_cache_dir."""
         with _step_warm_lock:
             _step_warm[key] = fn
         root = flag("FLAGS_artifact_cache_dir", "")
         if not root:
             return
-        from ...jit.artifact_cache import ArtifactCache, export_supported
+        from ...jit.artifact_cache import ArtifactCache
 
-        if export_supported():
-            ArtifactCache(root).store(key, fn)
+        ArtifactCache(root).store(key, fn)
 
     def _pure(self):
         import jax

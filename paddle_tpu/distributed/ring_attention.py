@@ -38,13 +38,9 @@ def _vary_like(inits, refs):
     pcast them up to the union of the reference operands' vma. In untracked
     regions (check_vma=False, e.g. ring_attention_val's own shard_map) every
     vma reads empty and this is a no-op."""
-    typeof = getattr(jax, "typeof", None)
-    if typeof is None:
-        # pre-vma jax (0.4/0.5): no replication typing exists to fix up
-        return inits
     target = set()
     for r in refs:
-        target |= set(typeof(r).vma)
+        target |= set(jax.typeof(r).vma)
     if not target:
         return inits
 

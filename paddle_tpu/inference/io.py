@@ -54,14 +54,7 @@ def export_inference_artifact(fn, weight_vals: Sequence, feed_specs,
     feed_specs: list of (name, shape, dtype-str).
     """
     import jax
-
-    from ..jit.artifact_cache import require_export
-
-    # jax.export is a LAZY submodule: attribute access off a bare
-    # `import jax` raises in a fresh process (the bug that made every
-    # artifact load/export look unsupported). require_export() imports
-    # it through the capability probe.
-    export = require_export()
+    from jax import export  # a lazy submodule: `jax.export.X` needs this
     w_avals = [jax.ShapeDtypeStruct(np.shape(w), np.asarray(w).dtype)
                for w in weight_vals]
     # None / -1 feed dims export as SYMBOLIC dims (shape polymorphism): the
@@ -129,11 +122,10 @@ class InferenceArtifact:
     @classmethod
     def load(cls, path_prefix: str):
         import jax.numpy as jnp
-
-        from ..jit.artifact_cache import require_export
+        from jax import export
 
         with open(path_prefix + ".pdmodel", "rb") as f:
-            exported = require_export().deserialize(bytearray(f.read()))
+            exported = export.deserialize(bytearray(f.read()))
         with open(path_prefix + ".manifest.json") as f:
             manifest = json.load(f)
         with open(path_prefix + ".pdiparams", "rb") as f:

@@ -43,8 +43,6 @@ def _worker_loop(dataset, index_q, result_q, collate_fn, worker_id,
                  num_workers, init_fn, iterable, batch_size, drop_last):
     global _worker_info
     _worker_info = WorkerInfo(worker_id, num_workers, dataset)
-    # keep workers off the accelerator: data decode is host work
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     try:
         if init_fn is not None:
             init_fn(worker_id)
@@ -91,17 +89,31 @@ class _WorkerPool:
         self.result_q = ctx.Queue()
         self.num_workers = loader.num_workers
         self.procs = []
-        for wid in range(loader.num_workers):
-            p = ctx.Process(
-                target=_worker_loop,
-                args=(loader.dataset, self.index_q, self.result_q,
-                      loader.collate_fn, wid, loader.num_workers,
-                      loader.worker_init_fn, loader._iterable_mode,
-                      getattr(loader, "batch_size", 1),
-                      getattr(loader, "drop_last", False)),
-                daemon=True)
-            p.start()
-            self.procs.append(p)
+        # Data decode is host work and the chip belongs to this process:
+        # a worker must come up with JAX held to the CPU even when
+        # JAX_PLATFORMS=tpu is exported. jax reads the variable when it is
+        # imported — in a spawned child that happens while the arguments
+        # unpickle, before _worker_loop runs — so it is ASSIGNED in the
+        # environment the children inherit, not defaulted inside them.
+        parent_platforms = os.environ.get("JAX_PLATFORMS")
+        os.environ["JAX_PLATFORMS"] = "cpu"
+        try:
+            for wid in range(loader.num_workers):
+                p = ctx.Process(
+                    target=_worker_loop,
+                    args=(loader.dataset, self.index_q, self.result_q,
+                          loader.collate_fn, wid, loader.num_workers,
+                          loader.worker_init_fn, loader._iterable_mode,
+                          getattr(loader, "batch_size", 1),
+                          getattr(loader, "drop_last", False)),
+                    daemon=True)
+                p.start()
+                self.procs.append(p)
+        finally:
+            if parent_platforms is None:
+                del os.environ["JAX_PLATFORMS"]
+            else:
+                os.environ["JAX_PLATFORMS"] = parent_platforms
         self.closed = False
 
     def shutdown(self):

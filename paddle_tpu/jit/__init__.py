@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..framework import autograd, random as rng_mod
+from ..framework.device import current_place
 from ..framework.tensor import Tensor
 from .functional import FunctionalModule, tree_to_vals, vals_to_tensors
 
@@ -399,6 +400,8 @@ class TrainStep:
             self._gc_comm = GradCommunicator(grad_comm)
         self._cache: Dict[Any, Callable] = {}
         self._slots = None
+        # True from a (re-)import of optimizer state until the next call
+        self._fresh_state = False
         self._accum = None
         self._accum_count = 0
         # newest cache entry + abstract call signature, kept so
@@ -562,7 +565,7 @@ class TrainStep:
         return (tp_sh, fp_sh, b_sh, slot_sh, gc_sh, ns(P()), ns(P()),
                 data_sh, lbl_sh), (ns(P()), tp_sh, b_sh, slot_sh)
 
-    def _build(self, key_shape):
+    def _build(self):
         fm = self.fm
         opt = self.optimizer
         loss_fn = self.loss_fn
@@ -934,7 +937,11 @@ class TrainStep:
         return jax.jit(pure_step, donate_argnums=(0, 3, 4),
                        in_shardings=in_sh, out_shardings=out_sh)
 
-    def __call__(self, inputs, labels=()):
+    def _step_args(self, inputs, labels):
+        """(jitted step, its un-placed positional arguments, the in-trace
+        grad-comm buckets or None). The ONE place the compiled step's
+        signature is assembled: __call__ places and runs these arguments,
+        jit.aot.aot_compile_step lowers the same tuple abstractly."""
         fm = self.fm
         if not isinstance(inputs, (tuple, list)):
             inputs = (inputs,)
@@ -970,9 +977,10 @@ class TrainStep:
             cur_slots = self._slots or [None] * len(train_params)
             self._slots = [_carry(p, cur)
                            for p, cur in zip(train_params, cur_slots)]
+            self._fresh_state = True
         # in-trace grad-comm carried state: the per-bucket error-feedback
         # residuals ride in and out of the jitted step as an aux pytree
-        gc_axes, gc_world = self._gc_world(self._mesh())
+        _gc_axes, gc_world = self._gc_world(self._mesh())
         gc_on = self._gc_comm is not None and gc_world > 1
         gc_res, gc_buckets = [], None
         if gc_on:
@@ -992,46 +1000,48 @@ class TrainStep:
         ckey = (_abstract_key(in_vals), _abstract_key(lbl_vals))
         if ckey not in self._cache:
             self._cache[ckey] = self._compile(
-                self._build(ckey), self._slots, in_vals, lbl_vals, gc_res
+                self._build(), self._slots, in_vals, lbl_vals, gc_res
             )
-        step = self._cache[ckey]
+        self._last_ckey = ckey
         pvals = fm.param_values()
         train_p = [v for v, m in zip(pvals, fm.trainable_mask) if m]
         frozen_p = [v for v, m in zip(pvals, fm.trainable_mask) if not m]
         lr = jnp.asarray(self.optimizer.get_lr(), jnp.float32)
         key = rng_mod.next_key()
-        bvals = fm.buffer_values()
+        args = (train_p, frozen_p, fm.buffer_values(), self._slots, gc_res,
+                key, lr, in_vals, lbl_vals)
+        return self._cache[ckey], args, gc_buckets
+
+    def __call__(self, inputs, labels=()):
+        fm = self.fm
+        step, args, gc_buckets = self._step_args(inputs, labels)
         if self._mesh() is not None:
             # place every operand on its target sharding (no-op when already
             # there); jit-with-in_shardings rejects mismatched placements
-            (tp_sh, fp_sh, b_sh, slot_sh, gc_sh, _k, _l, d_sh, l_sh), _ = \
-                self._shardings(None, self._slots, in_vals, lbl_vals,
-                                gc_res)
-            train_p = [jax.device_put(v, s) for v, s in zip(train_p, tp_sh)]
-            frozen_p = [jax.device_put(v, s) for v, s in zip(frozen_p, fp_sh)]
-            bvals = [jax.device_put(v, s) for v, s in zip(bvals, b_sh)]
-            self._slots = jax.tree_util.tree_map(
-                lambda v, s: jax.device_put(v, s), self._slots, slot_sh
-            )
-            gc_res = [jax.device_put(v, s) for v, s in zip(gc_res, gc_sh)]
-            in_vals = jax.tree_util.tree_map(
-                lambda v, s: jax.device_put(v, s), in_vals, d_sh
-            )
-            lbl_vals = jax.tree_util.tree_map(
-                lambda v, s: jax.device_put(v, s), lbl_vals, l_sh
-            )
+            slots, gc_res, in_vals, lbl_vals = (args[3], args[4], args[7],
+                                                args[8])
+            in_sh, _ = self._shardings(None, slots, in_vals, lbl_vals,
+                                       gc_res)
+            args = jax.tree_util.tree_map(jax.device_put, args, in_sh)
+        elif self._fresh_state:
+            # fresh parameters and slots are uncommitted arrays and the step
+            # hands them back committed: run on them as they are and jit
+            # compiles the same program again for the second call. Commit
+            # the carried state once, here (744 leaves for gpt-125m — not
+            # per call; key/lr/batch arrive the same way every call) — but
+            # only beside a batch that sits on the current place: a batch a
+            # caller sharded itself keeps deciding where the step runs.
+            dev = current_place().jax_device
+            if all(v.devices() == {dev}
+                   for v in jax.tree_util.tree_leaves(args[7:])):
+                args = jax.device_put(args[:5], dev) + args[5:]
+        self._fresh_state = False
         # abstract signature BEFORE the call: donated buffers (params,
         # slots) are deleted by the step, but memory_analysis() only needs
         # their shapes/dtypes
-        self._last_ckey = ckey
         self._last_abstract = jax.tree_util.tree_map(
-            lambda v: jax.ShapeDtypeStruct(v.shape, v.dtype),
-            (train_p, frozen_p, bvals, self._slots, gc_res, key, lr,
-             in_vals, lbl_vals))
-        loss, new_tp, new_b, new_slots, new_gc_res, out_vals = step(
-            train_p, frozen_p, bvals, self._slots, gc_res, key, lr,
-            in_vals, lbl_vals,
-        )
+            lambda v: jax.ShapeDtypeStruct(v.shape, v.dtype), args)
+        loss, new_tp, new_b, new_slots, new_gc_res, out_vals = step(*args)
         ti = 0
         for p, m in zip(fm.params, fm.trainable_mask):
             if m:
@@ -1039,11 +1049,11 @@ class TrainStep:
                 ti += 1
         fm.bind_buffers(new_b)
         self._slots = new_slots
-        if gc_on:
-            if len(new_gc_res):
-                for b, r in zip(gc_buckets, new_gc_res):
-                    self._gc_comm._residuals[b.index] = r
-            self._account_gc_step(gc_buckets, gc_world)
+        if gc_buckets is not None:
+            for b, r in zip(gc_buckets, new_gc_res):
+                self._gc_comm._residuals[b.index] = r
+            self._account_gc_step(gc_buckets,
+                                  self._gc_world(self._mesh())[1])
         self.optimizer._accumulated_steps += 1
         mark = getattr(self.optimizer, "_mark_slot_writer", None)
         if mark is not None:

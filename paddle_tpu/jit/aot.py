@@ -19,16 +19,13 @@ from __future__ import annotations
 import time
 from typing import Dict, Optional
 
-__all__ = ["aot_compile_step", "topology_mesh", "estimate_step_seconds"]
+from ..cost_model import TARGET_DEVICE_KIND, device_peaks
 
-# v5e per-chip peaks (shared with bench/tools MFU math — one source)
-V5E_PEAK_BF16_FLOPS = 197e12
-V5E_HBM_BYTES_PER_S = 819e9
-
+__all__ = ["aot_compile_step", "compile_for_one_chip", "topology_mesh",
+           "estimate_step_seconds"]
 
 def estimate_step_seconds(cost: Dict,
-                          peak_flops: float = V5E_PEAK_BF16_FLOPS,
-                          hbm_bw: float = V5E_HBM_BYTES_PER_S,
+                          device_kind: str = TARGET_DEVICE_KIND,
                           ) -> Optional[Dict]:
     """Best available per-device step-time estimate from a cost dict.
 
@@ -45,6 +42,7 @@ def estimate_step_seconds(cost: Dict,
     if opt_s is not None and opt_s > 0:
         return {"seconds": float(opt_s), "signal": "compiler"}
     fl, by = cost.get("flops"), cost.get("bytes_accessed")
+    peak_flops, hbm_bw = device_peaks(device_kind)
     if fl and fl > 0:
         sec = fl / peak_flops
         if by and by > 0:
@@ -73,8 +71,31 @@ def topology_mesh(name: str, shape_map: Dict[str, int]):
     return Mesh(np.asarray(topo.devices).reshape(degs), axes)
 
 
+def compile_for_one_chip(fn, *avals, topology: str = "v5e:2x2"):
+    """Compile ``fn`` at the ShapeDtypeStruct ``avals`` for ONE chip of a
+    described TPU topology — Pallas kernels go through Mosaic, not the
+    interpreter. Returns the compiled executable. The free pre-flight for
+    anything that will run on a chip: no TPU, no execution."""
+    import jax
+    import numpy as np
+    from jax.experimental import topologies
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from ..framework.target import force_target
+
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name=topology)
+    sh = NamedSharding(Mesh(np.asarray(topo.devices[:1]), ("x",)), P())
+    args = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh)
+            for a in avals]
+    # force_target: this is a raw jax mesh, not the framework's ambient
+    # mesh, so the pallas interpret gate needs the explicit pin
+    with force_target("tpu"):
+        return jax.jit(fn).lower(*args).compile()
+
+
 def compile_pallas_flash_for_tpu(shape=(8, 1024, 12, 64), block_size=512,
-                                 topology: str = "v5e:2x4",
+                                 topology: str = "v5e:2x2",
                                  grad: bool = True) -> float:
     """Compile the pallas flash-attention kernel (Mosaic, not interpret)
     for one chip of a described TPU topology; returns compile seconds.
@@ -82,34 +103,19 @@ def compile_pallas_flash_for_tpu(shape=(8, 1024, 12, 64), block_size=512,
     validation recipe can't drift."""
     import jax
     import jax.numpy as jnp
-    import numpy as np
-    from jax.experimental import topologies
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-    from ..framework.target import force_target
     from ..ops.flash_attention import flash_attention_val
 
-    topo = topologies.get_topology_desc(platform="tpu",
-                                        topology_name=topology)
-    mesh1 = Mesh(np.asarray(topo.devices[:1]).reshape(1), ("x",))
-    sh = NamedSharding(mesh1, P())
-    q = jax.ShapeDtypeStruct(tuple(shape), jnp.bfloat16, sharding=sh)
+    def fwd(a, b, c):
+        return flash_attention_val(a, b, c, block_size=block_size)
 
+    fn = fwd
     if grad:
-        fn = jax.grad(lambda a, b, c: jnp.sum(flash_attention_val(
-            a, b, c, block_size=block_size).astype(jnp.float32)),
-            argnums=(0, 1, 2))
-        jitted = jax.jit(fn, in_shardings=(sh, sh, sh))
-    else:
-        jitted = jax.jit(
-            lambda a, b, c: flash_attention_val(a, b, c,
-                                                block_size=block_size),
-            in_shardings=(sh, sh, sh), out_shardings=sh)
-    # force_target: mesh1 is a raw jax mesh, not the framework's ambient
-    # mesh, so the pallas interpret gate needs the explicit pin
-    with force_target("tpu"):
-        t0 = time.time()
-        jitted.lower(q, q, q).compile()
+        fn = jax.grad(lambda a, b, c: jnp.sum(fwd(a, b, c).astype(
+            jnp.float32)), argnums=(0, 1, 2))
+    q = jax.ShapeDtypeStruct(tuple(shape), jnp.bfloat16)
+    t0 = time.time()
+    compile_for_one_chip(fn, q, q, q, topology=topology)
     return round(time.time() - t0, 1)
 
 
@@ -119,42 +125,23 @@ def aot_compile_step(step, inputs, labels, want_cost: bool = False) -> Dict:
     in/out shardings), but with ShapeDtypeStruct arguments — nothing
     executes, so the mesh may live on a described topology.
 
-    Returns compile_seconds + XLA memory analysis (argument/output/temp/
-    alias/peak bytes, per device); with want_cost also the compiler's
-    cost analysis (optimal_seconds = estimated step time, flops).
+    Returns compile_seconds, mosaic_calls (Pallas custom calls in the
+    lowered program — 0 means every kernel fell back to its jnp path) and
+    XLA's memory analysis (argument/output/temp/alias/peak bytes, per
+    device); with want_cost also the compiler's cost analysis
+    (optimal_seconds = estimated step time, flops).
     """
     import jax
 
-    from . import tree_to_vals
-
-    fm = step.fm
-    in_vals = tree_to_vals(tuple(inputs))
-    lbl_vals = tree_to_vals(tuple(labels))
-    opt = step.optimizer
-    train_params = [p for p, m in zip(fm.params, fm.trainable_mask) if m]
-    step._slots = [opt._init_slots(p._value) for p in train_params]
-    pure = step._build(("aot",))
-    jitted = step._compile(pure, step._slots, in_vals, lbl_vals)
-
-    SDS = jax.ShapeDtypeStruct
-
-    def sds(v):
-        return SDS(v.shape, v.dtype)
-
-    pvals = fm.param_values()
-    train_p = [sds(v) for v, m in zip(pvals, fm.trainable_mask) if m]
-    frozen_p = [sds(v) for v, m in zip(pvals, fm.trainable_mask) if not m]
-    bvals = [sds(v) for v in fm.buffer_values()]
-    slots = jax.tree_util.tree_map(sds, step._slots)
-    key = jax.random.key(0)
-    lowered = jitted.lower(
-        train_p, frozen_p, bvals, slots, sds(key),
-        SDS((), "float32"),
-        jax.tree_util.tree_map(sds, in_vals),
-        jax.tree_util.tree_map(sds, lbl_vals))
+    # the argument tuple is TrainStep's own (_step_args): the arrays in it
+    # stay on the host backend, only their shapes/dtypes are lowered
+    jitted, args, _ = step._step_args(inputs, labels)
+    lowered = jitted.lower(*jax.tree_util.tree_map(
+        lambda v: jax.ShapeDtypeStruct(v.shape, v.dtype), args))
     t0 = time.time()
     compiled = lowered.compile()
-    out: Dict = {"compile_seconds": round(time.time() - t0, 1)}
+    out: Dict = {"compile_seconds": round(time.time() - t0, 1),
+                 "mosaic_calls": lowered.as_text().count("tpu_custom_call")}
     mem = compiled.memory_analysis()
     if mem is not None:
         out.update(

@@ -1,28 +1,13 @@
 """Persistent compiled-artifact cache (ISSUE 19, ROADMAP item 5).
 
 Compile latency was the repo's last unmanaged failure mode: the serving
-watchdog had to be sized above cold-compile time (PR 14), `compile_grace`
-state plumbing band-aided the same liability (PR 17), and the bench had
-to strip the XLA compilation cache across forced device counts because
-sharing one directory between worlds aborted glibc (PR 15). This module
-is the root fix — serialized executables with a validate-then-adopt
-cache discipline, keyed exactly like the PR-13 kernel tune cache:
+watchdog had to be sized above cold-compile time (PR 14) and
+`compile_grace` state plumbing band-aided the same liability (PR 17).
+This module is the root fix — serialized executables (``jax.export``) with
+a validate-then-adopt cache discipline, keyed exactly like the PR-13
+kernel tune cache:
 
     (program_fingerprint, shape_bucket, dtype, device_kind, world)
-
-``world`` and ``device_kind`` in the key are what make cross-device-count
-sharing safe: two processes with different forced device counts can point
-at the SAME cache root and never observe each other's entries (the PR-15
-abort becomes unrepresentable; ``compilation_cache_subdir`` applies the
-same keying to XLA's own persistent cache directory).
-
-Capability: serialization rides ``jax.export`` — a LAZY submodule on the
-jaxes this repo supports (``hasattr(jax, "export")`` is False until
-``from jax import export`` runs, the root cause of a 19-test skip set
-that over-approximated the missing capability). :func:`export_supported`
-probes ONCE by importing it; where the probe fails the cache degrades to
-a documented in-process warm path (``store``/``lookup`` still work, the
-artifacts just don't survive the process) and never crashes.
 
 Validation discipline (the PR-13 ``TuneCache`` shape, upgraded to binary
 payloads): every entry carries a content digest plus the producing
@@ -33,6 +18,9 @@ and the caller falls back to recompiling; a poisoned entry can never
 poison the process. Writes are atomic (tmp + fsync + rename through a
 ``LocalFS`` seam) so a crash mid-write leaves either the old entry or a
 ``.tmp`` orphan the loader never reads.
+
+:func:`use_compile_cache` is the one place the process points XLA's OWN
+persistent compilation cache at a directory.
 """
 from __future__ import annotations
 
@@ -43,12 +31,13 @@ import os
 import warnings
 from typing import Any, Dict, Optional
 
+from jax import export as _export
+
 from ..observability.metrics import get_registry as _get_registry
 
 __all__ = [
-    "CACHE_VERSION", "export_supported", "require_export", "producer_id",
-    "cache_key", "ArtifactCache", "export_compiled",
-    "compilation_cache_subdir",
+    "CACHE_VERSION", "producer_id", "cache_key", "ArtifactCache",
+    "export_compiled", "use_compile_cache",
 ]
 
 CACHE_VERSION = 1
@@ -58,50 +47,27 @@ _m_events = _get_registry().counter(
     "persistent compiled-artifact cache events",
     labels=("event",))
 
-# memoized probe result; None = not probed yet
-_EXPORT_MOD: Any = None
-_EXPORT_PROBED = False
 
+def use_compile_cache() -> str:
+    """Give this process a persistent XLA compilation cache and return its
+    directory. Entry points (chip_smoke.py, bench.py, examples, the chip
+    tools) call it before their first compile.
 
-def export_supported() -> bool:
-    """True iff this jax can serialize/deserialize compiled programs.
-
-    Probes ONCE per process by actually importing ``jax.export`` (a lazy
-    submodule — ``hasattr(jax, "export")`` is False before the import and
-    was therefore a false-negative capability gate) and checking the
-    serialize/deserialize surface. Never raises.
+    ``JAX_COMPILATION_CACHE_DIR`` set: jax already honours it, so no
+    directory is set in code — whoever placed the cache keeps control of
+    it. Unset: ``<checkout>/.jax_cache``, a fixed path (the path is part
+    of how a later run finds the entries; never a tmp name, pid or time).
     """
-    global _EXPORT_MOD, _EXPORT_PROBED
-    if _EXPORT_PROBED:
-        return _EXPORT_MOD is not None
-    _EXPORT_PROBED = True
-    try:
-        from jax import export as _export  # noqa: PLC0415
+    import jax
 
-        if (callable(getattr(_export, "export", None))
-                and callable(getattr(_export, "deserialize", None))):
-            _EXPORT_MOD = _export
-    except Exception:
-        _EXPORT_MOD = None
-    return _EXPORT_MOD is not None
-
-
-def _export_mod():
-    if not export_supported():
-        raise RuntimeError(
-            "jax.export unavailable in this environment "
-            "(artifact_cache.export_supported() is False) — callers must "
-            "stay on the in-process warm path")
-    return _EXPORT_MOD
-
-
-def require_export():
-    """The ``jax.export`` module, via the memoized probe. The ONE way the
-    repo reaches the submodule: it is lazy on supported jaxes, so
-    ``jax.export.X`` attribute access fails on a bare ``import jax`` —
-    the bug class behind the historical 19-test skip set. Raises the
-    probe-naming RuntimeError where unsupported."""
-    return _export_mod()
+    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env_dir:
+        return env_dir
+    checkout = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    path = os.path.join(checkout, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def producer_id() -> str:
@@ -140,9 +106,10 @@ def _default_world() -> int:
 def cache_key(program_fingerprint: str, shape_bucket, dtype,
               device_kind: Optional[str] = None,
               world: Optional[int] = None) -> str:
-    """The PR-13 kernel-cache key shape with the two fields whose absence
-    caused the PR-15 cross-device-count abort: device_kind and world are
-    ALWAYS part of the identity (defaulted from the live backend)."""
+    """The PR-13 kernel-cache key shape plus device_kind and world, which
+    are ALWAYS part of the identity (defaulted from the live backend): an
+    executable serialized for one device count is never adopted by
+    another."""
     dk = device_kind if device_kind is not None else _default_device_kind()
     w = world if world is not None else _default_world()
     bucket = "x".join(str(b) for b in shape_bucket) \
@@ -153,30 +120,10 @@ def cache_key(program_fingerprint: str, shape_bucket, dtype,
 def export_compiled(fn, *example_args):
     """Serialize-capable export of ``fn`` at the example arguments'
     shapes/dtypes. Returns the ``Exported`` (``.serialize()`` →  bytes,
-    ``.call(*args)`` executes). Raises where :func:`export_supported` is
-    False — gate on the probe first."""
+    ``.call(*args)`` executes)."""
     import jax
 
-    exp = _export_mod()
-    return exp.export(jax.jit(fn))(*example_args)
-
-
-def compilation_cache_subdir(base: str, world: Optional[int] = None,
-                             device_kind: Optional[str] = None) -> str:
-    """A world/device-kind-keyed subdirectory for XLA's OWN persistent
-    compilation cache (``JAX_COMPILATION_CACHE_DIR``).
-
-    The PR-15 bench aborted glibc when a subprocess with a different
-    ``--xla_force_host_platform_device_count`` shared the parent's cache
-    directory; the workaround stripped the cache wholesale. Keying the
-    directory the same way artifact entries are keyed lets every world
-    size share one base without interference — the root fix.
-    """
-    dk = device_kind if device_kind is not None else _default_device_kind()
-    w = world if world is not None else _default_world()
-    sub = os.path.join(base, f"{dk}-w{int(w)}")
-    os.makedirs(sub, exist_ok=True)
-    return sub
+    return _export.export(jax.jit(fn))(*example_args)
 
 
 class ArtifactCache:
@@ -185,9 +132,7 @@ class ArtifactCache:
     ``store(key, exported)`` persists ``exported.serialize()`` under the
     key (and always registers the object on the in-process warm map);
     ``lookup(key)`` answers from the warm map first, then deserializes a
-    validated on-disk entry. Where ``jax.export`` is unavailable the
-    disk tier is inert and the warm map alone carries the zero-cold-start
-    contract for the life of the process — the documented degraded mode.
+    validated on-disk entry.
 
     ``fs`` is the ``LocalFS`` syscall seam (robustness/checkpoint.py) so
     ``FaultyFS`` can tear writes at exactly the points a machine fails.
@@ -292,11 +237,9 @@ class ArtifactCache:
     def store(self, key: str, exported) -> bool:
         """Register a compiled program under ``key``. The in-process warm
         map always takes it; the disk tier additionally persists the
-        serialized form when the export capability exists AND the object
-        is serializable. True iff the entry was persisted to disk."""
+        serialized form when the object is serializable. True iff the
+        entry was persisted to disk."""
         self._warm[key] = exported
-        if not export_supported():
-            return False
         ser = getattr(exported, "serialize", None)
         if ser is None:
             return False
@@ -317,15 +260,11 @@ class ArtifactCache:
             self.hits += 1
             _m_events.labels(event="hit").inc()
             return hit
-        if not export_supported():
-            self.misses += 1
-            _m_events.labels(event="miss").inc()
-            return None
         payload = self.load_bytes(key)
         if payload is None:
             return None
         try:
-            obj = _export_mod().deserialize(bytearray(payload))
+            obj = _export.deserialize(bytearray(payload))
         except Exception as e:
             self._discard(self._path(key), f"deserialize failed ({e!r})")
             return None
@@ -335,5 +274,4 @@ class ArtifactCache:
     def stats(self) -> dict:
         return {"root": self.root, "warm_entries": len(self._warm),
                 "hits": self.hits, "misses": self.misses,
-                "discards": self.discards,
-                "export_supported": export_supported()}
+                "discards": self.discards}

@@ -373,21 +373,14 @@ def _policy_step(apply_full, policy: str):
     if policy == "remat":
         return jax.checkpoint(apply_full)
     if policy == "offload":
-        from ..distributed.pipeline.memory_plan import _offload_kind
+        from ..distributed.pipeline.memory_plan import OFFLOAD_KIND
         from ..distributed.pipeline.schedule import _to_memory_kind
 
-        kind = _offload_kind()
-        try:
-            dev_kind = jax.devices()[0].default_memory().kind
-        except Exception:
-            dev_kind = None
-        fetch = dev_kind if (dev_kind and dev_kind != kind) else None
-
         def run(carry, slices):
-            c_host = _to_memory_kind(carry, kind)
+            c_host = _to_memory_kind(carry, OFFLOAD_KIND)
 
             def inner(c2, sl):
-                return apply_full(_to_memory_kind(c2, fetch), sl)
+                return apply_full(_to_memory_kind(c2, "device"), sl)
 
             return jax.checkpoint(inner)(c_host, slices)
 

@@ -161,14 +161,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
 def _sds(shape, dtype, like):
     """Out ShapeDtypeStruct carrying `like`'s varying-mesh-axes set, so the
     pallas_call stays legal inside vma-tracked shard_map regions (the 1F1B
-    pipeline, ring attention's manual block)."""
-    try:
-        vma = jax.typeof(like).vma
-    except Exception:
-        return jax.ShapeDtypeStruct(shape, dtype)
-    if not vma:
-        return jax.ShapeDtypeStruct(shape, dtype)
-    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
+    pipeline, ring attention's manual block, the traced ZeRO-2
+    reduce_scatter path)."""
+    return jax.ShapeDtypeStruct(shape, dtype, vma=jax.typeof(like).vma)
 
 
 def _fwd(q, k, v, causal, block_q, block_k):

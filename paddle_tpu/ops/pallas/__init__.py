@@ -1,7 +1,6 @@
 """paddle_tpu.ops.pallas — the kernel-performance layer (ISSUE 13).
 
-Three pieces grow raw per-chip math throughput (BENCH_r05: 104.8k
-measured vs ~444k roofline tokens/s/chip):
+Three pieces aimed at raw per-chip math throughput:
 
 - ``autotune.py`` — a CUDA-L2-spirit sweep harness over kernel tile
   parameters: validate every candidate against the jnp reference, time

@@ -357,11 +357,12 @@ def autotune(kernel: str, *args, cache: Optional[TuneCache] = None,
         device_kind = current_device_kind()
     ref = family.reference(*args)
     default = family.default_params(*args)
-    flops, nbytes = family.cost(*args)
-    from ...cost_model import kernel_roofline
-
-    floor_s = kernel_roofline(flops, nbytes, device_kind)
     can_time = timer is not None or _can_time_on_device()
+    floor_s = None      # the noise floor exists only where something is timed
+    if can_time:
+        from ...cost_model import kernel_roofline
+
+        floor_s = kernel_roofline(*family.cost(*args), device_kind)
 
     rows = []
     for params in family.candidates(*args):

@@ -24,19 +24,19 @@ from __future__ import annotations
 import functools
 from typing import Optional
 
-import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from . import autotune
+from ..flash_attention import _sds
 
 __all__ = ["use_tpu_kernels", "block_encode", "block_decode",
            "DEFAULT_TILE"]
 
 _LANES = 128
 DEFAULT_TILE = 8
-_FP8_WIRE = getattr(jnp, "float8_e4m3fn", None)
+_FP8_WIRE = jnp.float8_e4m3fn
 
 
 def _interpret() -> bool:
@@ -51,19 +51,6 @@ def use_tpu_kernels() -> bool:
     from ...framework.target import target_platform
 
     return target_platform() == "tpu"
-
-
-def _sds(shape, dtype, like):
-    """vma-carrying ShapeDtypeStruct (see ops/flash_attention.py): keeps
-    the pallas_call legal inside vma-tracked shard_map regions (the
-    traced ZeRO-2 reduce_scatter path runs these under shard_map)."""
-    try:
-        vma = jax.typeof(like).vma
-    except Exception:
-        return jax.ShapeDtypeStruct(shape, dtype)
-    if not vma:
-        return jax.ShapeDtypeStruct(shape, dtype)
-    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
 def _resolve_tile(nb: int, dtype, tile: Optional[int]) -> int:
@@ -106,8 +93,7 @@ def block_encode(flat, scales, block_size: int, codec: str,
     pass. Ragged block sizes fall back to the jnp reference."""
     from ...distributed import grad_comm as _gc
 
-    if block_size % _LANES or codec not in ("int8_block", "fp8_block") \
-            or (codec == "fp8_block" and _FP8_WIRE is None):
+    if block_size % _LANES or codec not in ("int8_block", "fp8_block"):
         return _gc.block_encode(flat, scales, block_size, codec)
     x = _gc._as_blocks(flat, block_size)                 # (nb, bs) fp32
     nb = int(x.shape[0])

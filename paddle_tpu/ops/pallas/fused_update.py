@@ -38,12 +38,12 @@ from __future__ import annotations
 import functools
 from typing import Dict, Optional, Tuple
 
-import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from . import autotune
+from ..flash_attention import _sds
 
 __all__ = ["FUSED_RULES", "rule_spec", "fused_update_flat",
            "fused_dequant_update_flat", "bucket_update_fn",
@@ -152,18 +152,6 @@ def _dequant_kernel(s_ref, q_ref, srow_ref, *refs, kind, hyper, wd,
     out_refs[0][...] = new_p.astype(out_refs[0].dtype)
     for r, v in zip(out_refs[1:], new_slots):
         r[...] = v
-
-
-def _sds(shape, dtype, like):
-    """vma-carrying ShapeDtypeStruct (see ops/flash_attention.py): keeps
-    the pallas_call legal inside vma-tracked shard_map regions."""
-    try:
-        vma = jax.typeof(like).vma
-    except Exception:
-        return jax.ShapeDtypeStruct(shape, dtype)
-    if not vma:
-        return jax.ShapeDtypeStruct(shape, dtype)
-    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
 def _resolve_tile(n: int, dtype, tile: Optional[int]) -> int:

@@ -26,6 +26,7 @@ arguments pin them, and both fall back to the (256, 256, 512) defaults.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -43,30 +44,36 @@ def _interpret() -> bool:
 # quantize: per-output-channel symmetric int8
 # ---------------------------------------------------------------------------
 
-def _hash_uniform(shape, seed_u32):
+def _hash_uniform(shape, seed_u32, col0, n_cols):
     """[0, 1) uniforms from a murmur3-finalizer hash of (element index,
     seed): pure uint32 arithmetic — identical bits under Mosaic, the
     interpreter, and XLA:CPU. The per-element counter is the GLOBAL flat
-    index, so any future tiling of this kernel cannot change the noise."""
-    r, c = shape
-    idx = (jax.lax.broadcasted_iota(jnp.uint32, shape, 0) * jnp.uint32(c)
-           + jax.lax.broadcasted_iota(jnp.uint32, shape, 1))
+    index into the [k, n_cols] weight (this block starts at column
+    `col0`), so the column tiling cannot change the noise."""
+    idx = (jax.lax.broadcasted_iota(jnp.uint32, shape, 0)
+           * jnp.uint32(n_cols)
+           + jax.lax.broadcasted_iota(jnp.uint32, shape, 1)
+           + col0.astype(jnp.uint32))
     h = idx * jnp.uint32(2654435761) ^ seed_u32
     h = h ^ (h >> 16)
     h = h * jnp.uint32(0x85EB_CA6B)
     h = h ^ (h >> 13)
     h = h * jnp.uint32(0xC2B2_AE35)
     h = h ^ (h >> 16)
-    return (h >> 8).astype(jnp.float32) * jnp.float32(1.0 / (1 << 24))
+    # via int32: Mosaic has no uint32 -> float32 cast, and the value is
+    # < 2**24 so the bits are the same
+    return ((h >> 8).astype(jnp.int32).astype(jnp.float32)
+            * jnp.float32(1.0 / (1 << 24)))
 
 
-def _quantize_kernel(w_ref, seed_ref, q_ref, s_ref, *, stochastic):
+def _quantize_kernel(seed_ref, w_ref, q_ref, s_ref, *, stochastic, n_cols):
     w = w_ref[...].astype(jnp.float32)
     amax = jnp.max(jnp.abs(w), axis=0, keepdims=True)          # per col
     scale = jnp.maximum(amax / 127.0, 1e-12)
     scaled = w / scale
     if stochastic:
-        u = _hash_uniform(scaled.shape, seed_ref[0].astype(jnp.uint32))
+        u = _hash_uniform(scaled.shape, seed_ref[0].astype(jnp.uint32),
+                          pl.program_id(0) * w.shape[1], n_cols)
         # floor(x + u) rounds up with probability frac(x): unbiased
         q = jnp.clip(jnp.floor(scaled + u), -127, 127).astype(jnp.int8)
     else:
@@ -81,16 +88,29 @@ def quantize_int8(w, stochastic=False, seed=0):
     Deterministic: same (w, stochastic, seed) → bit-identical int8 on
     every platform and process (see module docstring)."""
     k, n = w.shape
+    # scales are per column, so a (k, bn) block holds everything a column
+    # needs. bn keeps the fp32 block near 1 MiB: the whole weight in VMEM
+    # (the gridless form) is refused by Mosaic from 768x3072 up. At
+    # k = 8192 even the narrowest (k, 128) block plus the rounding
+    # temporaries needs 22 MiB, over the default 16 MiB scoped limit —
+    # hence vmem_limit_bytes.
+    bn = max(128, (1 << 20) // (4 * k) // 128 * 128)
+    if n <= bn:
+        bn = n
     q, s = pl.pallas_call(
-        functools.partial(_quantize_kernel, stochastic=stochastic),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM),
-                  pl.BlockSpec(memory_space=pltpu.SMEM)],
-        out_specs=[pl.BlockSpec(memory_space=pltpu.VMEM),
-                   pl.BlockSpec(memory_space=pltpu.VMEM)],
+        functools.partial(_quantize_kernel, stochastic=stochastic,
+                          n_cols=n),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(pl.cdiv(n, bn),),
+            in_specs=[pl.BlockSpec((k, bn), lambda j, seed: (0, j))],
+            out_specs=[pl.BlockSpec((k, bn), lambda j, seed: (0, j)),
+                       pl.BlockSpec((1, bn), lambda j, seed: (0, j))]),
         out_shape=[jax.ShapeDtypeStruct((k, n), jnp.int8),
                    jax.ShapeDtypeStruct((1, n), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=64 << 20),
         interpret=_interpret(),
-    )(w, jnp.asarray([int(seed) & 0x7FFF_FFFF], jnp.int32))
+    )(jnp.asarray([int(seed) & 0x7FFF_FFFF], jnp.int32), w)
     return q, s
 
 
@@ -145,6 +165,16 @@ def _tuned_tiles(m: int, n: int, k: int, dtype):
     return _DEFAULT_TILES
 
 
+def _tile(dim: int, block: int) -> int:
+    """The largest tile <= block that divides dim and stays 128-aligned
+    (k = 768 under the default block_k = 512 runs at 256); the whole dim
+    when it is no larger than the block; 0 when nothing fits (ragged)."""
+    if dim <= block:
+        return dim
+    t = math.gcd(dim, block)
+    return t if t % 128 == 0 else 0
+
+
 def quant_matmul(x, qw, scales, block_m=None, block_n=None, block_k=None,
                  out_dtype=None):
     """x [m, k] @ dequant(qw [k, n], scales [1, n]) -> [m, n].
@@ -161,10 +191,8 @@ def quant_matmul(x, qw, scales, block_m=None, block_n=None, block_k=None,
         block_m = block_m or _DEFAULT_TILES[0]
         block_n = block_n or _DEFAULT_TILES[1]
         block_k = block_k or _DEFAULT_TILES[2]
-    bm = min(block_m, m)
-    bn = min(block_n, n)
-    bk = min(block_k, k)
-    if m % bm or n % bn or k % bk:
+    bm, bn, bk = _tile(m, block_m), _tile(n, block_n), _tile(k, block_k)
+    if not (bm and bn and bk):
         # ragged shapes: plain XLA dequant matmul (still weight-only int8 in
         # HBM — the bandwidth saving survives; only the tiling control is lost)
         out = x.astype(jnp.float32) @ (qw.astype(jnp.float32) * scales)

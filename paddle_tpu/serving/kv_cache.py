@@ -112,8 +112,6 @@ class KVBlockPool:
 
         if codec not in KV_CODECS:
             raise ValueError(f"codec must be one of {KV_CODECS}, got {codec!r}")
-        if codec == "fp8_block" and grad_comm._FP8_WIRE is None:
-            raise ValueError("fp8_block needs jax float8_e4m3fn support")
         self.n_blocks = int(n_blocks)
         self.block_tokens = int(block_tokens)
         self.elems_per_token = int(elems_per_token)

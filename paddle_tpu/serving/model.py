@@ -181,7 +181,10 @@ class GPTDecodeModel:
             S = past.shape[1]
             x = jnp.take(params["word"], ids, axis=0) \
                 + jnp.take(params["pos"], pos, axis=0)         # [b, h]
-            past_r = past.reshape(b, S, L, 2, n, d)
+            # the host keeps `past` as an fp32 working copy; attention runs
+            # in the model's dtype (an fp32 past under bf16 weights would
+            # promote the residual stream and break the scan carry)
+            past_r = past.reshape(b, S, L, 2, n, d).astype(x.dtype)
             pk = past_r[:, :, :, 0].transpose(2, 0, 1, 3, 4)   # [L,b,S,n,d]
             pv = past_r[:, :, :, 1].transpose(2, 0, 1, 3, 4)
             valid = jnp.arange(S)[None, :] < past_len[:, None]  # [b, S]
@@ -239,7 +242,7 @@ class GPTDecodeModel:
             S = past.shape[1]
             x = jnp.take(params["word"], ids, axis=0) \
                 + jnp.take(params["pos"], pos, axis=0)       # [b, s, h]
-            past_r = past.reshape(b, S, L, 2, n, d)
+            past_r = past.reshape(b, S, L, 2, n, d).astype(x.dtype)
             pk = past_r[:, :, :, 0].transpose(2, 0, 1, 3, 4)  # [L,b,S,n,d]
             pv = past_r[:, :, :, 1].transpose(2, 0, 1, 3, 4)
             valid_past = (jnp.arange(S)[None, :]

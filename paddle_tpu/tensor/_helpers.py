@@ -11,6 +11,7 @@ import math
 
 import jax.numpy as jnp
 import numpy as np
+from jax._src.core import EvalTrace as _EvalTrace, trace_ctx as _trace_ctx
 
 from ..framework.autograd import call_op as op  # noqa: F401
 from ..framework.tensor import Tensor  # noqa: F401
@@ -36,22 +37,11 @@ _scalar_cache: dict = {}
 # into compiled executables by identity, and a shared array reappearing
 # across separately-compiled programs corrupts their buffer plans (observed
 # as 'supplied N buffers but compiled program expected M' on executor
-# replays). Trace-time conversion cost compiles away anyway. The trace
-# probe is resolved ONCE at import — this sits on the per-op hot path.
-try:
-    from jax._src.core import EvalTrace as _EvalTrace, trace_ctx as _trace_ctx
-
-    def _tracing() -> bool:
-        return type(_trace_ctx.trace) is not _EvalTrace
-except Exception:  # pragma: no cover - jax internals moved
-    import warnings as _warnings
-
-    _warnings.warn("paddle_tpu: jax trace-state probe unavailable "
-                   "(jax internals changed); eager scalar caching is "
-                   "disabled — dispatch will be slower")
-
-    def _tracing() -> bool:
-        return True
+# replays). Trace-time conversion cost compiles away anyway. The probe is a
+# private jax import, unguarded on purpose: if it moves, the package fails
+# to import instead of silently treating every call as traced.
+def _tracing() -> bool:
+    return type(_trace_ctx.trace) is not _EvalTrace
 
 
 def _scalar_array(x, dtype):

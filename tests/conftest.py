@@ -2,7 +2,7 @@
 
 Mirrors the reference's strategy of testing distributed logic with local
 processes + gloo (SURVEY.md §4): here a single process with 8 XLA host devices
-stands in for an 8-chip TPU slice. bench.py / production use the real chip.
+stands in for an 8-chip TPU slice. chip_smoke.py / bench.py use the real chip.
 """
 import os
 import threading
@@ -55,48 +55,22 @@ if os.environ.get("FLAGS_host_sync_check", "").lower() in ("1", "true", "yes"):
 
 import pytest  # noqa: E402
 
-# ISSUE 19 re-audit of the ISSUE 16 skip set. The old gate was
-# `hasattr(jax, "export")` — a FALSE NEGATIVE on every jax where export
-# is a lazy submodule (the attribute only exists after `from jax import
-# export` runs), which silently skipped 19 tests this environment can
-# actually run. The capability is now probed by actually importing the
-# submodule (jit/artifact_cache.export_supported()), and the two
-# capabilities the old marker lumped in with export get their own
-# markers + live probes:
-#   requires_vma_shard_map    — jax >= 0.6 vma-typed shard_map
-#   requires_cpu_multiprocess — multi-process jax.distributed over CPU
-from paddle_tpu.jit.artifact_cache import export_supported  # noqa: E402
-
-_HAS_JAX_EXPORT = export_supported()
-# vma-typed shard_map (varying manual axes) landed with the jax 0.6 line
-_HAS_VMA_SHARD_MAP = tuple(
-    int(x) for x in jax.__version__.split(".")[:2]) >= (0, 6)
 # single-container CI: no second process to join a coordination service
 _HAS_CPU_MULTIPROCESS = os.environ.get(
     "PADDLE_TPU_MULTIPROC", "").lower() in ("1", "true", "yes")
 
 
 def pytest_collection_modifyitems(config, items):
-    gates = (
-        ("requires_jax_export", _HAS_JAX_EXPORT,
-         "artifact_cache.export_supported() is False: this jax cannot "
-         "serialize compiled programs (degraded in-process warm path "
-         "only); pre-existing capability gap, not a regression"),
-        ("requires_vma_shard_map", _HAS_VMA_SHARD_MAP,
-         "environment jax predates vma-typed shard_map (jax >= 0.6); "
-         "pre-existing capability gap, not a regression"),
-        ("requires_cpu_multiprocess", _HAS_CPU_MULTIPROCESS,
-         "multi-process jax.distributed unavailable here (set "
-         "PADDLE_TPU_MULTIPROC=1 on a host that can bind a coordination "
-         "service); pre-existing capability gap, not a regression"),
-    )
-    for marker, have, reason in gates:
-        if have:
-            continue
-        skip = pytest.mark.skip(reason=reason)
-        for item in items:
-            if marker in item.keywords:
-                item.add_marker(skip)
+    if _HAS_CPU_MULTIPROCESS:
+        return
+    skip = pytest.mark.skip(
+        reason="multi-process jax.distributed unavailable here (set "
+               "PADDLE_TPU_MULTIPROC=1 on a host that can bind a "
+               "coordination service); pre-existing capability gap, not a "
+               "regression")
+    for item in items:
+        if "requires_cpu_multiprocess" in item.keywords:
+            item.add_marker(skip)
 
 
 @pytest.fixture

@@ -1,12 +1,9 @@
 """Persistent compiled-artifact cache (ISSUE 19, ROADMAP item 5).
 
 Contracts pinned here:
-- capability probe: ``export_supported()`` actually imports the lazy
-  ``jax.export`` submodule (``hasattr(jax, "export")`` was a false
-  negative) and ``require_export()`` is the one sanctioned way in.
-- round trip: where the probe holds, export → serialize → store →
-  (fresh cache) lookup → deserialize is BYTE-identical and the
-  deserialized program computes the same results.
+- round trip: export → serialize → store → (fresh cache) lookup →
+  deserialize is BYTE-identical and the deserialized program computes
+  the same results.
 - validation discipline: corrupt, version-drifted, producer-drifted,
   key-mismatched and torn entries are discarded LOUDLY (warning +
   discard counter) and read as a miss — the caller recompiles; a
@@ -14,63 +11,24 @@ Contracts pinned here:
 - FaultyFS: a torn write or crashed rename leaves either the old entry
   or an orphan ``.tmp`` the loader never reads; transient write errors
   degrade to "not persisted", never an exception.
-- degraded mode: with the probe forced off, the disk tier goes inert
-  and the in-process warm map alone carries store/lookup.
-- ``compilation_cache_subdir``: world/device-kind-keyed subdirectories
-  let two processes with DIFFERENT forced device counts share one XLA
-  cache base (the PR-15 glibc abort, made unrepresentable).
+- an object that cannot serialize stays on the in-process warm map.
 """
 import os
-import subprocess
-import sys
 
 import numpy as np
 import pytest
 
-from paddle_tpu.jit import artifact_cache as ac
 from paddle_tpu.jit.artifact_cache import (
-    ArtifactCache, cache_key, compilation_cache_subdir, export_compiled,
-    export_supported, producer_id, require_export,
+    CACHE_VERSION, ArtifactCache, cache_key, export_compiled, producer_id,
 )
 from paddle_tpu.robustness.fault_injection import FaultyFS, InjectedCrash
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-@pytest.fixture
-def forced_degraded(monkeypatch):
-    """Force the probe to report no-export (the degraded warm path)."""
-    monkeypatch.setattr(ac, "_EXPORT_PROBED", True)
-    monkeypatch.setattr(ac, "_EXPORT_MOD", None)
-
-
-class _FakeExported:
-    """Duck Exported for plumbing tests: serialize() -> fixed bytes."""
-
-    def __init__(self, payload=b"fake-program"):
-        self._payload = payload
-
-    def serialize(self):
-        return self._payload
-
 
 # ---------------------------------------------------------------------------
-# probe + key
+# key
 # ---------------------------------------------------------------------------
 
-class TestProbeAndKey:
-    def test_probe_memoized_and_consistent(self):
-        assert export_supported() == export_supported()
-        if export_supported():
-            exp = require_export()
-            assert callable(exp.export) and callable(exp.deserialize)
-
-    def test_require_export_names_the_probe_when_absent(
-            self, forced_degraded):
-        assert not export_supported()
-        with pytest.raises(RuntimeError, match="export_supported"):
-            require_export()
-
+class TestKey:
     def test_key_separates_world_and_device(self):
         base = dict(program_fingerprint="fp", shape_bucket=(4, 16),
                     dtype="float32")
@@ -92,10 +50,9 @@ class TestProbeAndKey:
 
 
 # ---------------------------------------------------------------------------
-# round trip (real jax.export where the env has it)
+# round trip (real jax.export)
 # ---------------------------------------------------------------------------
 
-@pytest.mark.requires_jax_export
 class TestRoundTrip:
     def test_byte_identical_round_trip_and_execution(self, tmp_path):
         import jax.numpy as jnp
@@ -171,7 +128,7 @@ class TestValidation:
 
     def test_version_drift_discarded_loudly(self, tmp_path):
         cache, path, _ = self._stored(tmp_path)
-        self._rewrite(path, version=ac.CACHE_VERSION + 1)
+        self._rewrite(path, version=CACHE_VERSION + 1)
         with pytest.warns(UserWarning, match="version drift"):
             assert cache.load_bytes("k") is None
 
@@ -234,21 +191,10 @@ class TestFaultyFS:
 
 
 # ---------------------------------------------------------------------------
-# degraded mode (no jax.export)
+# unserializable objects
 # ---------------------------------------------------------------------------
 
-class TestDegradedMode:
-    def test_warm_map_alone_carries_store_lookup(self, tmp_path,
-                                                 forced_degraded):
-        cache = ArtifactCache(str(tmp_path))
-        obj = _FakeExported()
-        assert cache.store("k", obj) is False  # disk tier inert
-        assert cache.lookup("k") is obj        # warm map still answers
-        assert os.listdir(tmp_path) == []      # nothing persisted
-        fresh = ArtifactCache(str(tmp_path))
-        assert fresh.lookup("k") is None       # and nothing survives
-        assert fresh.stats()["export_supported"] is False
-
+class TestUnserializable:
     def test_unserializable_object_stays_in_process(self, tmp_path):
         class _Boom:
             def serialize(self):
@@ -259,51 +205,3 @@ class TestDegradedMode:
         with pytest.warns(UserWarning, match="kept in-process"):
             assert cache.store("k", obj) is False
         assert cache.lookup("k") is obj
-
-
-# ---------------------------------------------------------------------------
-# XLA compilation-cache keying (the PR-15 regression)
-# ---------------------------------------------------------------------------
-
-class TestCompilationCacheSubdir:
-    def test_subdirs_keyed_by_world_and_device(self, tmp_path):
-        a = compilation_cache_subdir(str(tmp_path), world=1,
-                                     device_kind="cpu")
-        b = compilation_cache_subdir(str(tmp_path), world=2,
-                                     device_kind="cpu")
-        assert a != b and os.path.isdir(a) and os.path.isdir(b)
-        assert os.path.dirname(a) == os.path.dirname(b) == str(tmp_path)
-
-    def test_two_world_sizes_share_one_cache_base(self, tmp_path):
-        """The PR-15 regression: two processes with different forced
-        device counts point at the SAME cache base. With keyed subdirs
-        neither can observe the other's entries — both must exit 0
-        (the unkeyed layout aborted glibc on the second run)."""
-        script = (
-            "import os, jax, jax.numpy as jnp\n"
-            "from paddle_tpu.jit.artifact_cache import "
-            "compilation_cache_subdir\n"
-            "jax.config.update('jax_platforms', 'cpu')\n"
-            "base = os.environ['CACHE_BASE']\n"
-            "sub = compilation_cache_subdir(base)\n"
-            "jax.config.update('jax_compilation_cache_dir', sub)\n"
-            "jax.config.update("
-            "'jax_persistent_cache_min_compile_time_secs', 0.0)\n"
-            "x = jax.jit(lambda a: (a * 3.0).sum())(jnp.arange(64.0))\n"
-            "print(jax.device_count(), sub)\n"
-        )
-        subs = []
-        for n in (1, 2):
-            env = dict(os.environ,
-                       CACHE_BASE=str(tmp_path),
-                       JAX_PLATFORMS="cpu",
-                       XLA_FLAGS=f"--xla_force_host_platform_device_count={n}")
-            proc = subprocess.run(
-                [sys.executable, "-c", script], env=env, cwd=REPO,
-                capture_output=True, text=True, timeout=300)
-            assert proc.returncode == 0, (proc.stdout, proc.stderr)
-            world, sub = proc.stdout.split()[-2:]
-            assert int(world) == n
-            subs.append(sub)
-        assert subs[0] != subs[1]
-        assert all(os.path.dirname(s) == str(tmp_path) for s in subs)

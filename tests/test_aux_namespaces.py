@@ -113,7 +113,6 @@ class TestAutoCheckpoint:
 
 
 class TestOnnxExport:
-    @pytest.mark.requires_jax_export
     def test_stablehlo_export_roundtrip(self, tmp_path):
         import jax
 

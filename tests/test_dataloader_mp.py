@@ -23,6 +23,18 @@ class PidDataset(Dataset):
         return np.array([os.getpid(), i], dtype=np.int64)
 
 
+class PlatformDataset(Dataset):
+    """1 where the process fetching the item has JAX held to the CPU."""
+
+    def __len__(self):
+        return 4
+
+    def __getitem__(self, i):
+        import jax
+
+        return np.array([jax.config.jax_platforms == "cpu"], dtype=np.int64)
+
+
 class SquareDataset(Dataset):
     def __len__(self):
         return 32
@@ -71,6 +83,15 @@ def test_workers_are_real_processes():
     assert os.getpid() not in pids  # fetched OUTSIDE the parent process
     assert len(pids) >= 1  # (on a multi-core box both workers participate;
     # this 1-core CI machine may drain everything through one)
+
+
+def test_workers_stay_off_the_chip_their_parent_holds(monkeypatch):
+    """One process per chip: with JAX_PLATFORMS=tpu exported, a worker
+    must still come up on the CPU (and the parent's variable survives)."""
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    dl = DataLoader(PlatformDataset(), batch_size=2, num_workers=1)
+    assert all(int(v) == 1 for b in dl for v in b.numpy()[:, 0])
+    assert os.environ["JAX_PLATFORMS"] == "tpu"
 
 
 def test_order_is_deterministic():

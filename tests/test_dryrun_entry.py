@@ -19,7 +19,6 @@ import pytest
 
 
 @pytest.mark.timeout(900)
-@pytest.mark.requires_vma_shard_map
 def test_dryrun_multichip_16_joint_axes():
     sys.path.insert(0, os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))

@@ -1,21 +1,16 @@
 """Eager per-op dispatch regression guard (VERDICT r4 #7, SURVEY §7
 hard-part 1).
 
-artifacts/eager_dispatch.json carries the measured numbers (TPU record
-from the on-chip sprint; CPU record from tools/eager_dispatch.py). This
-guard re-measures the CPU-PJRT hit path in-suite. The signal is the
+This guard re-measures the CPU-PJRT hit path in-suite (the one on-chip
+figure is artifacts/TPU_RESULTS.json `eager`, 2026-07-31). The signal is the
 miss/hit RATIO over the min of several repetitions, not an absolute
 wall-clock bound: a loaded CI host inflates both paths together, while
 the regression this guard exists for — a cache-key bug recompiling per
 call, a new per-op host hop — collapses the ratio toward 1. (The old
 `hit_us < 450` absolute bound flaked whenever the suite shared a box.)
 """
-import json
 import os
 import sys
-import time
-
-import numpy as np
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -39,14 +34,3 @@ def test_eager_hit_dispatch_stays_bounded():
     # the miss path must actually be a compile (orders slower than a
     # cache hit), or the hit measurement is not exercising the cache
     assert miss_us > 10 * hit_us, (hit_us, miss_us, recs)
-
-
-def test_eager_dispatch_artifact_is_current():
-    """The committed artifact must exist, carry both labeled records, and
-    keep the TPU record marked as on-chip."""
-    path = os.path.join(REPO, "artifacts", "eager_dispatch.json")
-    d = json.load(open(path))
-    assert "cpu" in d and d["cpu"]["on_tpu"] is False
-    assert d["cpu"]["hit_us"] > 0 and d["cpu"]["miss_us"] > d["cpu"]["hit_us"]
-    assert "tpu" in d and d["tpu"]["on_tpu"] is True
-    assert d["tpu"]["hit_us"] > 0

@@ -86,7 +86,6 @@ def test_with_compiled_predictor_stage():
     exe.shutdown()
 
 
-@pytest.mark.requires_jax_export
 def test_dist_model_sharded_inference_matches_single_device(tmp_path):
     """DistModel (reference dist_model.cc): artifact load + batch sharded
     over the mesh produces the same logits as plain single-device run."""
@@ -129,7 +128,6 @@ def test_dist_model_sharded_inference_matches_single_device(tmp_path):
         mesh_mod._current[0] = None
 
 
-@pytest.mark.requires_jax_export
 def test_dist_model_mesh_set_after_init(tmp_path):
     """A mesh installed AFTER init() must be honored at run() (the
     sharding decision follows the current mesh, not a stale snapshot)."""

@@ -105,7 +105,6 @@ def _one_step_mp(clip_norm, w0=None):
     return init, _params(net)
 
 
-@pytest.mark.requires_vma_shard_map
 def test_global_norm_clip_parity_mp2():
     w0, mp_clipped = _one_step_mp(CLIP)
     i0, single_clipped = _one_step_mp(CLIP, w0=w0)
@@ -157,7 +156,6 @@ def test_global_norm_clip_parity_sharding2_stage3():
     assert err <= 1e-5, f"sharding2/stage3 post-clip update diverges: {err}"
 
 
-@pytest.mark.requires_vma_shard_map
 def test_global_norm_clip_parity_pipe2_1f1b():
     """The 1F1B compat path: grads reach _apply_clip from the pipeline
     grad_fn. pipeline_1f1b pre-reduces them (psum over pipe for the owning

@@ -286,7 +286,6 @@ def test_create_predictor_serves_reference_artifact(mlp_artifact):
     np.testing.assert_allclose(out, _np_mlp(x, w), rtol=1e-5, atol=1e-6)
 
 
-@pytest.mark.requires_jax_export
 def test_save_optimized_model_roundtrip(tmp_path, mlp_artifact):
     """AnalysisPredictor::SaveOptimModel (analysis_predictor.h:265): a
     predictor serving a reference __model__ dir persists the optimized

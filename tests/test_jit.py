@@ -191,7 +191,6 @@ class TestAmp:
         assert net.weight.dtype == jnp.bfloat16
 
 
-@pytest.mark.requires_jax_export
 def test_jit_load_returns_translated_layer(tmp_path):
     """jit.save with input_spec → jit.load returns a CALLABLE TranslatedLayer
     (reference: dygraph/io.py TranslatedLayer)."""

@@ -1077,17 +1077,32 @@ class TestBenchGate:
         spec.loader.exec_module(mod)
         return mod
 
-    def test_offline_passes_on_current_trajectory(self, bench_gate):
-        assert bench_gate.main(["--offline"]) == 0
+    @pytest.fixture()
+    def trajectory(self, tmp_path):
+        """Two same-class rounds, the newer inside the band of the older."""
+        rounds = tmp_path / "trajectory"
+        rounds.mkdir()
+        rec = {"value": 1000.0, "device_kind": "TPU v5 lite",
+               "peak_hbm_bytes_measured": 1000}
+        for n, value in ((1, 1000.0), (2, 990.0)):
+            (rounds / f"BENCH_r{n:02d}.json").write_text(json.dumps(
+                {"n": n, "rc": 0, "parsed": dict(rec, value=value)}))
+        return rounds
 
-    def test_degraded_candidate_fails(self, bench_gate, tmp_path):
-        traj = bench_gate.load_trajectory()
-        assert traj, "repo must carry BENCH_r*.json records"
+    def test_offline_passes_on_recorded_trajectory(self, bench_gate,
+                                                   trajectory):
+        assert bench_gate.main(["--root", str(trajectory),
+                                "--offline"]) == 0
+
+    def test_degraded_candidate_fails(self, bench_gate, trajectory,
+                                      tmp_path):
+        traj = bench_gate.load_trajectory(str(trajectory))
         degraded = dict(traj[-1][1])
         degraded["value"] = degraded["value"] * 0.5  # half the tokens/s
         p = tmp_path / "degraded.json"
         p.write_text(json.dumps(degraded))
-        assert bench_gate.main(["--candidate", str(p)]) == 1
+        assert bench_gate.main(["--root", str(trajectory),
+                                "--candidate", str(p)]) == 1
 
     def test_memory_and_comm_regressions_gate(self, bench_gate, tmp_path):
         base = {"value": 1000.0, "fallback": "cpu",

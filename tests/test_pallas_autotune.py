@@ -163,6 +163,11 @@ def test_lookup_returns_copy(flag_on):
 
 # ---------------------------------------------------------------- autotune
 
+# sweeps with an injected timer name the chip whose published peaks bound
+# the timings (cost_model.DEVICE_PEAKS has no entry for the CPU)
+CHIP = "TPU v5 lite"
+
+
 def test_sweep_selects_validated_non_default_winner(tmp_path, fresh_cache):
     """The acceptance sweep: an injected timer that prefers tile=32 makes
     the harness persist a validated non-default winner, and dispatch
@@ -175,7 +180,7 @@ def test_sweep_selects_validated_non_default_winner(tmp_path, fresh_cache):
         return 1.0 if params["tile"] == 4 else 2.0 + params["tile"] * 0.01
 
     rep = at.autotune("fused_update", *args, cache=cache, timer=timer,
-                      cache_path=path)
+                      cache_path=path, device_kind=CHIP)
     assert rep["winner_params"] == {"tile": 4}
     assert rep["winner_params"] != rep["default_params"]
     assert rep["n_validated"] == rep["n_candidates"] > 1
@@ -186,8 +191,8 @@ def test_sweep_selects_validated_non_default_winner(tmp_path, fresh_cache):
     flags.set_flags({"FLAGS_kernel_autotune": True})
     try:
         at.reset_runtime_cache(reloaded)
-        assert at.lookup("fused_update", (1000,),
-                         jnp.float32) == {"tile": 4}
+        assert at.lookup("fused_update", (1000,), jnp.float32,
+                         device_kind=CHIP) == {"tile": 4}
     finally:
         flags.set_flags({"FLAGS_kernel_autotune": False})
         at.reset_runtime_cache()
@@ -203,7 +208,8 @@ def test_sweep_rejects_below_roofline_timings(fresh_cache):
 
     rep = at.autotune("fused_update", *args, cache=cache,
                       timer=impossible_timer, persist=True,
-                      cache_path="/nonexistent/should/never/write.json")
+                      cache_path="/nonexistent/should/never/write.json",
+                      device_kind=CHIP)
     assert rep["n_timed"] == 0
     assert rep["n_rejected_roofline"] == rep["n_validated"] > 0
     assert rep["winner_params"] is None and not rep["persisted"]
@@ -231,7 +237,8 @@ def test_sweep_winner_equal_to_default_not_persisted(fresh_cache):
         return 1.0 if params["tile"] == DEFAULT_TILE else 5.0
 
     rep = at.autotune("fused_update", *args, cache=cache, timer=timer,
-                      cache_path="/nonexistent/never.json")
+                      cache_path="/nonexistent/never.json",
+                      device_kind=CHIP)
     assert rep["winner_params"] == rep["default_params"]
     assert not rep["persisted"] and cache.entries == {}
 

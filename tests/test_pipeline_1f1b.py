@@ -72,7 +72,6 @@ def test_1f1b_matches_sequential(pipe_mesh, M):
             rtol=2e-4, atol=1e-6, err_msg=k)
 
 
-@pytest.mark.requires_vma_shard_map
 def test_1f1b_composes_with_tp():
     """pipe=4 x model=2: column/row-parallel stage matmuls with explicit
     psum — Megatron inside the 1F1B schedule."""
@@ -197,7 +196,6 @@ def test_1f1b_memory_is_o_p_not_o_m(pipe_mesh):
     assert t2 < g2, (t2, g2)
 
 
-@pytest.mark.requires_vma_shard_map
 def test_gpt_1f1b_train_step_matches_single_device():
     """Full-model integration: GPT trained with the 1F1B schedule on a
     pipe2 x model2 x data2 mesh tracks the single-device TrainStep losses."""
@@ -282,7 +280,6 @@ def test_gpt_1f1b_with_ulysses_sequence_parallel():
         mesh_mod.set_mesh(prev)
 
 
-@pytest.mark.requires_vma_shard_map
 def test_gpt_1f1b_bf16_with_remat():
     """Config-4 regime: bf16 params + jax.checkpoint recompute inside the
     hand-scheduled backward — must train (fp32 grad accumulation)."""

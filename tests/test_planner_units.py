@@ -9,9 +9,10 @@ import pytest
 from paddle_tpu.distributed.auto_parallel.planner import (
     enumerate_factorizations,
 )
-from paddle_tpu.jit.aot import (
-    V5E_HBM_BYTES_PER_S, V5E_PEAK_BF16_FLOPS, estimate_step_seconds,
-)
+from paddle_tpu.cost_model import device_peaks
+from paddle_tpu.jit.aot import estimate_step_seconds
+
+V5E_PEAK_BF16_FLOPS, V5E_HBM_BYTES_PER_S = device_peaks("TPU v5 lite")
 
 
 class TestEnumerateFactorizations:
@@ -80,10 +81,12 @@ class TestEstimateStepSeconds:
         assert estimate_step_seconds({"optimal_seconds": -1.0}) is None
         assert estimate_step_seconds({"flops": 0.0}) is None
 
-    def test_custom_peaks(self):
-        out = estimate_step_seconds({"flops": 100.0}, peak_flops=10.0,
-                                    hbm_bw=1.0)
-        assert out["seconds"] == pytest.approx(10.0)
+    def test_peaks_come_from_the_named_device(self):
+        out = estimate_step_seconds({"flops": 2e14}, device_kind="TPU v4")
+        assert out["seconds"] == pytest.approx(
+            2e14 / device_peaks("TPU v4")[0])
+        with pytest.raises(ValueError, match="no published peaks"):
+            estimate_step_seconds({"flops": 2e14}, device_kind="cpu")
 
 
 class TestRankKey:

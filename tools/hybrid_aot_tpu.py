@@ -52,16 +52,19 @@ def build_config_a():
     rs = np.random.RandomState(0)
     crit = GPTPretrainingCriterion()
     mesh_mod.set_mesh(None)
-    cfg = gpt_presets("gpt-test", mode="scan", use_flash_attention=False)
+    # flash ON at a shape the kernel accepts under this mesh: head_dim 128,
+    # one head per 'model' shard, seq 128 (the O(s^2) fallback compiling
+    # instead would go unnoticed with the kernel off)
+    cfg = gpt_presets("gpt-test", mode="scan", hidden_size=256, num_heads=2)
     model = GPTForCausalLM(cfg, seed=0)
     optim = opt.AdamW(learning_rate=1e-4, parameters=model.parameters())
     model, optim, _ = group_sharded_parallel(model, optim, "os_g")
     step = TrainStep(model, lambda lg, lb: crit(lg, lb), optim,
                      batch_spec=P(("data", "sharding")))
-    batch = 16
-    ids = paddle.to_tensor(rs.randint(0, cfg.vocab_size, (batch, 16)),
+    batch, seq = 16, 128
+    ids = paddle.to_tensor(rs.randint(0, cfg.vocab_size, (batch, seq)),
                            dtype="int64")
-    lbl = paddle.to_tensor(rs.randint(0, cfg.vocab_size, (batch, 16)),
+    lbl = paddle.to_tensor(rs.randint(0, cfg.vocab_size, (batch, seq)),
                            dtype="int64")
     mesh_mod.set_mesh(
         topo_mesh("v5e:2x4", {"data": 2, "sharding": 2, "model": 2}))
@@ -125,15 +128,12 @@ def main():
 
     # ---- pallas kernels: first TPU-backend validation (tests run them in
     # CPU interpret mode; this proves the Mosaic lowering itself) ----
-    import jax
     import jax.numpy as jnp
-    from jax.sharding import Mesh, NamedSharding
-    import numpy as np
 
-    from paddle_tpu.framework.target import force_target
-    from paddle_tpu.jit.aot import compile_pallas_flash_for_tpu
+    from paddle_tpu.jit.aot import (
+        compile_for_one_chip, compile_pallas_flash_for_tpu,
+    )
     from paddle_tpu.ops.quant_matmul import quant_matmul
-    from jax.experimental import topologies
 
     b, s, n, d = 8, 1024, 12, 64
     results["pallas_flash_fwd_bwd"] = {
@@ -144,26 +144,16 @@ def main():
     print("pallas flash fwd+bwd TPU compile:",
           results["pallas_flash_fwd_bwd"])
 
-    topo = topologies.get_topology_desc(platform="tpu",
-                                        topology_name="v5e:2x4")
-    mesh1 = Mesh(np.asarray(topo.devices[:1]).reshape(1), ("x",))
-    sh = NamedSharding(mesh1, P())
     SDS = jax.ShapeDtypeStruct
-    # force_target: mesh1 is a raw jax mesh, not the framework's ambient
-    # mesh, so the pallas interpret gate needs the explicit pin
-    with force_target("tpu"):
-        t0 = time.time()
-        x_s = SDS((512, 1024), jnp.bfloat16, sharding=sh)
-        w_s = SDS((1024, 1024), jnp.int8, sharding=sh)
-        sc_s = SDS((1, 1024), jnp.float32, sharding=sh)
-        jax.jit(quant_matmul, in_shardings=(sh, sh, sh)).lower(
-            x_s, w_s, sc_s).compile()
-        results["pallas_int8_matmul"] = {
-            "compile_seconds": round(time.time() - t0, 1),
-            "shape": [512, 1024, 1024], "topology": "v5e (single chip)",
-            "mosaic": True}
-        print("pallas int8 matmul TPU compile:",
-              results["pallas_int8_matmul"])
+    t0 = time.time()
+    compile_for_one_chip(quant_matmul, SDS((512, 1024), jnp.bfloat16),
+                         SDS((1024, 1024), jnp.int8),
+                         SDS((1, 1024), jnp.float32))
+    results["pallas_int8_matmul"] = {
+        "compile_seconds": round(time.time() - t0, 1),
+        "shape": [512, 1024, 1024], "topology": "v5e (single chip)",
+        "mosaic": True}
+    print("pallas int8 matmul TPU compile:", results["pallas_int8_matmul"])
 
     path = os.path.join(REPO, "artifacts", "hybrid_aot_tpu.json")
     with open(path, "w") as f:

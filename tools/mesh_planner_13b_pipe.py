@@ -22,7 +22,9 @@ import time
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-from paddle_tpu.jit.aot import V5E_PEAK_BF16_FLOPS  # noqa: E402
+from paddle_tpu.cost_model import device_peaks  # noqa: E402
+
+V5E_PEAK_BF16_FLOPS = device_peaks("TPU v5 lite")[0]
 
 HBM_BUDGET = 16 * 2**30
 GLOBAL_BATCH, SEQ, N_CHIPS = 64, 2048, 64
