@@ -6,7 +6,12 @@ public namespace mirrors python/paddle/__init__.py of the reference.
 """
 from __future__ import annotations
 
+import time as _time
 import warnings as _warnings
+
+# the `import` span's first stamp (the profiler's clock; the profiler
+# module does not exist yet)
+_t_import = _time.monotonic_ns()
 
 _warnings.filterwarnings("ignore", message=".*truncated to dtype.*")
 
@@ -226,3 +231,7 @@ def disable_signal_handler():
 def tolist(x):
     """paddle.tolist (reference: tensor/manipulation.py tolist)."""
     return x.tolist()
+
+
+# everything above is the package's import: one closed span, a set-up phase
+profiler.record_span("import", _t_import, profiler.now_ns())  # noqa: F821

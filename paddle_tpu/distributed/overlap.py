@@ -57,6 +57,7 @@ import numpy as np
 from ..framework import autograd as _autograd
 from ..observability.flight_recorder import get_flight_recorder
 from ..observability.metrics import get_registry as _get_registry
+from ..profiler import now_ns
 from .grad_comm import GradBucket, GradCommConfig, GradCommunicator
 
 __all__ = [
@@ -283,7 +284,7 @@ class OverlappedGradCommunicator(GradCommunicator):
         from ..profiler import RecordEvent
 
         fut = BucketFuture(bucket)
-        fut.launch_ns = time.perf_counter_ns()
+        fut.launch_ns = now_ns()
         st["futures"][bucket.index] = fut
         # zero-width marker in the MAIN thread's span stream: nests inside
         # the enclosing "backward" span, so the step trace proves the
@@ -301,7 +302,7 @@ class OverlappedGradCommunicator(GradCommunicator):
                        bucket=bucket.index, group=group, phase="launch")
 
         def job():
-            fut.start_ns = time.perf_counter_ns()
+            fut.start_ns = now_ns()
             flightrec.lane(f"comm:bucket{bucket.index}", bucket=bucket.index,
                            group=group, phase="start")
             try:
@@ -323,7 +324,7 @@ class OverlappedGradCommunicator(GradCommunicator):
                 fut._resolve(reduced)
                 flightrec.lane(f"comm:bucket{bucket.index}",
                                bucket=bucket.index, group=group, phase="end")
-            fut.end_ns = time.perf_counter_ns()
+            fut.end_ns = now_ns()
 
         self._lane.submit(job)
 
@@ -367,7 +368,7 @@ class OverlappedGradCommunicator(GradCommunicator):
             for fut in st["futures"].values():
                 fut._done.wait()
             raise RuntimeError(st["dtype_error"])
-        flush_t0 = time.perf_counter_ns()
+        flush_t0 = now_ns()
         with RecordEvent("comm"):     # the EXPOSED comm window of this step
             # stragglers: buckets whose grads appeared outside backward
             # (zero-filled unused params, manual .grad writes) — or a

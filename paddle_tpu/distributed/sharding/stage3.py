@@ -73,6 +73,7 @@ from ...framework.tensor import Tensor
 from ...observability import memory as obs_memory
 from ...observability.flight_recorder import get_flight_recorder
 from ...observability.metrics import get_registry as _get_registry
+from ...profiler import now_ns
 
 __all__ = ["FreedParamValue", "Stage3ParamShards", "zero3_gather_report"]
 
@@ -297,7 +298,7 @@ class Stage3ParamShards:
                     or index in self._futures):
                 return None
             fut = GatherFuture(self.buckets[index])
-            fut.launch_ns = time.perf_counter_ns()
+            fut.launch_ns = now_ns()
             self._futures[index] = fut
             self._state[index] = INFLIGHT
         # zero-width marker in the MAIN thread's span stream: the proof the
@@ -312,7 +313,7 @@ class Stage3ParamShards:
         bucket = self.buckets[index]
 
         def job():
-            fut.start_ns = time.perf_counter_ns()
+            fut.start_ns = now_ns()
             flightrec.lane(f"gather:bucket{index}", bucket=index,
                            group=group, phase="start")
             try:
@@ -328,7 +329,7 @@ class Stage3ParamShards:
                 fut._resolve(full)
                 flightrec.lane(f"gather:bucket{index}", bucket=index,
                                group=group, phase="end")
-            fut.end_ns = time.perf_counter_ns()
+            fut.end_ns = now_ns()
 
         self._lane.submit(job)
         _m_gathers.labels(mode="prefetched").inc()

@@ -20,6 +20,7 @@ import numpy as np
 from ..framework import autograd, random as rng_mod
 from ..framework.device import current_place
 from ..framework.tensor import Tensor
+from ..profiler import RecordEvent, now_ns
 from .functional import FunctionalModule, tree_to_vals, vals_to_tensors
 
 
@@ -270,12 +271,10 @@ class StaticFunction:
         frz = tuple(frozen)
         bv = tuple(bvals)
         if autograd._op_profiler is not None:
-            import time as _time
-
-            t0 = _time.perf_counter_ns()
+            t0 = now_ns()
             out_vals_tree, new_b = entry["fwd"](trk_vals, leaf_vals, frz, bv,
                                                 rng_key)
-            autograd._op_profiler("to_static", t0, _time.perf_counter_ns())
+            autograd._op_profiler("to_static", t0, now_ns())
         else:
             out_vals_tree, new_b = entry["fwd"](trk_vals, leaf_vals, frz, bv,
                                                 rng_key)
@@ -404,10 +403,11 @@ class TrainStep:
         self._fresh_state = False
         self._accum = None
         self._accum_count = 0
-        # newest cache entry + abstract call signature, kept so
-        # memory_analysis() can AOT-lower the exact compiled program
+        # newest cache entry, and each entry's abstract call signature
+        # (made at the entry's first call), kept so memory_analysis() can
+        # AOT-lower the exact compiled program
         self._last_ckey = None
-        self._last_abstract = None
+        self._abstract: Dict[Any, Any] = {}
         # distributed: PartitionSpec for data batches (defaults to sharding the
         # leading dim over the 'data' axis when a mesh is active)
         self._batch_spec = batch_spec
@@ -419,6 +419,12 @@ class TrainStep:
         if m is not None and m.size > 1:
             return m
         return None
+
+    @property
+    def _last_abstract(self):
+        """The newest entry's arguments as ShapeDtypeStructs (None before
+        its first call)."""
+        return self._abstract.get(self._last_ckey)
 
     # ------------------------------------------- in-trace quantized comm
     @property
@@ -673,7 +679,7 @@ class TrainStep:
                                                           (tuple, list))
                                  else [outs])
                         largs += list(vals_to_tensors(lbls_))
-                        with autograd.no_grad():
+                        with autograd.no_grad(), jax.named_scope("loss"):
                             loss_t = loss_fn(*largs)
                         return (loss_t._value.astype(jnp.float32),
                                 (new_b, out_vals))
@@ -728,7 +734,8 @@ class TrainStep:
                     # clip AFTER the sync — global-gradient semantics,
                     # same as the implicit-psum path
                     if clip_cfg is not None:
-                        grads = _apply_clip(grads, clip_cfg)
+                        with jax.named_scope("clip"):
+                            grads = _apply_clip(grads, clip_cfg)
                     # floating buffers computed on the batch shard average
                     # back to one replicated value
                     rep_b = []
@@ -826,7 +833,10 @@ class TrainStep:
                 outs = vals_to_tensors(out_vals)
                 largs = list(outs) if isinstance(outs, (tuple, list)) else [outs]
                 largs += list(vals_to_tensors(lbls))
-                with autograd.no_grad():
+                # the device trace's scopes (jax.named_scope reaches each
+                # op's name there): every model gets `loss`, `clip` and
+                # `optimizer`, and names its own layers
+                with autograd.no_grad(), jax.named_scope("loss"):
                     loss_t = loss_fn(*largs)
                 return loss_t._value.astype(jnp.float32), (new_b, out_vals)
 
@@ -839,8 +849,9 @@ class TrainStep:
                 if gc_fused is not None:
                     # `grads` carries the per-bucket wire payloads; the
                     # fused kernel dequantizes inside the update
-                    new_tp, new_slots = _gc_fused_update(
-                        train_p, slots, grads, lr)
+                    with jax.named_scope("optimizer"):
+                        new_tp, new_slots = _gc_fused_update(
+                            train_p, slots, grads, lr)
                     return (loss, new_tp, new_b, new_slots, new_gc_res,
                             out_vals)
             elif self.grad_fn is not None:
@@ -898,22 +909,26 @@ class TrainStep:
                 )
             if clip_cfg is not None and gc_step is None:
                 # the gc path already clipped inside the shard body
-                grads = _apply_clip(grads, clip_cfg)
+                with jax.named_scope("clip"):
+                    grads = _apply_clip(grads, clip_cfg)
             new_tp, new_slots = [], []
-            for i, (pval, g, s, lm, wd) in enumerate(
-                zip(train_p, grads, slots, lr_mults, wds)
-            ):
-                np_, ns_ = opt._update(pval, g.astype(pval.dtype), s, lr, lm, wd)
-                np_ = np_.astype(pval.dtype)
-                if param_sh is not None:
-                    np_ = jax.lax.with_sharding_constraint(np_, param_sh[i])
-                    ns_ = {
-                        k: jax.lax.with_sharding_constraint(v, param_sh[i])
-                        if getattr(v, "shape", ()) == tuple(pval.shape) else v
-                        for k, v in ns_.items()
-                    }
-                new_tp.append(np_)
-                new_slots.append(ns_)
+            with jax.named_scope("optimizer"):
+                for i, (pval, g, s, lm, wd) in enumerate(
+                    zip(train_p, grads, slots, lr_mults, wds)
+                ):
+                    np_, ns_ = opt._update(pval, g.astype(pval.dtype), s, lr,
+                                           lm, wd)
+                    np_ = np_.astype(pval.dtype)
+                    if param_sh is not None:
+                        def pin(v, sh=param_sh[i]):
+                            return jax.lax.with_sharding_constraint(v, sh)
+
+                        np_ = pin(np_)
+                        ns_ = {k: pin(v) if getattr(v, "shape", ())
+                               == tuple(pval.shape) else v
+                               for k, v in ns_.items()}
+                    new_tp.append(np_)
+                    new_slots.append(ns_)
             # donated-buffer outputs (params, slots, residuals) come BEFORE
             # out_vals: jax pairs donated inputs with outputs of equal
             # abstract shape in order, and a batch-sharded model output that
@@ -975,9 +990,14 @@ class TrainStep:
             train_params = [p for p, m in zip(fm.params, fm.trainable_mask)
                             if m]
             cur_slots = self._slots or [None] * len(train_params)
-            self._slots = [_carry(p, cur)
-                           for p, cur in zip(train_params, cur_slots)]
+            with RecordEvent("jit_step.state_init"):
+                self._slots = [_carry(p, cur)
+                               for p, cur in zip(train_params, cur_slots)]
             self._fresh_state = True
+            # imported slots may differ in dtype or structure from the ones
+            # the entries were first called with: each entry's next call
+            # makes its signature anew (and may compile again)
+            self._abstract.clear()
         # in-trace grad-comm carried state: the per-bucket error-feedback
         # residuals ride in and out of the jitted step as an aux pytree
         _gc_axes, gc_world = self._gc_world(self._mesh())
@@ -999,9 +1019,10 @@ class TrainStep:
                             rows, b.size))
         ckey = (_abstract_key(in_vals), _abstract_key(lbl_vals))
         if ckey not in self._cache:
-            self._cache[ckey] = self._compile(
-                self._build(), self._slots, in_vals, lbl_vals, gc_res
-            )
+            with RecordEvent("jit_step.build"):
+                self._cache[ckey] = self._compile(
+                    self._build(), self._slots, in_vals, lbl_vals, gc_res
+                )
         self._last_ckey = ckey
         pvals = fm.param_values()
         train_p = [v for v, m in zip(pvals, fm.trainable_mask) if m]
@@ -1013,8 +1034,14 @@ class TrainStep:
         return self._cache[ckey], args, gc_buckets
 
     def __call__(self, inputs, labels=()):
-        fm = self.fm
-        step, args, gc_buckets = self._step_args(inputs, labels)
+        """One step. The host work is one `jit_step` span whose children
+        name its parts (profiler.RecordEvent: in the registry's per-span
+        totals always, in the device trace when a jax.profiler session is
+        on)."""
+        with RecordEvent("jit_step"):
+            return self._call(inputs, labels)
+
+    def _place(self, args):
         if self._mesh() is not None:
             # place every operand on its target sharding (no-op when already
             # there); jit-with-in_shardings rejects mismatched placements
@@ -1022,44 +1049,62 @@ class TrainStep:
                                                 args[8])
             in_sh, _ = self._shardings(None, slots, in_vals, lbl_vals,
                                        gc_res)
-            args = jax.tree_util.tree_map(jax.device_put, args, in_sh)
-        elif self._fresh_state:
-            # fresh parameters and slots are uncommitted arrays and the step
-            # hands them back committed: run on them as they are and jit
-            # compiles the same program again for the second call. Commit
-            # the carried state once, here (744 leaves for gpt-125m — not
-            # per call; key/lr/batch arrive the same way every call) — but
-            # only beside a batch that sits on the current place: a batch a
-            # caller sharded itself keeps deciding where the step runs.
-            dev = current_place().jax_device
-            if all(v.devices() == {dev}
-                   for v in jax.tree_util.tree_leaves(args[7:])):
-                args = jax.device_put(args[:5], dev) + args[5:]
+            return jax.tree_util.tree_map(jax.device_put, args, in_sh)
+        # fresh parameters and slots are uncommitted arrays and the step
+        # hands them back committed: run on them as they are and jit
+        # compiles the same program again for the second call. Commit the
+        # carried state once, here (744 leaves for gpt-125m — not per call;
+        # key/lr/batch arrive the same way every call) — but only beside a
+        # batch that sits on the current place: a batch a caller sharded
+        # itself keeps deciding where the step runs.
+        dev = current_place().jax_device
+        if all(v.devices() == {dev}
+               for v in jax.tree_util.tree_leaves(args[7:])):
+            return jax.device_put(args[:5], dev) + args[5:]
+        return args
+
+    def _call(self, inputs, labels):
+        fm = self.fm
+        with RecordEvent("jit_step.args"):
+            step, args, gc_buckets = self._step_args(inputs, labels)
+        if self._mesh() is not None or self._fresh_state:
+            with RecordEvent("jit_step.place"):
+                args = self._place(args)
         self._fresh_state = False
-        # abstract signature BEFORE the call: donated buffers (params,
-        # slots) are deleted by the step, but memory_analysis() only needs
-        # their shapes/dtypes
-        self._last_abstract = jax.tree_util.tree_map(
-            lambda v: jax.ShapeDtypeStruct(v.shape, v.dtype), args)
-        loss, new_tp, new_b, new_slots, new_gc_res, out_vals = step(*args)
-        ti = 0
-        for p, m in zip(fm.params, fm.trainable_mask):
-            if m:
-                p._value = new_tp[ti]
-                ti += 1
-        fm.bind_buffers(new_b)
-        self._slots = new_slots
-        if gc_buckets is not None:
-            for b, r in zip(gc_buckets, new_gc_res):
-                self._gc_comm._residuals[b.index] = r
-            self._account_gc_step(gc_buckets,
-                                  self._gc_world(self._mesh())[1])
-        self.optimizer._accumulated_steps += 1
-        mark = getattr(self.optimizer, "_mark_slot_writer", None)
-        if mark is not None:
-            mark(self)
-        t = Tensor(loss, _internal=True)
-        self.last_outputs = vals_to_tensors(out_vals)
+        # an entry's first call (and its first with newly imported state)
+        # makes its abstract signature, BEFORE the call: donated buffers
+        # (params, slots) are deleted by the step, but memory_analysis()
+        # only needs their shapes/dtypes
+        first = self._last_ckey not in self._abstract
+        if first:
+            self._abstract[self._last_ckey] = jax.tree_util.tree_map(
+                lambda v: jax.ShapeDtypeStruct(v.shape, v.dtype), args)
+        # `dispatch` is where the host blocks once the runtime's steps in
+        # flight are full; an entry's first call also traces, lowers and
+        # compiles (or loads the cache), so it goes by another name
+        with RecordEvent("jit_step.first_call" if first
+                         else "jit_step.dispatch"):
+            loss, new_tp, new_b, new_slots, new_gc_res, out_vals = \
+                step(*args)
+        with RecordEvent("jit_step.rebind"):
+            ti = 0
+            for p, m in zip(fm.params, fm.trainable_mask):
+                if m:
+                    p._value = new_tp[ti]
+                    ti += 1
+            fm.bind_buffers(new_b)
+            self._slots = new_slots
+            if gc_buckets is not None:
+                for b, r in zip(gc_buckets, new_gc_res):
+                    self._gc_comm._residuals[b.index] = r
+                self._account_gc_step(gc_buckets,
+                                      self._gc_world(self._mesh())[1])
+            self.optimizer._accumulated_steps += 1
+            mark = getattr(self.optimizer, "_mark_slot_writer", None)
+            if mark is not None:
+                mark(self)
+            t = Tensor(loss, _internal=True)
+            self.last_outputs = vals_to_tensors(out_vals)
         return t
 
     def memory_analysis(self, record=True, entry=None):

@@ -42,6 +42,7 @@ from ..nn import initializer as I
 from ..nn.layer.common import Dropout, Embedding
 from ..nn.layer.layers import Layer
 from ..nn.layer.norm import LayerNorm
+from ..profiler import RecordEvent
 from jax.sharding import PartitionSpec as P
 
 BATCH_AXES = ("data", "sharding")  # batch is sharded over dp × zero-dp
@@ -212,27 +213,28 @@ def _block_apply(pd: dict, x, cfg: GPTConfig):
         out = (v.astype(jnp.float32) - mu) * jax.lax.rsqrt(var + eps)
         return (out * w + bi).astype(v.dtype)
 
-    # --- attention
-    hn = ln(x, pd["ln1_w"], pd["ln1_b"])
-    qkv = jnp.einsum("bsh,hcj->bscj", hn, pd["qkv_w"]) + pd["qkv_b"]
-    qkv = qkv.reshape(b, s, 3, n, d)  # [b,s,3,H] col-sharded on 'model'
-    q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-    q = _constrain_val(q, BATCH_AXES, SEQ_AXIS, MODEL_AXIS, None)
-    k = _constrain_val(k, BATCH_AXES, SEQ_AXIS, MODEL_AXIS, None)
-    v = _constrain_val(v, BATCH_AXES, SEQ_AXIS, MODEL_AXIS, None)
-    attn = _attention_val(q, k, v, cfg)
-    attn = attn.reshape(b, s, h)
-    y = attn @ pd["out_w"] + pd["out_b"]  # row-sharded: GSPMD allreduces
-    x = x + y
-    x = _constrain_val(x, BATCH_AXES, SEQ_AXIS, None)
+    # the scopes carry no layer index: a trace's reader adds the layers up
+    with jax.named_scope("attn"):
+        hn = ln(x, pd["ln1_w"], pd["ln1_b"])
+        qkv = jnp.einsum("bsh,hcj->bscj", hn, pd["qkv_w"]) + pd["qkv_b"]
+        qkv = qkv.reshape(b, s, 3, n, d)  # [b,s,3,H] col-sharded on 'model'
+        q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+        q = _constrain_val(q, BATCH_AXES, SEQ_AXIS, MODEL_AXIS, None)
+        k = _constrain_val(k, BATCH_AXES, SEQ_AXIS, MODEL_AXIS, None)
+        v = _constrain_val(v, BATCH_AXES, SEQ_AXIS, MODEL_AXIS, None)
+        attn = _attention_val(q, k, v, cfg)
+        attn = attn.reshape(b, s, h)
+        y = attn @ pd["out_w"] + pd["out_b"]  # row-sharded: GSPMD allreduces
+        x = x + y
+        x = _constrain_val(x, BATCH_AXES, SEQ_AXIS, None)
 
-    # --- mlp
-    hn = ln(x, pd["ln2_w"], pd["ln2_b"])
-    z = hn @ pd["fc1_w"] + pd["fc1_b"]
-    z = jax.nn.gelu(z, approximate=True)
-    z = z @ pd["fc2_w"] + pd["fc2_b"]
-    x = x + z
-    return _constrain_val(x, BATCH_AXES, SEQ_AXIS, None)
+    with jax.named_scope("mlp"):
+        hn = ln(x, pd["ln2_w"], pd["ln2_b"])
+        z = hn @ pd["fc1_w"] + pd["fc1_b"]
+        z = jax.nn.gelu(z, approximate=True)
+        z = z @ pd["fc2_w"] + pd["fc2_b"]
+        x = x + z
+        return _constrain_val(x, BATCH_AXES, SEQ_AXIS, None)
 
 
 def _block_apply_manual(pd: dict, x, cfg: GPTConfig, mesh):
@@ -252,57 +254,59 @@ def _block_apply_manual(pd: dict, x, cfg: GPTConfig, mesh):
         out = (v.astype(jnp.float32) - mu) * jax.lax.rsqrt(var + eps)
         return (out * w + bi).astype(v.dtype)
 
-    hn = ln(x, pd["ln1_w"], pd["ln1_b"])
-    qkv = jnp.einsum("bsh,hcj->bscj", hn, pd["qkv_w"]) + pd["qkv_b"]
-    n_loc = qkv.shape[-1] // d                    # local head count (H/mp)/d
-    qkv = qkv.reshape(b, s, 3, n_loc, d)
-    q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-    if has_sep:
-        if cfg.use_ulysses_attention:
-            from ..distributed.ulysses import ulysses_attention_manual
+    with jax.named_scope("attn"):
+        hn = ln(x, pd["ln1_w"], pd["ln1_b"])
+        qkv = jnp.einsum("bsh,hcj->bscj", hn, pd["qkv_w"]) + pd["qkv_b"]
+        n_loc = qkv.shape[-1] // d                    # local head count (H/mp)/d
+        qkv = qkv.reshape(b, s, 3, n_loc, d)
+        q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+        if has_sep:
+            if cfg.use_ulysses_attention:
+                from ..distributed.ulysses import ulysses_attention_manual
 
-            attn = ulysses_attention_manual(
-                q, k, v, SEQ_AXIS, causal=True,
-                use_flash=(cfg.use_flash_attention
-                           and cfg.attn_dropout == 0.0))
+                attn = ulysses_attention_manual(
+                    q, k, v, SEQ_AXIS, causal=True,
+                    use_flash=(cfg.use_flash_attention
+                               and cfg.attn_dropout == 0.0))
+            else:
+                from ..distributed.ring_attention import ring_attention_manual
+
+                attn = ring_attention_manual(q, k, v, SEQ_AXIS,
+                                             mesh.shape[SEQ_AXIS], causal=True)
         else:
-            from ..distributed.ring_attention import ring_attention_manual
+            attn = None
+            from ..framework.target import target_platform
 
-            attn = ring_attention_manual(q, k, v, SEQ_AXIS,
-                                         mesh.shape[SEQ_AXIS], causal=True)
-    else:
-        attn = None
-        from ..framework.target import target_platform
+            if (cfg.use_flash_attention and cfg.attn_dropout == 0.0
+                    and target_platform() == "tpu"):
+                from ..ops.flash_attention import (
+                    flash_attention_supported, flash_attention_val,
+                )
 
-        if (cfg.use_flash_attention and cfg.attn_dropout == 0.0
-                and target_platform() == "tpu"):
-            from ..ops.flash_attention import (
-                flash_attention_supported, flash_attention_val,
-            )
+                if flash_attention_supported(q.shape):
+                    attn = flash_attention_val(q, k, v, causal=True)
+            if attn is None:
+                scale = 1.0 / math.sqrt(d)
+                logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
+                causal = jnp.tril(jnp.ones((s, s), dtype=bool))
+                logits = jnp.where(causal, logits, jnp.finfo(logits.dtype).min)
+                probs = jax.nn.softmax(logits.astype(jnp.float32),
+                                       axis=-1).astype(v.dtype)
+                attn = jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+        attn = attn.reshape(b, s, n_loc * d)
+        y = attn @ pd["out_w"]                        # row-sharded: partial sums
+        if has_model:
+            y = coll.in_trace_psum(y, MODEL_AXIS)
+        x = x + y + pd["out_b"]
 
-            if flash_attention_supported(q.shape):
-                attn = flash_attention_val(q, k, v, causal=True)
-        if attn is None:
-            scale = 1.0 / math.sqrt(d)
-            logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
-            causal = jnp.tril(jnp.ones((s, s), dtype=bool))
-            logits = jnp.where(causal, logits, jnp.finfo(logits.dtype).min)
-            probs = jax.nn.softmax(logits.astype(jnp.float32),
-                                   axis=-1).astype(v.dtype)
-            attn = jnp.einsum("bhqk,bkhd->bqhd", probs, v)
-    attn = attn.reshape(b, s, n_loc * d)
-    y = attn @ pd["out_w"]                        # row-sharded: partial sums
-    if has_model:
-        y = coll.in_trace_psum(y, MODEL_AXIS)
-    x = x + y + pd["out_b"]
-
-    hn = ln(x, pd["ln2_w"], pd["ln2_b"])
-    z = hn @ pd["fc1_w"] + pd["fc1_b"]
-    z = jax.nn.gelu(z, approximate=True)
-    z = z @ pd["fc2_w"]
-    if has_model:
-        z = coll.in_trace_psum(z, MODEL_AXIS)
-    return x + z + pd["fc2_b"]
+    with jax.named_scope("mlp"):
+        hn = ln(x, pd["ln2_w"], pd["ln2_b"])
+        z = hn @ pd["fc1_w"] + pd["fc1_b"]
+        z = jax.nn.gelu(z, approximate=True)
+        z = z @ pd["fc2_w"]
+        if has_model:
+            z = coll.in_trace_psum(z, MODEL_AXIS)
+        return x + z + pd["fc2_b"]
 
 
 _BLOCK_PARAMS = ("ln1_w", "ln1_b", "qkv_w", "qkv_b", "out_w", "out_b",
@@ -543,16 +547,19 @@ class GPTEmbeddings(Layer):
             pe = jax.lax.dynamic_slice_in_dim(pos, 0, s, axis=0)
             return emb + pe
 
-        if position_ids is None:
-            x = call_op(fn, self.word_embeddings, self.position_embeddings,
-                        input_ids, op_name="gpt_embed")
-        else:
-            x = call_op(
-                lambda w, pos, ids, pid: jnp.take(w, ids, 0) + jnp.take(pos, pid, 0),
-                self.word_embeddings, self.position_embeddings, input_ids,
-                position_ids, op_name="gpt_embed")
-        x = mesh_mod.constrain(x, BATCH_AXES, SEQ_AXIS, None)
-        return self.dropout(x)
+        with jax.named_scope("embed"):
+            if position_ids is None:
+                x = call_op(fn, self.word_embeddings,
+                            self.position_embeddings, input_ids,
+                            op_name="gpt_embed")
+            else:
+                x = call_op(
+                    lambda w, pos, ids, pid: (jnp.take(w, ids, 0)
+                                              + jnp.take(pos, pid, 0)),
+                    self.word_embeddings, self.position_embeddings,
+                    input_ids, position_ids, op_name="gpt_embed")
+            x = mesh_mod.constrain(x, BATCH_AXES, SEQ_AXIS, None)
+            return self.dropout(x)
 
 
 class GPTModel(Layer):
@@ -589,23 +596,30 @@ class GPTForCausalLM(Layer):
 
     def __init__(self, config: GPTConfig, seed: int = 0):
         super().__init__()
-        self.gpt = GPTModel(config, seed=seed)
+        # the numpy draws and their uploads: a set-up phase of its own
+        with RecordEvent("model_init"):
+            self.gpt = GPTModel(config, seed=seed)
         self.config = config
 
     def forward(self, input_ids, position_ids=None, labels=None):
         x = self.gpt(input_ids, position_ids)
         w = self.gpt.embeddings.word_embeddings
-        if labels is not None and self.config.fused_loss_chunk > 0:
-            # fused chunked linear+CE: logits never hit HBM whole
-            from ..incubate.nn.functional import fused_linear_cross_entropy
+        with jax.named_scope("lm_head"):
+            if labels is not None and self.config.fused_loss_chunk > 0:
+                # fused chunked linear+CE: logits never hit HBM whole
+                from ..incubate.nn.functional import (
+                    fused_linear_cross_entropy,
+                )
 
-            h = x.reshape([-1, self.config.hidden_size])
-            return fused_linear_cross_entropy(
-                h, w, labels.reshape([-1]),
-                vocab_chunk=self.config.fused_loss_chunk,
-                transposed_weight=True)
-        logits = call_op(lambda h, wv: h @ wv.T, x, w, op_name="gpt_logits")
-        return mesh_mod.constrain(logits, BATCH_AXES, SEQ_AXIS, MODEL_AXIS)
+                h = x.reshape([-1, self.config.hidden_size])
+                return fused_linear_cross_entropy(
+                    h, w, labels.reshape([-1]),
+                    vocab_chunk=self.config.fused_loss_chunk,
+                    transposed_weight=True)
+            logits = call_op(lambda h, wv: h @ wv.T, x, w,
+                             op_name="gpt_logits")
+            return mesh_mod.constrain(logits, BATCH_AXES, SEQ_AXIS,
+                                      MODEL_AXIS)
 
 
 class GPTPretrainingCriterion(Layer):

@@ -15,6 +15,10 @@ tentpole):
   checkpoint commits, NaN trips and watchdog stalls;
   ``FLAGS_enable_rpc_profiler`` additionally streams per-collective events
   into it (the reference's RPC profiler, reinterpreted).
+- ``host_spans`` (host_spans.py): the always-installed span sink — every
+  profiler.RecordEvent end adds to ``host_span_seconds_total{span}`` and
+  ``host_span_calls_total{span}``, so set-up phases and step spans can be
+  read after the fact (the flight recorder's ring evicts).
 - ``StepTimer`` (step_timer.py): per-step data / forward / backward /
   optimizer / comm / checkpoint breakdown assembled from nested
   RecordEvent spans; ``breakdown_from_trace`` recomputes it offline from a

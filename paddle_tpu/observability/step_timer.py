@@ -66,6 +66,9 @@ class StepTimer:
         self.phases = tuple(phases)
         self.steps: List[dict] = []     # one closed row per step()
         self._current: Dict[str, float] = {}
+        # this step's phase spans as the sink got them: (phase, start_ns,
+        # end_ns) on the profiler's clock (time.monotonic_ns)
+        self._spans: List[tuple] = []
         self._step_t0 = None
         self._active = False
         self._registry = registry       # optional MetricsRegistry mirror
@@ -78,7 +81,8 @@ class StepTimer:
             _prof.add_span_sink(self._on_span)
             self._active = True
         self._current = {}
-        self._step_t0 = time.perf_counter()
+        self._spans = []
+        self._step_t0 = time.monotonic()
         return self
 
     def stop(self):
@@ -102,10 +106,11 @@ class StepTimer:
         if ph is not None:
             self._current[ph] = self._current.get(ph, 0.0) + \
                 (end_ns - start_ns) / 1e9
+            self._spans.append((ph, start_ns, end_ns))
 
     def step(self) -> dict:
         """Close the current step: record its phase row and reset."""
-        now = time.perf_counter()
+        now = time.monotonic()
         wall = now - self._step_t0 if self._step_t0 is not None else 0.0
         row = {ph: self._current.get(ph, 0.0) for ph in self.phases}
         row["total"] = wall
@@ -115,32 +120,28 @@ class StepTimer:
             h = self._registry.histogram("step_time_seconds",
                                          help="wall time per training step")
             h.observe(wall)
-        self._trace_step(row, step_index=len(self.steps) - 1)
+        self._trace_step(now - wall, now, step_index=len(self.steps) - 1)
         self._current = {}
+        self._spans = []
         self._step_t0 = now
         return row
 
-    def _trace_step(self, row: dict, step_index: int):
+    def _trace_step(self, t_start: float, t_end: float, step_index: int):
         """Mint a per-step trace so checkpoint/comm/optimizer phases share
         the timeline store (and /traces endpoint) with serve requests.
-        Phase spans carry the measured duration; their t_start is
-        back-computed from the step-close instant (the profiler sink only
-        hands us durations), so within a step they overlap — readers
-        should order by span_id, not t_start."""
+        Each phase span is the interval its RecordEvent had: the profiler
+        and the tracer read one clock."""
         from .tracing import get_tracer
 
         tracer = get_tracer()
         ctx = tracer.start_trace("train_step", step=step_index)
         if ctx is None:
             return
-        now = time.monotonic()
-        tracer.record_span(ctx, "step", t_start=now - row["total"],
-                           t_end=now, step=step_index)
-        for ph in tuple(self.phases) + ("other",):
-            sec = row.get(ph, 0.0)
-            if sec > 0.0:
-                tracer.record_span(ctx, ph, t_start=now - sec, t_end=now,
-                                   step=step_index)
+        tracer.record_span(ctx, "step", t_start=t_start, t_end=t_end,
+                           step=step_index)
+        for ph, start_ns, end_ns in self._spans:
+            tracer.record_span(ctx, ph, t_start=start_ns / 1e9,
+                               t_end=end_ns / 1e9, step=step_index)
 
     # ------------------------------------------------------------ reports
     def breakdown(self) -> dict:

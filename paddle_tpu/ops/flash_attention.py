@@ -197,6 +197,7 @@ def _fwd(q, k, v, causal, block_q, block_k):
             pltpu.VMEM((block_q, _LANES), jnp.float32),
         ],
         interpret=_interpret(),
+        name="flash_fwd",
     )(q, k, v)
     return out, lse
 
@@ -308,6 +309,7 @@ def _bwd(q, k, v, out, lse, do, causal, block_q, block_k):
         out_shape=_sds((b, n, s, d), q.dtype, q),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=_interpret(),
+        name="flash_bwd_dq",
     )(q, k, v, do, lse, delta)
 
     # dkv: grid (b, n, kv_blocks, q_blocks) — q innermost
@@ -328,6 +330,7 @@ def _bwd(q, k, v, out, lse, do, causal, block_q, block_k):
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
         interpret=_interpret(),
+        name="flash_bwd_dkv",
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 

@@ -12,6 +12,14 @@ the same thread, so chrome traces and tools/trace_report.py can reconstruct
 nesting instead of guessing from time overlap. Every span end is also
 streamed to registered span sinks (observability.StepTimer subscribes to
 build per-step phase breakdowns), profiler active or not.
+
+One span stream, one clock. RecordEvent is the program's one span type: it
+also opens a ``jax.profiler.TraceAnnotation`` named ``pt.<name>``, so any
+active jax.profiler session (this module's Profiler(targets=[TPU]), or a
+caller's own start_trace) holds the program's spans beside the device's
+operations, on the trace's clock. Every host stamp of the program comes
+from ``now_ns()`` (time.monotonic_ns: the clock Tracer, FlightRecorder and
+EventLog read), so no two stamps of one trace are on different clocks.
 """
 from __future__ import annotations
 
@@ -22,13 +30,23 @@ import threading
 import time
 from typing import Callable, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 from ..framework import autograd
+from ..observability.host_spans import on_span as _host_span_totals
 
 __all__ = [
     "Profiler", "RecordEvent", "ProfilerTarget", "ProfilerState",
     "make_scheduler", "export_chrome_tracing", "load_profiler_result",
-    "SummaryView", "add_span_sink", "remove_span_sink",
+    "SummaryView", "add_span_sink", "remove_span_sink", "now_ns",
+    "record_span",
 ]
+
+# a RecordEvent named `x` is the host event `pt.x` in a jax.profiler trace
+TRACE_PREFIX = "pt."
+
+# the program's one host clock, in ns
+now_ns = time.monotonic_ns
 
 
 class ProfilerTarget:
@@ -89,8 +107,9 @@ _event_ids = itertools.count(1)
 
 # span sinks: called as sink(name, start_ns, end_ns, tid) on EVERY
 # RecordEvent end, whether or not a profiler is recording
-# (observability.StepTimer registers here)
-_span_sinks: List[Callable] = []
+# (observability.StepTimer registers here). The first is always installed:
+# the registry's per-span totals, which outlive the flight recorder's ring.
+_span_sinks: List[Callable] = [_host_span_totals]
 
 
 def add_span_sink(sink: Callable) -> Callable:
@@ -117,6 +136,29 @@ def _current_span_id() -> Optional[int]:
     return s[-1] if s else None
 
 
+def _emit(name, start_ns, end_ns, eid, parent_id):
+    """One finished span to the recording profiler and to every sink."""
+    tid = threading.get_ident()
+    prof = _active_profiler
+    if prof is not None and prof._recording:
+        prof._add(_Event(name, start_ns, end_ns, tid, "user", eid=eid,
+                         parent_id=parent_id))
+    for sink in _span_sinks:
+        try:
+            sink(name, start_ns, end_ns, tid)
+        except Exception:
+            # a broken sink must not sink the training loop — but the
+            # fault is recorded, not swallowed (rule C003)
+            _log_profiler_fault(f"span sink failed for {name!r}")
+
+
+def record_span(name, start_ns, end_ns):
+    """A span that already ended, from two `now_ns()` stamps: for work that
+    starts before a RecordEvent can exist (the package's own import). It
+    reaches the profiler and the sinks, not a device trace."""
+    _emit(name, start_ns, end_ns, next(_event_ids), _current_span_id())
+
+
 class RecordEvent:
     """RAII host-event marker (platform/profiler.cc RecordEvent analog).
 
@@ -130,34 +172,27 @@ class RecordEvent:
         self._t0 = None
         self._id = None
         self._parent_id = None
+        self._annotation = None
 
     def begin(self):
         self._id = next(_event_ids)
         self._parent_id = _current_span_id()
         _stack().append(self._id)
-        self._t0 = time.perf_counter_ns()
+        self._annotation = TraceAnnotation(TRACE_PREFIX + self.name)
+        self._annotation.__enter__()
+        self._t0 = now_ns()
 
     def end(self):
         if self._t0 is None:
             return
-        t1 = time.perf_counter_ns()
+        t1 = now_ns()
+        self._annotation.__exit__(None, None, None)
         s = _stack()
         if s and s[-1] == self._id:
             s.pop()
         elif self._id in s:        # misnested explicit begin()/end(): unwind
             del s[s.index(self._id):]
-        tid = threading.get_ident()
-        prof = _active_profiler
-        if prof is not None and prof._recording:
-            prof._add(_Event(self.name, self._t0, t1, tid, "user",
-                             eid=self._id, parent_id=self._parent_id))
-        for sink in _span_sinks:
-            try:
-                sink(self.name, self._t0, t1, tid)
-            except Exception:
-                # a broken sink must not sink the training loop — but the
-                # fault is recorded, not swallowed (rule C003)
-                _log_profiler_fault(f"span sink failed for {self.name!r}")
+        _emit(self.name, self._t0, t1, self._id, self._parent_id)
         self._t0 = None
 
     def __enter__(self):
@@ -278,8 +313,13 @@ class Profiler:
             import jax
 
             self._device_trace_dir = tempfile.mkdtemp(prefix="paddle_tpu_xplane_")
+            # the host's Python call tracer is off: it costs far more than
+            # its spans are worth, and the program's own are RecordEvents
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 0
             try:
-                jax.profiler.start_trace(self._device_trace_dir)
+                jax.profiler.start_trace(self._device_trace_dir,
+                                         profiler_options=options)
             except Exception:
                 self._device_trace_dir = None
                 _log_profiler_fault("device trace start failed")

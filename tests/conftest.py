@@ -86,6 +86,19 @@ def fresh_mesh():
     mesh_mod.set_mesh(prev)
 
 
+@pytest.fixture(autouse=True, scope="module")
+def _no_mesh_leaks_to_the_next_file():
+    """A mesh one file leaves behind fails whichever eager file the xdist
+    worker runs next (`Cannot convert GSPMDSharding {maximal device=0}`),
+    and which file that is changes with the files' order: every file hands
+    on the mesh it found."""
+    from paddle_tpu.distributed import mesh as mesh_mod
+
+    prev = mesh_mod.get_mesh()
+    yield
+    mesh_mod.set_mesh(prev)
+
+
 def pytest_terminal_summary(terminalreporter, exitstatus, config):
     """Surface skipped AOT regression gates at suite end (VERDICT r4 #9:
     libtpu-lock contention must not silently disable test_tpu_aot)."""

@@ -233,3 +233,301 @@ def test_nan_inf_flag_roundtrip():
     flags = paddle.get_flags(["FLAGS_check_nan_inf"])
     assert flags["FLAGS_check_nan_inf"] is False
     jax.config.update("jax_debug_nans", False)
+
+
+# ---------------------------------------------------------------- ISSUE 25
+# one span stream, one clock: RecordEvent in the jax.profiler trace, the
+# registry's per-span totals, and the train step's host spans
+
+def _host_events(trace_dir, prefix="pt."):
+    """[(name, start_ns, end_ns, (plane, line))] of one jax.profiler trace."""
+    import glob
+
+    import jax
+
+    (path,) = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
+                                     "*.xplane.pb"))
+    out = []
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            out.extend((e.name, e.start_ns, e.start_ns + e.duration_ns,
+                        (plane.name, line.name))
+                       for e in line.events if e.name.startswith(prefix))
+    return out
+
+
+def _span_tree_in_a_trace():
+    import time
+
+    with RecordEvent("outer"):
+        with RecordEvent("inner:detail"):
+            time.sleep(0.002)
+        with RecordEvent("sibling"):
+            time.sleep(0.001)
+
+
+def _assert_nested(events):
+    by = {name: (s, e, where) for name, s, e, where in events}
+    assert set(by) == {"pt.outer", "pt.inner:detail", "pt.sibling"}
+    o, i, s = by["pt.outer"], by["pt.inner:detail"], by["pt.sibling"]
+    assert o[2] == i[2] == s[2]                     # one thread's line
+    assert o[0] <= i[0] and i[1] <= s[0] and s[1] <= o[1]
+    assert i[1] - i[0] >= 2e6
+
+
+def test_record_event_tree_is_nested_in_a_jax_profiler_trace(tmp_path):
+    import jax
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        _span_tree_in_a_trace()
+    finally:
+        jax.profiler.stop_trace()
+    _assert_nested(_host_events(str(tmp_path)))
+
+
+def test_the_programs_own_profiler_writes_the_same_trace():
+    """Profiler(targets=[TPU]) starts jax.profiler's trace with the Python
+    call tracer off: its file holds the program's spans and no `$file:line
+    function` event per Python call."""
+    with Profiler(targets=[ProfilerTarget.TPU]) as prof:
+        _span_tree_in_a_trace()
+        sum(i * i for i in range(2000))             # Python calls to trace
+    assert prof.device_trace_dir
+    _assert_nested(_host_events(prof.device_trace_dir))
+    assert not _host_events(prof.device_trace_dir, prefix="$")
+    tree = prof.span_tree()                         # the host tree, as ever
+    assert [n["event"].name for n in tree] == ["outer"]
+    assert [c["event"].name for c in tree[0]["children"]] == \
+        ["inner:detail", "sibling"]
+
+
+def test_record_event_stamps_are_on_time_monotonic_ns():
+    import time
+
+    assert profiler.now_ns is time.monotonic_ns
+    t0 = time.monotonic_ns()
+    with Profiler() as prof:
+        with RecordEvent("stamped"):
+            pass
+    t1 = time.monotonic_ns()
+    (ev,) = [e for e in prof.events if e.name == "stamped"]
+    assert t0 <= ev.start_ns <= ev.end_ns <= t1
+
+
+def test_no_stamp_of_the_program_reads_another_clock():
+    """The op hook, the futures' launch/start/end and RecordEvent are
+    compared with each other: all come from profiler.now_ns."""
+    package = os.path.dirname(os.path.abspath(paddle.__file__))
+    found = []
+    for d, _, files in os.walk(package):
+        for f in files:
+            if f.endswith(".py"):
+                with open(os.path.join(d, f)) as fh:
+                    if "perf_counter_ns" in fh.read():
+                        found.append(os.path.join(d, f))
+    assert found == []
+
+
+def test_host_span_totals_count_calls_and_seconds_by_base_name():
+    import time
+
+    from paddle_tpu.observability import get_registry
+
+    reg = get_registry()
+
+    def read(family, span):
+        fam = reg.get(family)
+        return dict((lb["span"], c.value) for lb, c in fam.items()).get(
+            span, 0)
+
+    before = {(f, s): read(f, s)
+              for f in ("host_span_calls_total", "host_span_seconds_total")
+              for s in ("i25comm", "i25comm:bucket3", "i25other")}
+    for i in range(3):
+        with RecordEvent(f"i25comm:bucket{i}"):
+            time.sleep(0.002)
+    with RecordEvent("i25comm"):
+        pass
+    with RecordEvent("i25other"):
+        pass
+
+    def grew(family, span):
+        return read(family, span) - before[(family, span)]
+
+    assert grew("host_span_calls_total", "i25comm") == 4
+    assert grew("host_span_calls_total", "i25other") == 1
+    assert read("host_span_calls_total", "i25comm:bucket3") == 0
+    assert 0.006 <= grew("host_span_seconds_total", "i25comm") < 0.5
+    text = reg.to_prometheus()
+    assert 'host_span_calls_total{span="i25comm"}' in text
+
+
+def test_record_span_is_a_closed_span_for_the_profiler_and_the_sinks():
+    seen = []
+    sink = profiler.add_span_sink(lambda *a: seen.append(a))
+    try:
+        with Profiler() as prof:
+            with RecordEvent("around"):
+                t = profiler.now_ns()
+                profiler.record_span("closed", t - 5_000_000, t)
+    finally:
+        profiler.remove_span_sink(sink)
+    ev = {e.name: e for e in prof.events}
+    assert ev["closed"].end_ns - ev["closed"].start_ns == 5_000_000
+    assert ev["closed"].parent_id == ev["around"].id
+    assert [a[0] for a in seen] == ["closed", "around"]
+
+
+def test_the_packages_import_is_a_span():
+    from paddle_tpu.observability import get_registry
+
+    fam = get_registry().get("host_span_calls_total")
+    calls = {lb["span"]: c.value for lb, c in fam.items()}
+    # a registry reset (other tests do) zeroes it: present either way
+    assert "import" in calls
+
+
+def test_step_timer_traces_each_phase_at_its_own_interval():
+    """The sink is handed start and end: the per-step trace's phase spans
+    are the intervals the RecordEvents had, in order, not durations hung
+    on the step's end."""
+    import time
+
+    from paddle_tpu.observability import StepTimer, get_tracer
+
+    tracer = get_tracer()
+    timer = StepTimer().start()
+    with RecordEvent("forward"):
+        time.sleep(0.003)
+    with RecordEvent("backward"):
+        time.sleep(0.002)
+    with RecordEvent("optimizer"):
+        time.sleep(0.001)
+    row = timer.step()
+    timer.stop()
+    assert row["forward"] >= 0.003 and row["backward"] >= 0.002
+    index = tracer.store.index()["traces"]
+    tid = [t["trace_id"] for t in index if t["name"] == "train_step"][-1]
+    spans = {s["name"]: s for s in tracer.store.get(tid)["spans"]}
+    f, b, o, st = (spans[k] for k in ("forward", "backward", "optimizer",
+                                      "step"))
+    assert st["t_start"] <= f["t_start"] < f["t_end"] <= b["t_start"] \
+        < b["t_end"] <= o["t_start"] < o["t_end"] <= st["t_end"]
+    assert f["t_end"] - f["t_start"] >= 0.003
+
+
+def _gpt_test_step():
+    import paddle_tpu.optimizer as opt
+    from paddle_tpu.jit import TrainStep
+    from paddle_tpu.models import (GPTForCausalLM, GPTPretrainingCriterion,
+                                   gpt_presets)
+
+    cfg = gpt_presets("gpt-test")
+    model = GPTForCausalLM(cfg, seed=0)
+    crit = GPTPretrainingCriterion()
+    optim = opt.AdamW(learning_rate=1e-3, parameters=model.parameters())
+    step = TrainStep(model, lambda lg, lb: crit(lg, lb), optim)
+    ids = np.random.RandomState(0).randint(0, cfg.vocab_size, (2, 33))
+
+    def call():
+        return step(inputs=(paddle.to_tensor(ids[:, :-1], dtype="int64"),),
+                    labels=(paddle.to_tensor(ids[:, 1:], dtype="int64"),))
+
+    return step, call
+
+
+def test_train_step_host_spans_over_three_calls():
+    step, call = _gpt_test_step()
+    with Profiler(timer_only=True) as prof:
+        losses = [float(call()) for _ in range(3)]
+    assert losses[2] < losses[0]
+    spans = [e for e in prof.events if e.name.startswith("jit_step")]
+    by_id = {e.id: e for e in spans}
+    count = {}
+    for e in spans:
+        count[e.name] = count.get(e.name, 0) + 1
+    assert count == {"jit_step": 3, "jit_step.args": 3, "jit_step.rebind": 3,
+                     "jit_step.state_init": 1, "jit_step.build": 1,
+                     "jit_step.place": 1, "jit_step.first_call": 1,
+                     "jit_step.dispatch": 2}
+    steps = [e for e in spans if e.name == "jit_step"]
+    assert all(e.parent_id not in by_id for e in steps)
+    for e in spans:
+        if e.name == "jit_step":
+            continue
+        parent = by_id[e.parent_id]
+        # state_init and build happen inside the argument assembly
+        want = "jit_step.args" if e.name in (
+            "jit_step.state_init", "jit_step.build") else "jit_step"
+        assert parent.name == want, (e.name, parent.name)
+        assert parent.start_ns <= e.start_ns and e.end_ns <= parent.end_ns
+    first = steps[0].id
+    firsts = {e.name for e in spans if e.parent_id == first}
+    assert firsts == {"jit_step.args", "jit_step.place",
+                      "jit_step.first_call", "jit_step.rebind"}
+
+
+def test_train_step_makes_its_abstract_signature_once_per_entry():
+    import jax
+
+    step, call = _gpt_test_step()
+    assert step._last_abstract is None and step.memory_analysis() is None
+    call()
+    first = step._last_abstract
+    call()
+    assert step._last_abstract is first             # not rebuilt per call
+    leaves = jax.tree_util.tree_leaves(first)
+    assert leaves and all(isinstance(v, jax.ShapeDtypeStruct)
+                          for v in leaves)
+    assert step.memory_analysis(record=False) is not None
+
+
+def test_reimported_state_drops_the_abstract_signature():
+    """Slots another writer left (set_state_dict, the eager path) may have
+    other dtypes: the entry's next call makes its signature anew, and goes
+    by `first_call` since jit may compile again."""
+    step, call = _gpt_test_step()
+    call()
+    call()
+    before = step._last_abstract
+    step.optimizer.set_state_dict(step.optimizer.state_dict())
+    with Profiler(timer_only=True) as prof:
+        call()
+        call()
+    assert step._last_abstract is not None
+    assert step._last_abstract is not before
+    names = [e.name for e in prof.events if e.name.startswith("jit_step.")]
+    assert names.count("jit_step.state_init") == 1
+    assert names.count("jit_step.first_call") == 1
+    assert names.count("jit_step.dispatch") == 1
+    assert step.memory_analysis(record=False) is not None
+
+
+def _scope_paths():
+    """The names on each op's path in the lowered gpt-test step:
+    `jit(pure_step)/transpose(jvp(attn))/dot_general` gives
+    {jit, pure_step, transpose, jvp, attn, dot_general}."""
+    import re
+
+    step, call = _gpt_test_step()
+    call()
+    text = step._cache[step._last_ckey].lower(
+        *step._last_abstract).as_text(debug_info=True)
+    return [set(re.findall(r"[A-Za-z_]\w*", path)) for path in
+            set(re.findall(r'loc\("(jit\(pure_step\)[^"]*)"', text))]
+
+
+def test_the_lowered_train_step_names_its_layers():
+    paths = _scope_paths()
+
+    def some_path_holds(*names):
+        return any(set(names) <= p for p in paths)
+
+    for scope in ("attn", "mlp", "embed", "lm_head", "loss"):
+        assert some_path_holds(scope), scope
+        # the backward inherits the scope inside transpose(jvp(...))
+        assert some_path_holds("transpose", "jvp", scope), scope
+    assert some_path_holds("optimizer")
+    assert not some_path_holds("optimizer", "jvp")
+    assert not some_path_holds("attn", "mlp")       # siblings, not nested

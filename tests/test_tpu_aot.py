@@ -73,6 +73,35 @@ for shape in ((8, 1024, 12, 64), (4, 2048, 16, 128)):
     compile_pallas_flash_for_tpu(shape, grad=True)
 print("FLASH-OK")
 
+# the three kernels go by name in the compiled programs (a device trace's
+# reader finds them so): forward alone, then forward + both backward calls.
+# Inside a scope, as in the model's block: XLA names the call after the
+# last name on its path, and directly under a transform that would be
+# `jvp(flash_fwd)`
+import re
+from paddle_tpu.ops.flash_attention import flash_attention_val
+
+
+def attention(a, b, c):
+    with jax.named_scope("attn"):
+        return flash_attention_val(a, b, c)
+
+
+def mosaic_names(fn):
+    q = SDS((8, 1024, 12, 64), bf16)
+    text = compile_for_one_chip(fn, q, q, q).as_text()
+    return sorted(re.match(r"\s*%%?([A-Za-z_0-9]+)", line).group(1)
+                  for line in text.splitlines()
+                  if "custom-call(" in line and "tpu_custom_call" in line)
+
+
+assert mosaic_names(attention) == ["flash_fwd"]
+names = mosaic_names(jax.grad(
+    lambda a, b, c: jnp.sum(attention(a, b, c).astype(f32)),
+    argnums=(0, 1, 2)))
+assert names == ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"], names
+print("FLASH-NAMES-OK")
+
 for k, n in ((768, 3072), (2048, 8192), (3072, 768), (8192, 2048)):
     for stochastic in (False, True):
         mosaic(lambda w: quantize_int8(w, stochastic=stochastic, seed=3),
@@ -149,8 +178,8 @@ def test_trainstep_with_flash_compiles_for_tpu():
 
 def test_pallas_families_compile_by_mosaic():
     out = _run_child(KERNELS_CHILD)
-    for tag in ("FLASH-OK", "QUANTIZE-OK", "QMM-OK", "CODEC-OK",
-                "FUSED-UPDATE-OK"):
+    for tag in ("FLASH-OK", "FLASH-NAMES-OK", "QUANTIZE-OK", "QMM-OK",
+                "CODEC-OK", "FUSED-UPDATE-OK"):
         assert tag in out, out[-2000:]
 
 
