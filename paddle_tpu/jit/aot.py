@@ -94,7 +94,7 @@ def compile_for_one_chip(fn, *avals, topology: str = "v5e:2x2"):
         return jax.jit(fn).lower(*args).compile()
 
 
-def compile_pallas_flash_for_tpu(shape=(8, 1024, 12, 64), block_size=512,
+def compile_pallas_flash_for_tpu(shape=(8, 1024, 12, 64), block_size=None,
                                  topology: str = "v5e:2x2",
                                  grad: bool = True) -> float:
     """Compile the pallas flash-attention kernel (Mosaic, not interpret)

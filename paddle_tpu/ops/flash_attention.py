@@ -4,10 +4,20 @@ Capability parity: the reference's fused CUDA attention
 (paddle/fluid/operators/fused/fused_attention_op.cu, fmha_ref.h) — here
 re-designed for the TPU memory hierarchy: the kv dimension is the innermost
 grid axis, so k/v blocks stream HBM→VMEM with automatic double-buffering,
-online-softmax state lives in VMEM scratch across grid steps, the [s, s]
-score matrix never exists in HBM, and the MXU does every matmul with fp32
-accumulation (preferred_element_type=f32). Causal upper-triangle blocks are
-predicated off with @pl.when, realizing the ~2x causal FLOP saving.
+online-softmax state lives in VMEM scratch across grid steps and the [s, s]
+score matrix never exists in HBM. Causal upper-triangle blocks are
+predicated off with @pl.when.
+
+Precision follows the caller's dtype, with no switch. The MXU's operands —
+q (times the softmax scale, rounded once), k, v, dO, and the probabilities
+p and ds where they enter the second matmuls — are in the dtype q, k, v
+arrive in: bf16 callers get bf16 x bf16 dots, fp32 callers fp32 ones. What
+is float32 whatever comes in: every dot's accumulation
+(preferred_element_type), the scores and the mask, the softmax statistics
+m, l, lse and delta, exp, dp - delta, and the three accumulators. (On the
+chip this states what Mosaic did already: at default precision it feeds an
+fp32 operand to the MXU as one bf16 pass, so widening the blocks first
+bought no precision and cost no time — PERF.md, PR 26.)
 
 Layout is [b, n, s, d] inside the kernels (head-major, contiguous (s, d)
 tiles per grid cell); the public entry takes the model's [b, s, n, d] and
@@ -15,7 +25,8 @@ transposes (XLA fuses the transposes into the surrounding program).
 
 Backward uses the standard two-kernel flash decomposition:
   dq kernel:  grid (b, n, q_blocks, kv_blocks), dq accumulates in scratch
-  dkv kernel: grid (b, n, kv_blocks, q_blocks), dk/dv accumulate in scratch
+  dkv kernel: grid (b, n, kv_blocks, q_blocks), dk/dv accumulate in scratch,
+              on the transposed score block (see _dkv_kernel)
 with delta = rowsum(dO * O) precomputed outside (one fused elementwise pass).
 """
 from __future__ import annotations
@@ -37,19 +48,38 @@ def _pick_block(s: int, want: int) -> int:
     that divides s. The FALLBACK when the tune cache has no validated
     winner for the shape (and the whole story when FLAGS_kernel_autotune
     is off)."""
-    for b in (want, 512, 256, 128, 64, 32, 16, 8):
+    for b in (want, 1024, 512, 256, 128, 64, 32, 16, 8):
         if b <= want and s % b == 0 and b <= s:
             return b
     return 0
 
 
-def _tuned_blocks(shape, dtype, causal: bool, want: int):
+def _default_block(d: int, dtype) -> int:
+    """Where the ladder starts when the caller names no block: 1024, or 512
+    where a row of the tile (d elements of dtype) is wider than 512 bytes.
+
+    From the chip sweep of PR 26 over {256, 512, 1024} x {256, 512, 1024}
+    (v5e, bf16, s1024 and s2048 at d=64, s2048 at d=128; PERF.md section
+    6): the kernels' time goes with the number of grid steps and of score
+    ROWS a step handles (the row max and sum, the (BQ, 1) statistics
+    spread over the lanes), hardly with the block's area, so one (1024,
+    1024) block beats the three of four (512, 512) blocks under the causal
+    diagonal by 1.33-1.39x over forward + backward although it computes
+    the masked quarter too. 2048 does not fit VMEM in the backward
+    kernels, nor does 1024 with fp32 rows of d=256 (the TPU compiler,
+    without the chip): hence the bound on the row's bytes."""
+    return 1024 if d * jnp.dtype(dtype).itemsize <= 512 else 512
+
+
+def _tuned_blocks(shape, dtype, causal: bool, want):
     """(block_q, block_k) for a [b, s, n, d] call: the tuner cache's
     validated winner under FLAGS_kernel_autotune when it still fits the
-    concrete sequence length, else the _pick_block ladder pair. The
+    concrete sequence length, else the _pick_block ladder pair from
+    ``want`` (None: from _default_block of the head size and dtype). The
     independent q/k blocks are the point — the cache may hold an
     asymmetric winner the ladder can never produce."""
     s = int(shape[1])
+    want = want or _default_block(int(shape[3]), dtype)
     from .pallas import autotune as _at
 
     params = _at.lookup(
@@ -70,7 +100,7 @@ def _tuned_blocks(shape, dtype, causal: bool, want: int):
 
 
 def flash_block_choice(shape, dtype="float32", causal=True,
-                       block_size=512) -> dict:
+                       block_size=None) -> dict:
     """What dispatch would run for this [b, s, n, d] call — the record
     bench.py carries so the trajectory shows WHICH tiles produced a
     throughput number: {"block_q", "block_k", "source"}."""
@@ -103,12 +133,20 @@ def _interpret() -> bool:
     return target_platform() != "tpu"
 
 
-def _causal_mask(s_blk, qi, ki, block_q, block_k):
+def _causal_mask(s_blk, qi, ki, block_q, block_k, transposed=False):
+    """s_blk is (BQ, BK), or (BK, BQ) when transposed."""
     q_pos = qi * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0)
+        jnp.int32, s_blk.shape, 1 if transposed else 0)
     k_pos = ki * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)
+        jnp.int32, s_blk.shape, 0 if transposed else 1)
     return jnp.where(q_pos >= k_pos, s_blk, NEG_INF)
+
+
+def _scaled(q, scale):
+    """The q tile times the softmax scale, in q's own dtype: the product is
+    made in fp32 and rounded once (exact where the scale is a power of two,
+    as at d=64), so the MXU takes it as it takes k, v and dO."""
+    return (q.astype(jnp.float32) * scale).astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -131,9 +169,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
 
     @pl.when(run)
     def _compute():
-        q = q_ref[0, 0, :, :].astype(jnp.float32) * scale        # (BQ, d)
-        kb = k_ref[0, 0, :, :].astype(jnp.float32)               # (BK, d)
-        vb = v_ref[0, 0, :, :].astype(jnp.float32)
+        q = _scaled(q_ref[0, 0, :, :], scale)                    # (BQ, d)
+        kb = k_ref[0, 0, :, :]                                   # (BK, d)
+        vb = v_ref[0, 0, :, :]
         s_blk = jax.lax.dot_general(
             q, kb, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)                  # (BQ, BK)
@@ -146,7 +184,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
         alpha = jnp.exp(m_prev - m_new)
         l_new = l_prev * alpha + jnp.sum(p, -1, keepdims=True)
         acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p, vb, (((1,), (0,)), ((), ())),
+            p.astype(vb.dtype), vb, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)                  # (BQ, d)
         m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
         l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
@@ -219,10 +257,10 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
     @pl.when(run)
     def _compute():
-        q = q_ref[0, 0, :, :].astype(jnp.float32) * scale        # (BQ, d)
-        kb = k_ref[0, 0, :, :].astype(jnp.float32)               # (BK, d)
-        vb = v_ref[0, 0, :, :].astype(jnp.float32)
-        do = do_ref[0, 0, :, :].astype(jnp.float32)
+        q = _scaled(q_ref[0, 0, :, :], scale)                    # (BQ, d)
+        kb = k_ref[0, 0, :, :]                                   # (BK, d)
+        vb = v_ref[0, 0, :, :]
+        do = do_ref[0, 0, :, :]
         lse = lse_ref[0, 0, :, :]                                # (BQ, 1)
         delta = delta_ref[0, 0, :, :]
         s_blk = jax.lax.dot_general(
@@ -236,7 +274,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
             preferred_element_type=jnp.float32)
         ds = p * (dp - delta)
         acc_ref[...] += jax.lax.dot_general(
-            ds, kb, (((1,), (0,)), ((), ())),
+            ds.astype(kb.dtype), kb, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
     @pl.when(ki == nk - 1)
@@ -247,6 +285,12 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dk_ref, dv_ref, dk_acc, dv_acc, *, scale, causal,
                 block_q, block_k):
+    """Works on the TRANSPOSED score block s^T = k q^T, (BK, BQ), so that
+    dv += p^T dO and dk += ds^T q are plain row-by-column products and the
+    row statistics come as (1, BQ) rows, spread down the sublanes. From an
+    untransposed p both products contract dim 0 of both operands and lse
+    and delta are (BQ, 1) columns spread over the lanes: 18-23 % slower at
+    block 512 on the v5e, 2-10 % at 1024 (PERF.md, PR 26)."""
     ki, qi = pl.program_id(2), pl.program_id(3)
     nq = pl.num_programs(3)
 
@@ -259,28 +303,28 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     @pl.when(run)
     def _compute():
-        q = q_ref[0, 0, :, :].astype(jnp.float32) * scale        # (BQ, d)
-        kb = k_ref[0, 0, :, :].astype(jnp.float32)               # (BK, d)
-        vb = v_ref[0, 0, :, :].astype(jnp.float32)
-        do = do_ref[0, 0, :, :].astype(jnp.float32)
-        lse = lse_ref[0, 0, :, :]
-        delta = delta_ref[0, 0, :, :]
-        s_blk = jax.lax.dot_general(
-            q, kb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)                  # (BQ, BK)
+        q = _scaled(q_ref[0, 0, :, :], scale)                    # (BQ, d)
+        kb = k_ref[0, 0, :, :]                                   # (BK, d)
+        vb = v_ref[0, 0, :, :]
+        do = do_ref[0, 0, :, :]
+        lse = lse_ref[0, 0, 0, :, :]                             # (1, BQ)
+        delta = delta_ref[0, 0, 0, :, :]
+        st = jax.lax.dot_general(
+            kb, q, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)                  # (BK, BQ)
         if causal:
-            s_blk = _causal_mask(s_blk, qi, ki, block_q, block_k)
-        p = jnp.exp(s_blk - lse)
+            st = _causal_mask(st, qi, ki, block_q, block_k, transposed=True)
+        pt = jnp.exp(st - lse)
         dv_acc[...] += jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
+            pt.astype(do.dtype), do, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)                  # (BK, d)
-        dp = jax.lax.dot_general(
-            do, vb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - delta)
+        dpt = jax.lax.dot_general(
+            vb, do, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)                  # (BK, BQ)
+        dst = pt * (dpt - delta)
         # q was pre-scaled, so dk already carries `scale`
         dk_acc[...] += jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
+            dst.astype(q.dtype), q, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)                  # (BK, d)
 
     @pl.when(qi == nq - 1)
@@ -312,13 +356,16 @@ def _bwd(q, k, v, out, lse, do, causal, block_q, block_k):
         name="flash_bwd_dq",
     )(q, k, v, do, lse, delta)
 
-    # dkv: grid (b, n, kv_blocks, q_blocks) — q innermost
+    # dkv: grid (b, n, kv_blocks, q_blocks) — q innermost. lse and delta go
+    # in as one (1, BQ) row per q block: a block that spans its array's last
+    # two dims whole is legal for every block_q
     qb2 = pl.BlockSpec((1, 1, block_q, d),
                        lambda bi, hi, ki, qi: (bi, hi, qi, 0))
     kvb2 = pl.BlockSpec((1, 1, block_k, d),
                         lambda bi, hi, ki, qi: (bi, hi, ki, 0))
-    rowb2 = pl.BlockSpec((1, 1, block_q, 1),
-                         lambda bi, hi, ki, qi: (bi, hi, qi, 0))
+    rowb2 = pl.BlockSpec((1, 1, 1, 1, block_q),
+                         lambda bi, hi, ki, qi: (bi, hi, qi, 0, 0))
+    rows = (b, n, s // block_q, 1, block_q)
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=1.0 / math.sqrt(d),
                           causal=causal, block_q=block_q, block_k=block_k),
@@ -331,7 +378,7 @@ def _bwd(q, k, v, out, lse, do, causal, block_q, block_k):
                         pltpu.VMEM((block_k, d), jnp.float32)],
         interpret=_interpret(),
         name="flash_bwd_dkv",
-    )(q, k, v, do, lse, delta)
+    )(q, k, v, do, lse.reshape(rows), delta.reshape(rows))
     return dq, dk, dv
 
 
@@ -358,7 +405,7 @@ def _flash_bwd_rule(causal, block_q, block_k, res, do):
 _flash_bnsd.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
-def flash_attention_val(q, k, v, causal=True, block_size=512,
+def flash_attention_val(q, k, v, causal=True, block_size=None,
                         block_q=None, block_k=None):
     """Causal flash attention on [b, s, n, d] arrays → [b, s, n, d].
 
@@ -367,12 +414,18 @@ def flash_attention_val(q, k, v, causal=True, block_size=512,
     check flash_attention_supported() first. Explicit ``block_q`` /
     ``block_k`` pin the tiles (both must divide s); otherwise dispatch
     consults the autotune cache under FLAGS_kernel_autotune and falls
-    back to the ``_pick_block`` ladder.
+    back to the ``_pick_block`` ladder from ``block_size`` (None: from
+    ``_default_block`` of the head size and dtype).
+
+    The result and the gradients have the inputs' dtype, and so have the
+    operands of every matmul inside; accumulation and the softmax
+    statistics are float32 for every input dtype (module docstring).
     """
     b, s, n, d = q.shape
     if block_q is not None or block_k is not None:
-        bq = int(block_q or block_size)
-        bk = int(block_k or block_size)
+        other = block_size or _default_block(d, q.dtype)
+        bq = int(block_q or other)
+        bk = int(block_k or other)
         if not flash_attention_supported(q.shape, block_q=bq, block_k=bk):
             raise ValueError(
                 f"flash attention: blocks ({bq}, {bk}) invalid for seq "
@@ -430,7 +483,7 @@ def flash_attention_sharded_ok(shape) -> bool:
     return mesh is not None
 
 
-def flash_attention_val_auto(q, k, v, causal=True, block_size=512):
+def flash_attention_val_auto(q, k, v, causal=True, block_size=None):
     """flash_attention_val that is safe under an active mesh: wraps the
     pallas call in shard_map with batch/head partitioning so GSPMD never
     sees an unpartitionable Mosaic call. Check flash_attention_sharded_ok

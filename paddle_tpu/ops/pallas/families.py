@@ -16,7 +16,7 @@ from . import autotune
 
 __all__ = ["flash_candidate_blocks"]
 
-_FLASH_BLOCKS = (512, 256, 128, 64, 32, 16, 8)
+_FLASH_BLOCKS = (1024, 512, 256, 128, 64, 32, 16, 8)
 
 
 def flash_candidate_blocks(s: int):
@@ -42,14 +42,16 @@ def _flash_reference(q, k, v, causal):
 
 
 def _register_flash():
-    from ..flash_attention import _pick_block, flash_attention_val
+    from ..flash_attention import (_default_block, _pick_block,
+                                   flash_attention_val)
 
     def candidates(q, k, v, causal):
         return [{"block_q": bq, "block_k": bk}
                 for bq, bk in flash_candidate_blocks(int(q.shape[1]))]
 
     def default_params(q, k, v, causal):
-        blk = _pick_block(int(q.shape[1]), 512)
+        blk = _pick_block(int(q.shape[1]),
+                          _default_block(int(q.shape[3]), q.dtype))
         return {"block_q": blk, "block_k": blk}
 
     def run(params, q, k, v, causal):

@@ -107,3 +107,127 @@ def test_jit_under_mesh():
                                    rtol=1e-5, atol=1e-5)
     finally:
         mesh_mod.set_mesh(prev)
+
+
+# ---------------------------------------------------------------------------
+# operand dtype: the MXU takes the caller's dtype, the statistics stay fp32
+# ---------------------------------------------------------------------------
+
+# bf16 keeps 8 bits: one rounding is at most 2^-9 = 2e-3 of the value. The
+# largest errors seen here are the result's own rounding (0.02 at |dv| = 5.5,
+# 0.008 at |out| = 3.3), the roundings inside (the scaled q tile, p, ds)
+# average out over the contraction. 1e-2 + 1e-2 |x| bounds them with room and
+# is half the autotune family's validation tolerance, the ceiling (2e-2)
+BF16_TOL = dict(rtol=1e-2, atol=1e-2)
+
+# (head dim, block_q, block_k): both published head sizes, block_q != block_k
+# both ways round, so the diagonal crosses blocks that are not square
+DTYPE_CASES = [(64, 64, 32), (128, 32, 64)]
+
+
+def _f32(x):
+    return np.asarray(x.astype(jnp.float32))
+
+
+def _loss(attn, q, k, v):
+    return jnp.sum(jnp.sin(attn(q, k, v).astype(jnp.float32)))
+
+
+def _flash_and_reference_grads(q, k, v, causal, bq, bk):
+    """(out, dq, dk, dv) of the kernel on q, k, v as given, and of the
+    reference on the same values widened to fp32: the reference sees the
+    rounded inputs, so the comparison is of the arithmetic alone."""
+    flash = lambda a, b, c: flash_attention_val(a, b, c, causal=causal,
+                                                block_q=bq, block_k=bk)
+    ref = lambda a, b, c: ref_attn(a, b, c, causal)
+    wide = [x.astype(jnp.float32) for x in (q, k, v)]
+    got = (flash(q, k, v),) + jax.grad(
+        lambda *a: _loss(flash, *a), (0, 1, 2))(q, k, v)
+    want = (ref(*wide),) + jax.grad(
+        lambda *a: _loss(ref, *a), (0, 1, 2))(*wide)
+    return got, want
+
+
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("d,bq,bk", DTYPE_CASES)
+def test_bf16_forward_matches_fp32_reference(causal, d, bq, bk):
+    q, k, v = (x.astype(jnp.bfloat16) for x in _rand(2, 128, 2, d, seed=5))
+    out = flash_attention_val(q, k, v, causal=causal, block_q=bq, block_k=bk)
+    assert out.dtype == jnp.bfloat16
+    want = ref_attn(*(x.astype(jnp.float32) for x in (q, k, v)), causal)
+    np.testing.assert_allclose(_f32(out), np.asarray(want), **BF16_TOL)
+
+
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("d,bq,bk", DTYPE_CASES)
+def test_bf16_gradients_match_fp32_reference(causal, d, bq, bk):
+    q, k, v = (x.astype(jnp.bfloat16) for x in _rand(2, 128, 2, d, seed=6))
+    got, want = _flash_and_reference_grads(q, k, v, causal, bq, bk)
+    for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
+        assert a.dtype == jnp.bfloat16, name
+        np.testing.assert_allclose(_f32(a), np.asarray(b), err_msg=name,
+                                   **BF16_TOL)
+
+
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("d,bq,bk", DTYPE_CASES)
+def test_fp32_inputs_keep_fp32_tolerances(causal, d, bq, bk):
+    # fp32 callers get fp32 dots: the tolerances of the first two tests
+    # above, at the head sizes and uneven blocks of the bf16 cases
+    q, k, v = _rand(2, 128, 2, d, seed=7)
+    got, want = _flash_and_reference_grads(q, k, v, causal, bq, bk)
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want[0]),
+                               rtol=1e-5, atol=1e-5)
+    for a, b in zip(got[1:], want[1:]):
+        assert a.dtype == jnp.float32
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-4, atol=1e-4)
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr, those of nested jaxprs (pl.when's cond
+    branches, the kernel body of a pallas_call) included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _eqns(inner)
+
+
+def _kernel_bodies(dtype):
+    """The three pallas_call equations of forward + backward, by name."""
+    q = jnp.zeros((1, 64, 2, 64), dtype)
+    grads = jax.grad(
+        lambda a, b, c: jnp.sum(flash_attention_val(
+            a, b, c, causal=True, block_q=32, block_k=16
+        ).astype(jnp.float32)), (0, 1, 2))
+    jaxpr = jax.make_jaxpr(grads)(q, q, q).jaxpr
+    return {e.params["name"]: e for e in _eqns(jaxpr)
+            if e.primitive.name == "pallas_call"}
+
+
+@pytest.mark.parametrize("kernel,n_dots", [("flash_fwd", 2),
+                                           ("flash_bwd_dq", 3),
+                                           ("flash_bwd_dkv", 4)])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_kernel_dots_take_the_input_dtype(kernel, n_dots, dtype):
+    """No dot in a kernel body widens its operands: for bf16 inputs none
+    takes a float32 operand, and every one accumulates in float32. The
+    statistics (lse, delta) and the scratch accumulators are float32
+    whatever comes in."""
+    call = _kernel_bodies(dtype)[kernel]
+    dots = [e for e in _eqns(call.params["jaxpr"])
+            if e.primitive.name == "dot_general"]
+    assert len(dots) == n_dots
+    for dot in dots:
+        assert [v.aval.dtype for v in dot.invars] == [dtype, dtype], dot
+        assert dot.params["preferred_element_type"] == jnp.float32
+    refs = [v.aval for v in call.params["jaxpr"].invars]
+    mapping = call.params["grid_mapping"]
+    scratch = refs[len(refs) - mapping.num_scratch_operands:]
+    assert scratch and all(r.dtype == jnp.float32 for r in scratch), scratch
+    # lse and delta blocks: (BQ, 1) columns, (1, BQ) rows in the dkv kernel
+    stats = [r for r in refs if 1 in r.shape[-2:]]
+    assert stats and all(r.dtype == jnp.float32 for r in stats), stats

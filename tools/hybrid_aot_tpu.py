@@ -138,7 +138,7 @@ def main():
     b, s, n, d = 8, 1024, 12, 64
     results["pallas_flash_fwd_bwd"] = {
         "compile_seconds": compile_pallas_flash_for_tpu(
-            (b, s, n, d), block_size=512, grad=True),
+            (b, s, n, d), grad=True),
         "shape": [b, s, n, d], "topology": "v5e (single chip)",
         "mosaic": True}
     print("pallas flash fwd+bwd TPU compile:",
