@@ -1,0 +1,179 @@
+"""Traffic kind `train_moe`: the `deepseek_v3` block's train step in chunks
+of k steps, on one chip's share of an expert-parallel deployment.
+
+The window, the chunk rule and the rate are kinds/train.py's (its
+`_segment`, `segment_wall`, `chunk_seconds`, imported): see its docstring
+for the timing. What differs is the program (benchmark/program_mla_moe.py:
+MlaMoeForCausalLM -> AdamW -> jit.TrainStep), the comparison with the
+reference (router choices and logits on the timed length of ids, the last
+layer's gradients on a token-specific hidden state of that length), the
+operation counts (benchmark/flops_mla_moe.py, the routed experts by the
+window's COUNTED assignments) and one more check: the layers' assignment counters,
+read at the window's two ends, must sum to steps x tokens x k x expert
+layers, or a token was dropped.
+"""
+from __future__ import annotations
+
+import time
+
+import numpy as np
+
+from benchmark import flops_mla_moe, program_mla_moe, stats, traffic_gen
+from benchmark.harness import Record
+from benchmark.kinds.train import _segment, chunk_seconds, segment_wall
+
+
+def run(cell, opts) -> Record:
+    import jax
+
+    import paddle_tpu as paddle
+
+    log = opts.log
+    tr, cfgd = cell.traffic, cell.config
+    batch, seq, k = tr["global_batch"], tr["seq"], tr["chunk_steps"]
+    built = program_mla_moe.build_train(cell, opts.seed)
+    step, model = built["step"], built["model"]
+    why = []
+
+    gen = traffic_gen.ZipfTokens(opts.seed, cfgd["vocab_size"],
+                                 tr["tokens"]["exponent"])
+    counter = {"n": 0}
+
+    def step_fn():
+        x, y = gen.batch(counter["n"], batch, seq)
+        counter["n"] += 1
+        loss = step(inputs=(paddle.to_tensor(x, dtype="int64"),),
+                    labels=(paddle.to_tensor(y, dtype="int64"),))
+        return loss._value
+
+    def sync(v):
+        jax.block_until_ready(v)
+
+    for _ in range(tr["warm_steps"]):
+        sync(step_fn())          # the first compiles, or finds the cache
+    warm_steps = counter["n"]
+    counts_start = program_mla_moe.assign_counts(model)
+
+    # the window: the device is drained, nothing is in flight
+    drop = tr["drop_chunks"]
+    t_window = time.monotonic()
+    t_stop = t_window + opts.seconds
+    segments, traced = [], None
+    if not opts.trace:
+        segments.append(_segment(step_fn, sync, k, until=t_stop))
+    else:
+        from benchmark import tracewin
+
+        n_tr = tr["trace_chunks"]
+        first = _segment(step_fn, sync, k, n_chunks=drop + 4)
+        segments.append(first)
+        est = np.median(chunk_seconds(first, drop))
+        with tracewin.device_trace(opts.trace_dir) as tw:
+            traced = _segment(step_fn, sync, k, n_chunks=n_tr + 1,
+                              annotate=tw.annotate)
+        log(f"[trace] {n_tr + 1} chunks traced, chunk ~{est:.3f} s, "
+            f"profiler start+stop {tw.overhead_s:.1f} s")
+        if time.monotonic() < t_stop:
+            segments.append(_segment(step_fn, sync, k, until=t_stop))
+    t_window_end = time.monotonic()
+    steps = counter["n"] - warm_steps
+    # what training holds: the comparison below runs the float32 reference
+    # in this process, and the process's peak then reads that
+    window_peak = (jax.devices()[0].memory_stats() or {}).get(
+        "peak_bytes_in_use")
+    log(f"[train] peak bytes in use at the window's end, before the "
+        f"comparison with the reference: {(window_peak or 0) / 1e9:.3f} GB")
+
+    # correctness, part 1: no token dropped. Every one of the window's
+    # tokens has k assignments in every expert layer's counters
+    counted = program_mla_moe.assign_counts(model) - counts_start
+    n_moe = counted.shape[0]
+    top_k = cfgd["num_experts_per_tok"]
+    want = steps * batch * seq * top_k * n_moe
+    lo, hi = cfgd["experts_held"]
+    held = counted[:, lo:hi]
+    log(f"[moe] {steps} steps: {int(counted.sum())} assignments counted in "
+        f"{n_moe} expert layers, {want} = steps x tokens x {top_k} x layers "
+        f"expected; to the {hi - lo} held experts {int(held.sum())} "
+        f"({held.sum() / max(counted.sum(), 1):.4f} of all; uniform "
+        f"{(hi - lo) / counted.shape[1]:.4f}); per layer max/mean of the "
+        f"held {[round(float(r.max() / max(r.mean(), 1e-9)), 3) for r in held]}")
+    if int(counted.sum()) != want:
+        why.append(f"assignment counters sum to {int(counted.sum())}, not "
+                   f"{want}: a token was dropped or counted twice")
+
+    # part 2, AFTER the window: the compiled step holds the Mosaic kernels,
+    # and the program's forward and its last layer's backward at the timed
+    # length agree with the float32 reference at the weights the window left
+    program_mla_moe.check_step_program(built, log)
+    ref = program_mla_moe.check_against_reference(cell, model, opts.seed,
+                                                  log)
+    if not ref["ok"]:
+        why.append(ref["why"])
+
+    kept = [s for seg in segments for s in chunk_seconds(seg, drop)]
+    losses = [float(l) for seg in segments for l in seg["losses"]]
+    losses_all = losses + [float(l) for l in (traced or {"losses": []})[
+        "losses"]]
+    min_kept = tr["min_kept_chunks"] if not opts.trace else 3
+    if len(kept) < min_kept:
+        raise SystemExit(
+            f"benchmark: only {len(kept)} kept chunks of {k} steps fit in "
+            f"{opts.seconds} s; the cell needs {min_kept}. Run longer.")
+
+    wall = sum(segment_wall(seg) for seg in segments)
+    timed_steps = sum(seg["steps"] for seg in segments)
+    end_to_end = {}
+    if not opts.trace:
+        tok_s_chip = stats.rate(timed_steps * batch * seq, wall) / cell.chips
+        end_to_end["train_tok_s_chip"] = tok_s_chip
+        log(f"[train] {timed_steps} steps = {timed_steps * batch * seq} "
+            f"tokens in {wall:.4f} s of window: {tok_s_chip:.1f} "
+            f"tokens/s/chip")
+    log(f"[train] {len(kept)} kept chunks of {k}; chunk s: median "
+        f"{np.median(kept):.4f} min {min(kept):.4f} max {max(kept):.4f}; "
+        f"tokens/s/chip by the median chunk "
+        f"{stats.chunk_rate(kept, batch * seq * k) / cell.chips:.1f}")
+    log(f"[train] losses (each chunk's last step): "
+        f"{[round(x, 4) for x in losses]}")
+
+    # part 3: finite losses that fall
+    bad = [x for x in losses_all if not np.isfinite(x)]
+    if bad:
+        why.append(f"{len(bad)} non-finite losses")
+    if len(losses) >= 6:
+        first3, last3 = np.median(losses[:3]), np.median(losses[-3:])
+        if not last3 < first3:
+            why.append(f"loss did not fall: median of first three "
+                       f"{first3:.4f}, of last three {last3:.4f}")
+
+    # needed work, the routed experts by what the window counted
+    tokens = max(steps * batch * seq, 1)
+    held_per_token_layer = float(held.sum()) / tokens / n_moe
+    expert_rows_layer_step = float(held.sum()) / max(steps, 1) / n_moe
+    experts_cost = flops_mla_moe.experts_train_cost(
+        expert_rows_layer_step, hi - lo, cfgd["hidden_size"],
+        cfgd["moe_intermediate_size"])
+    obs = {
+        "chunk_seconds": kept, "chunk_steps": k,
+        "tokens_per_step": batch * seq, "chips": cell.chips,
+        "flops_per_token": flops_mla_moe.train_flops_per_token(
+            cfgd, seq, held_per_token_layer),
+        "trace_dir": opts.trace_dir if traced else None,
+        "traced_steps": traced["steps"] if traced else 0,
+        # per step, for the rooflines of readers/named_ops.py
+        "flash_mla_cost": flops_mla_moe.flash_mla_train_cost(
+            batch, seq, cfgd["num_attention_heads"], cfgd["qk_head_dim"],
+            cfgd["v_head_dim"], cfgd["num_hidden_layers"]),
+        "moe_experts_cost": {key: v * n_moe
+                             for key, v in experts_cost.items()},
+        "moe_load_max_over_mean": float(
+            held.sum(0).max() / max(held.sum(0).mean(), 1e-9)),
+        "moe_held_load": held.tolist(),
+        "hbm_window_peak_gb": window_peak / 1e9 if window_peak else None,
+        "reference": ref,
+    }
+    return Record(attempted=steps, failed=len(bad) * k,
+                  end_to_end=end_to_end,
+                  t_window_start=t_window, t_window_end=t_window_end,
+                  obs=obs, why_incorrect=why)

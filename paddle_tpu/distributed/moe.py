@@ -12,9 +12,18 @@ shapes, so routing uses the GShard/Switch fixed-capacity design — top-k gating
 'expert' mesh axis and GSPMD emits the AllToAll from the dispatch einsum's
 contraction. The reference's global_scatter/global_gather API survives in
 distributed/utils.py as eager permutation semantics for compatibility.
+
+Two expert layers live here. `MoELayer` is that GShard layer: softmax top-2,
+a capacity factor (tokens over capacity are dropped), one-hot [T, E, C]
+dispatch. `DroplessMoELayer` is the DeepSeek-V3 style layer of one chip of
+an expert-parallel group: it is told which experts it holds, routes over
+all of them with sigmoid scores and a selection bias, and computes its own
+experts' part for every token routed to them as grouped matrix products
+over rows sorted by expert: no capacity, no dropped token, no one-hot.
 """
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -137,3 +146,240 @@ def _constrain(v, *spec):
 
     return jax.lax.with_sharding_constraint(
         v, NamedSharding(m, mesh_mod.sanitize_spec(P(*spec), m)))
+
+
+# ---------------------------------------------------------------------------
+# the dropless layer: sigmoid routing over all experts, grouped products
+# over the experts held here
+# ---------------------------------------------------------------------------
+
+def _int_zero(v):
+    """The cotangent custom_vjp wants for an integer input."""
+    return np.zeros(v.shape, jax.dtypes.float0)
+
+
+def _keep_rows(v, live):
+    """Rows [live:] of v set to zero. XLA's grouped-matmul kernel on the
+    TPU leaves the rows past the last group UNWRITTEN (5.2 was read there
+    on the v5e, PR 27; the CPU lowering zeroes them), so whatever reads a
+    sorted buffer whole drops the tail first, in both directions."""
+    rows = jnp.arange(v.shape[0], dtype=jnp.int32)[:, None]
+    return jnp.where(rows < live, v, jnp.zeros((), v.dtype))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _rows_by_expert(x2, order, inv, live, k: int):
+    """Dispatch: row a of the result is token order[a] // k of x2 [T, h].
+    The transpose of this gather is a scatter-add; `inv` (order's inverse
+    permutation) turns it into a gather and a sum over each token's k
+    assignments, which the TPU does at memory speed. `live` rows of the
+    sorted buffer belong to held experts."""
+    return jnp.take(x2, order // k, axis=0)
+
+
+def _rows_by_expert_fwd(x2, order, inv, live, k):
+    return _rows_by_expert(x2, order, inv, live, k), (order, inv, live)
+
+
+def _rows_by_expert_bwd(k, res, g):
+    order, inv, live = res
+    dx = jnp.take(_keep_rows(g, live), inv, axis=0)
+    dx = dx.reshape(-1, k, g.shape[-1])
+    return (jnp.sum(dx.astype(jnp.float32), axis=1).astype(g.dtype),
+            _int_zero(order), _int_zero(inv), _int_zero(live))
+
+
+_rows_by_expert.defvjp(_rows_by_expert_fwd, _rows_by_expert_bwd)
+
+
+@jax.custom_vjp
+def _rows_by_token(out, order, inv, live):
+    """Un-sort: row t*k + j of the result is the sorted row of token t's
+    j-th assignment, zero where that went to an absent expert. Forward
+    gathers by `inv`, backward by `order`."""
+    return jnp.take(_keep_rows(out, live), inv, axis=0)
+
+
+def _rows_by_token_fwd(out, order, inv, live):
+    return _rows_by_token(out, order, inv, live), (order, inv, live)
+
+
+def _rows_by_token_bwd(res, g):
+    order, inv, live = res
+    return (_keep_rows(jnp.take(g, order, axis=0), live), _int_zero(order),
+            _int_zero(inv), _int_zero(live))
+
+
+_rows_by_token.defvjp(_rows_by_token_fwd, _rows_by_token_bwd)
+
+
+def sigmoid_topk_route(x2, router_w, bias, top_k: int, scale: float):
+    """DeepSeek-V3 routing (`noaux_tc`, one group) of x2 [T, h] over the
+    router's R outputs, all in float32: scores s = sigmoid(x W_g); the
+    top_k of s + bias are chosen (the bias selects and never weighs, and
+    takes no gradient); weights s[chosen] / sum(s[chosen]) * scale.
+    Returns (chosen [T, k] int32, weights [T, k] float32)."""
+    with jax.named_scope("moe_router"):
+        s = jax.nn.sigmoid(jnp.dot(
+            x2.astype(jnp.float32), router_w.astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST))
+        _, chosen = jax.lax.top_k(s + jax.lax.stop_gradient(bias), top_k)
+        picked = jnp.take_along_axis(s, chosen, axis=-1)
+        w = picked / (jnp.sum(picked, -1, keepdims=True) + 1e-20) * scale
+        return chosen.astype(jnp.int32), w
+
+
+def held_experts_ffn(x2, chosen, weights, w_gate, w_up, w_down, lo: int):
+    """The part of the routed result that experts [lo, lo + E) give, E the
+    leading size of the weights [E, h, f] / [E, f, h] (SwiGLU experts):
+    y[t] = sum over t's chosen experts e held here of weights * E_e(x[t]).
+
+    The T*k assignments are sorted by expert; those to absent experts fall
+    in a tail that no product reads and `_keep_rows` drops, so every
+    assignment to a held expert is computed whatever the load: nothing is
+    dropped and nothing is capped.
+    """
+    T, k = chosen.shape
+    E = w_gate.shape[0]
+    with jax.named_scope("moe_dispatch"):
+        flat = chosen.reshape(-1)
+        held = (flat >= lo) & (flat < lo + E)
+        local = jnp.where(held, flat - lo, E)
+        order = jnp.argsort(local, stable=True).astype(jnp.int32)
+        inv = jnp.zeros_like(order).at[order].set(
+            jnp.arange(order.shape[0], dtype=jnp.int32))
+        group_sizes = jnp.sum(
+            local[:, None] == jnp.arange(E, dtype=local.dtype)[None, :],
+            axis=0, dtype=jnp.int32)
+        live = jnp.sum(group_sizes)
+        xs = _rows_by_expert(x2, order, inv, live, k)
+    with jax.named_scope("moe_experts"):
+        g = jax.lax.ragged_dot(xs, w_gate, group_sizes)
+        u = jax.lax.ragged_dot(xs, w_up, group_sizes)
+        a = (jax.nn.silu(g.astype(jnp.float32))
+             * u.astype(jnp.float32)).astype(x2.dtype)
+        out = jax.lax.ragged_dot(a, w_down, group_sizes)
+    with jax.named_scope("moe_combine"):
+        # rows of assignments to absent experts come back zero
+        sel = _rows_by_token(out, order, inv, live).reshape(T, k, -1)
+        y = jnp.sum(sel.astype(jnp.float32) * weights[..., None], axis=1)
+        return y.astype(x2.dtype)
+
+
+def swiglu(x, w_gate, w_up, w_down):
+    """down(silu(gate(x)) * up(x)), the gate in float32."""
+    a = jax.nn.silu((x @ w_gate).astype(jnp.float32)) \
+        * (x @ w_up).astype(jnp.float32)
+    return a.astype(x.dtype) @ w_down
+
+
+def dropless_moe_val(x2, p: dict, bias, *, top_k: int, scale: float,
+                     lo: int):
+    """x2 [T, h] through the whole layer at value level. `p` holds
+    router_w [h, R], w_gate / w_up [E, h, f], w_down [E, f, h] and, where
+    the layer has shared experts, shared_gate / shared_up [h, fs] and
+    shared_down [fs, h]. Returns (y [T, h], chosen [T, k], the counts of
+    assignments per router output [R] int32)."""
+    chosen, weights = sigmoid_topk_route(x2, p["router_w"], bias, top_k,
+                                         scale)
+    y = held_experts_ffn(x2, chosen, weights.astype(jnp.float32),
+                         p["w_gate"], p["w_up"], p["w_down"], lo)
+    with jax.named_scope("moe_router"):
+        R = p["router_w"].shape[1]
+        counts = jnp.sum(chosen.reshape(-1, 1) == jnp.arange(
+            R, dtype=jnp.int32)[None, :], axis=0, dtype=jnp.int32)
+    if "shared_gate" in p:
+        with jax.named_scope("mlp"):
+            y = y + swiglu(x2, p["shared_gate"], p["shared_up"],
+                           p["shared_down"])
+    return y, chosen, counts
+
+
+class DroplessMoELayer(Layer):
+    """One chip's share of a DeepSeek-V3 style expert layer.
+
+    `router_outputs` experts exist (R); this layer holds those in
+    `experts_held` = [lo, hi). Every token is routed over all R (sigmoid
+    scores, top `experts_per_token` of score + selection bias, weights
+    normalised and scaled by `routed_scaling`), and the layer returns the
+    part of the result its own experts give, plus the shared expert's
+    (one SwiGLU of `shared_width`, 0 for none). What the absent experts
+    would add is left out; held on one chip the layer runs without the
+    exchange that brings other chips' tokens.
+
+    Two buffers ride through jit.TrainStep the way batch-norm statistics
+    do: `select_bias` [R] float32, moved after each training forward by
+    `bias_speed * sign(mean load - load)` from this chip's own counts (the
+    auxiliary-loss-free balancing of arXiv:2412.19437, 2.1.2), and
+    `assign_count` [R] int32, the cumulative assignments per router output.
+    After a forward `self.chosen` holds the chosen experts [T, k], the way
+    MoELayer keeps `aux_loss`, for a comparison with a reference router.
+
+    The layer has no `forward`: a decoder layer runs `apply_val` inside its
+    own traced block (under jax.checkpoint, so that attention and the
+    experts are recomputed as one) and hands `advance` what came out."""
+
+    PARAMS = ("router_w", "w_gate", "w_up", "w_down")
+    SHARED = ("shared_gate", "shared_up", "shared_down")
+
+    def __init__(self, hidden_size, expert_width, router_outputs,
+                 experts_per_token, experts_held=None, shared_width=0,
+                 routed_scaling=1.0, bias_speed=0.001, init_std=0.02,
+                 seed=0, dtype="float32", rs=None):
+        """`rs`: a numpy Generator to draw the weights from, in place of
+        one made from `seed` (a model draws all its layers from one)."""
+        super().__init__()
+        from ..framework import dtype as dtype_mod
+        from ..framework.tensor import Parameter
+
+        lo, hi = experts_held or (0, router_outputs)
+        if not 0 <= lo < hi <= router_outputs:
+            raise ValueError(f"experts_held {experts_held} is no range of "
+                             f"the router's {router_outputs} outputs")
+        self.lo = int(lo)
+        self.top_k = int(experts_per_token)
+        self.routed_scaling = float(routed_scaling)
+        self.bias_speed = float(bias_speed)
+        rs = rs or np.random.default_rng(seed)
+        dt = dtype_mod.convert_dtype(dtype)
+
+        def param(*shape):
+            w = rs.standard_normal(shape, dtype=np.float32) * init_std
+            return Parameter(Tensor(w, dtype=dt)._value, trainable=True)
+
+        E, h, f = int(hi - lo), hidden_size, expert_width
+        self.router_w = param(h, router_outputs)
+        self.w_gate = param(E, h, f)
+        self.w_up = param(E, h, f)
+        self.w_down = param(E, f, h)
+        self.names = self.PARAMS
+        if shared_width:
+            self.shared_gate = param(h, shared_width)
+            self.shared_up = param(h, shared_width)
+            self.shared_down = param(shared_width, h)
+            self.names = self.PARAMS + self.SHARED
+        self.register_buffer("select_bias", Tensor(
+            np.zeros(router_outputs, np.float32)))
+        self.register_buffer("assign_count", Tensor(
+            np.zeros(router_outputs, np.int32)))
+        self.chosen = None
+
+    def apply_val(self, x2, pvals, bias):
+        """The layer at value level on x2 [T, h]: `pvals` in the order of
+        `self.names`. For a caller that runs the layer inside its own
+        traced block (a decoder layer under jax.checkpoint)."""
+        return dropless_moe_val(x2, dict(zip(self.names, pvals)), bias,
+                                top_k=self.top_k, scale=self.routed_scaling,
+                                lo=self.lo)
+
+    def advance(self, chosen, counts):
+        """Keep the router's choice; in training add the step's counts and
+        move the selection bias toward balance. Values, inside or outside
+        a trace."""
+        self.chosen = chosen
+        if not self.training:
+            return
+        c = counts.astype(jnp.float32)
+        self.assign_count._value = self.assign_count._value + counts
+        self.select_bias._value = self.select_bias._value \
+            + self.bias_speed * jnp.sign(jnp.mean(c) - c)

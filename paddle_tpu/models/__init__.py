@@ -10,6 +10,9 @@ from .gpt import (  # noqa: F401
     GPTConfig, GPTForCausalLM, GPTModel, GPTPretrainingCriterion, gpt_presets,
     gpt_1f1b_grad_fn, gpt_1f1b_train_step,
 )
+from .mla_moe import (  # noqa: F401
+    MlaMoeConfig, MlaMoeForCausalLM, MlaMoeModel,
+)
 from .bert import (  # noqa: F401
     BertConfig, BertForPretraining, BertModel, BertPretrainingCriterion,
     bert_presets,

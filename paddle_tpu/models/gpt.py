@@ -184,10 +184,18 @@ def _attention_val(q, k, v, cfg: GPTConfig):
         return ulysses_attention_val(
             q, k, v, axis=SEQ_AXIS, causal=True,
             use_flash=cfg.use_flash_attention and cfg.attn_dropout == 0.0)
+    return _local_attention_val(
+        q, k, v, cfg.use_flash_attention and cfg.attn_dropout == 0.0)
+
+
+def _local_attention_val(q, k, v, use_flash: bool):
+    """Causal attention over the whole sequence on this device: q, k
+    [b, s, n, d_qk], v [b, s, n, d_v]. The Pallas flash kernel on a TPU
+    where its shape gate allows, else the O(s^2) einsum with a float32
+    softmax."""
     from ..framework.target import target_platform
 
-    if (cfg.use_flash_attention and cfg.attn_dropout == 0.0
-            and target_platform() == "tpu"):
+    if use_flash and target_platform() == "tpu":
         from ..ops.flash_attention import flash_attention_sharded_ok
 
         if flash_attention_sharded_ok(q.shape):

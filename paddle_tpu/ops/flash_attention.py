@@ -23,6 +23,12 @@ Layout is [b, n, s, d] inside the kernels (head-major, contiguous (s, d)
 tiles per grid cell); the public entry takes the model's [b, s, n, d] and
 transposes (XLA fuses the transposes into the surrounding program).
 
+q and k share one width d_qk and v has its own d_v (latent attention's
+heads are 192 / 128 wide): the scores contract d_qk and are scaled by
+1/sqrt(d_qk); o, dO, dv and the forward accumulator are d_v wide, dq and dk
+d_qk wide. Nothing is padded to the wider of the two, in HBM or in VMEM.
+With d_qk == d_v the kernels are the ones they were.
+
 Backward uses the standard two-kernel flash decomposition:
   dq kernel:  grid (b, n, q_blocks, kv_blocks), dq accumulates in scratch
   dkv kernel: grid (b, n, kv_blocks, q_blocks), dk/dv accumulate in scratch,
@@ -54,9 +60,10 @@ def _pick_block(s: int, want: int) -> int:
     return 0
 
 
-def _default_block(d: int, dtype) -> int:
+def _default_block(d: int, dtype, d_v: int = None) -> int:
     """Where the ladder starts when the caller names no block: 1024, or 512
-    where a row of the tile (d elements of dtype) is wider than 512 bytes.
+    where a row of the tile (d elements of dtype, the wider of d_qk and
+    d_v) is wider than 512 bytes.
 
     From the chip sweep of PR 26 over {256, 512, 1024} x {256, 512, 1024}
     (v5e, bf16, s1024 and s2048 at d=64, s2048 at d=128; PERF.md section
@@ -67,19 +74,25 @@ def _default_block(d: int, dtype) -> int:
     diagonal by 1.33-1.39x over forward + backward although it computes
     the masked quarter too. 2048 does not fit VMEM in the backward
     kernels, nor does 1024 with fp32 rows of d=256 (the TPU compiler,
-    without the chip): hence the bound on the row's bytes."""
-    return 1024 if d * jnp.dtype(dtype).itemsize <= 512 else 512
+    without the chip): hence the bound on the row's bytes.
+    Latent attention's q, k 192 / v 128 wide in bf16 (a 384-byte row) stay
+    at 1024 by the same rule and by PR 27's sweep at s=8192, 32 heads (ms a
+    call, forward / dq + dkv, ten calls chained in one jit): 1024x1024
+    8.2 / 22.5; 512x1024 9.7 / 23.8; 1024x512 13.5 / 24.1; 512x512 13.8 /
+    25.3; 2048 runs out of VMEM in the dq kernel."""
+    return 1024 if max(d, d_v or d) * jnp.dtype(dtype).itemsize <= 512 \
+        else 512
 
 
-def _tuned_blocks(shape, dtype, causal: bool, want):
-    """(block_q, block_k) for a [b, s, n, d] call: the tuner cache's
+def _tuned_blocks(shape, dtype, causal: bool, want, d_v: int = None):
+    """(block_q, block_k) for a [b, s, n, d_qk] call (v `d_v` wide): the tuner cache's
     validated winner under FLAGS_kernel_autotune when it still fits the
     concrete sequence length, else the _pick_block ladder pair from
     ``want`` (None: from _default_block of the head size and dtype). The
     independent q/k blocks are the point — the cache may hold an
     asymmetric winner the ladder can never produce."""
     s = int(shape[1])
-    want = want or _default_block(int(shape[3]), dtype)
+    want = want or _default_block(int(shape[3]), dtype, d_v)
     from .pallas import autotune as _at
 
     params = _at.lookup(
@@ -206,6 +219,7 @@ def _sds(shape, dtype, like):
 
 def _fwd(q, k, v, causal, block_q, block_k):
     b, n, s, d = q.shape
+    d_v = v.shape[-1]
     grid = (b, n, s // block_q, s // block_k)
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=1.0 / math.sqrt(d),
@@ -216,21 +230,21 @@ def _fwd(q, k, v, causal, block_q, block_k):
                          lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
             pl.BlockSpec((1, 1, block_k, d),
                          lambda bi, hi, qi, ki: (bi, hi, ki, 0)),
-            pl.BlockSpec((1, 1, block_k, d),
+            pl.BlockSpec((1, 1, block_k, d_v),
                          lambda bi, hi, qi, ki: (bi, hi, ki, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, block_q, d),
+            pl.BlockSpec((1, 1, block_q, d_v),
                          lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
             pl.BlockSpec((1, 1, block_q, 1),
                          lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
         ],
         out_shape=[
-            _sds((b, n, s, d), q.dtype, q),
+            _sds((b, n, s, d_v), q.dtype, q),
             _sds((b, n, s, 1), jnp.float32, q),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, d_v), jnp.float32),
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, _LANES), jnp.float32),
         ],
@@ -335,12 +349,23 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _bwd(q, k, v, out, lse, do, causal, block_q, block_k):
     b, n, s, d = q.shape
+    d_v = v.shape[-1]
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1, keepdims=True)                      # (b, n, s, 1)
-    qb = pl.BlockSpec((1, 1, block_q, d),
-                      lambda bi, hi, qi, ki: (bi, hi, qi, 0))
-    kvb = pl.BlockSpec((1, 1, block_k, d),
-                       lambda bi, hi, qi, ki: (bi, hi, ki, 0))
+
+    def tile(rows, width, on_q, kv_major=False):
+        """A (1, 1, rows, width) tile that follows the q block (`on_q`) or
+        the kv block; `kv_major` for the grid whose third axis is kv."""
+        if kv_major:
+            return pl.BlockSpec((1, 1, rows, width),
+                                lambda bi, hi, ki, qi: (
+                                    bi, hi, qi if on_q else ki, 0))
+        return pl.BlockSpec((1, 1, rows, width),
+                            lambda bi, hi, qi, ki: (
+                                bi, hi, qi if on_q else ki, 0))
+
+    qb, dob = tile(block_q, d, True), tile(block_q, d_v, True)
+    kb_, vb_ = tile(block_k, d, False), tile(block_k, d_v, False)
     rowb = pl.BlockSpec((1, 1, block_q, 1),
                         lambda bi, hi, qi, ki: (bi, hi, qi, 0))
 
@@ -348,7 +373,7 @@ def _bwd(q, k, v, out, lse, do, causal, block_q, block_k):
         functools.partial(_dq_kernel, scale=1.0 / math.sqrt(d), causal=causal,
                           block_q=block_q, block_k=block_k),
         grid=(b, n, s // block_q, s // block_k),
-        in_specs=[qb, kvb, kvb, qb, rowb, rowb],
+        in_specs=[qb, kb_, vb_, dob, rowb, rowb],
         out_specs=qb,
         out_shape=_sds((b, n, s, d), q.dtype, q),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
@@ -359,10 +384,8 @@ def _bwd(q, k, v, out, lse, do, causal, block_q, block_k):
     # dkv: grid (b, n, kv_blocks, q_blocks) — q innermost. lse and delta go
     # in as one (1, BQ) row per q block: a block that spans its array's last
     # two dims whole is legal for every block_q
-    qb2 = pl.BlockSpec((1, 1, block_q, d),
-                       lambda bi, hi, ki, qi: (bi, hi, qi, 0))
-    kvb2 = pl.BlockSpec((1, 1, block_k, d),
-                        lambda bi, hi, ki, qi: (bi, hi, ki, 0))
+    qb2, dob2 = tile(block_q, d, True, True), tile(block_q, d_v, True, True)
+    kb2, vb2 = tile(block_k, d, False, True), tile(block_k, d_v, False, True)
     rowb2 = pl.BlockSpec((1, 1, 1, 1, block_q),
                          lambda bi, hi, ki, qi: (bi, hi, qi, 0, 0))
     rows = (b, n, s // block_q, 1, block_q)
@@ -370,12 +393,12 @@ def _bwd(q, k, v, out, lse, do, causal, block_q, block_k):
         functools.partial(_dkv_kernel, scale=1.0 / math.sqrt(d),
                           causal=causal, block_q=block_q, block_k=block_k),
         grid=(b, n, s // block_k, s // block_q),
-        in_specs=[qb2, kvb2, kvb2, qb2, rowb2, rowb2],
-        out_specs=[kvb2, kvb2],
+        in_specs=[qb2, kb2, vb2, dob2, rowb2, rowb2],
+        out_specs=[kb2, vb2],
         out_shape=[_sds((b, n, s, d), k.dtype, k),
-                   _sds((b, n, s, d), v.dtype, v)],
+                   _sds((b, n, s, d_v), v.dtype, v)],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                        pltpu.VMEM((block_k, d), jnp.float32)],
+                        pltpu.VMEM((block_k, d_v), jnp.float32)],
         interpret=_interpret(),
         name="flash_bwd_dkv",
     )(q, k, v, do, lse.reshape(rows), delta.reshape(rows))
@@ -407,7 +430,8 @@ _flash_bnsd.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 def flash_attention_val(q, k, v, causal=True, block_size=None,
                         block_q=None, block_k=None):
-    """Causal flash attention on [b, s, n, d] arrays → [b, s, n, d].
+    """Causal flash attention on q, k [b, s, n, d_qk] and v [b, s, n, d_v]
+    → [b, s, n, d_v], the scores scaled by 1/sqrt(d_qk).
 
     Value-level (raw jax arrays); Tensor-level wrappers live in
     nn/functional/flash_attention.py. Fallback is the caller's job —
@@ -422,8 +446,13 @@ def flash_attention_val(q, k, v, causal=True, block_size=None,
     statistics are float32 for every input dtype (module docstring).
     """
     b, s, n, d = q.shape
+    d_v = int(v.shape[-1])
+    if k.shape != q.shape or v.shape[:3] != q.shape[:3]:
+        raise ValueError(
+            f"flash attention: q {q.shape} and k {k.shape} must agree, and "
+            f"v {v.shape} with them in all but the head size")
     if block_q is not None or block_k is not None:
-        other = block_size or _default_block(d, q.dtype)
+        other = block_size or _default_block(d, q.dtype, d_v)
         bq = int(block_q or other)
         bk = int(block_k or other)
         if not flash_attention_supported(q.shape, block_q=bq, block_k=bk):
@@ -432,7 +461,7 @@ def flash_attention_val(q, k, v, causal=True, block_size=None,
                 f"len {s} (both must divide it and be >= 8)")
     else:
         bq, bk, _src = _tuned_blocks(q.shape, q.dtype, bool(causal),
-                                     block_size)
+                                     block_size, d_v)
         if bq < 8 or bk < 8:
             raise ValueError(
                 f"flash attention: no valid block for seq len {s}")

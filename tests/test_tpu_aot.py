@@ -108,6 +108,17 @@ names = mosaic_names(jax.grad(
 assert names == ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"], names
 print("FLASH-NAMES-OK")
 
+# latent attention's two widths (q, k 192 and v 128 wide) at the shape of
+# the cell kanana-2-30b-a3b-ep8.train-b1-s8192, forward and both backward
+# kernels at the block dispatch picks: a 192-wide row that Mosaic cannot
+# tile, or a block over the VMEM limit at s = 8192, fails here
+q, v = SDS((1, 8192, 32, 192), bf16), SDS((1, 8192, 32, 128), bf16)
+text = compile_for_one_chip(jax.grad(
+    lambda a, b, c: jnp.sum(flash_attention_val(a, b, c).astype(f32)),
+    argnums=(0, 1, 2)), q, q, v).as_text()
+assert text.count('custom_call_target="tpu_custom_call"') == 3, text[:2000]
+print("FLASH-MLA-OK")
+
 for k, n in ((768, 3072), (2048, 8192), (3072, 768), (8192, 2048)):
     for stochastic in (False, True):
         mosaic(lambda w: quantize_int8(w, stochastic=stochastic, seed=3),
@@ -184,8 +195,8 @@ def test_trainstep_with_flash_compiles_for_tpu():
 
 def test_pallas_families_compile_by_mosaic():
     out = _run_child(KERNELS_CHILD)
-    for tag in ("FLASH-OK", "FLASH-NAMES-OK", "QUANTIZE-OK", "QMM-OK",
-                "CODEC-OK", "FUSED-UPDATE-OK"):
+    for tag in ("FLASH-OK", "FLASH-NAMES-OK", "FLASH-MLA-OK", "QUANTIZE-OK",
+                "QMM-OK", "CODEC-OK", "FUSED-UPDATE-OK"):
         assert tag in out, out[-2000:]
 
 
