@@ -158,23 +158,79 @@ def _int_zero(v):
     return np.zeros(v.shape, jax.dtypes.float0)
 
 
-def _keep_rows(v, live):
-    """Rows [live:] of v set to zero. XLA's grouped-matmul kernel on the
-    TPU leaves the rows past the last group UNWRITTEN (5.2 was read there
-    on the v5e, PR 27; the CPU lowering zeroes them), so whatever reads a
-    sorted buffer whole drops the tail first, in both directions."""
-    rows = jnp.arange(v.shape[0], dtype=jnp.int32)[:, None]
-    return jnp.where(rows < live, v, jnp.zeros((), v.dtype))
+# Rows a sorted-side pass moves at a time. 2048 rows of a [T*k, h] buffer
+# are a few MB: long enough that a loop's step costs little beside its
+# rows, short enough that rounding `live` up to it wastes little.
+ROW_BLOCK = 2048
+
+
+def _row_blocks(live, n_rows: int):
+    """(rows a block, blocks that hold the first `live` of `n_rows` rows)."""
+    block = min(ROW_BLOCK, n_rows)
+    return block, -(-live // block)
+
+
+def rows_covered(live, n_rows: int):
+    """The sorted rows the passes below write when `live` of `n_rows`
+    belong to held experts: `live` rounded up to whole blocks."""
+    block, trips = _row_blocks(live, n_rows)
+    return jnp.minimum(trips * block, n_rows)
+
+
+def _take_rows(v, idx):
+    """v[idx] for indices this module made (a permutation, or token
+    numbers): all in bounds, so no pass checks them."""
+    return v.at[idx].get(mode="promise_in_bounds")
+
+
+def _sorted_rows(src, idx, live):
+    """Row a of the result is src[idx[a]] for every a < rows_covered(live):
+    a loop over blocks of rows whose trip count the device computes from
+    `live`, so the pass costs what the step's held experts were given and
+    not T*k. The rows past that are zero and stand for NOTHING: what reads
+    a sorted buffer stops at the last group (the grouped products) or drops
+    the tail where it consumes it (`_live_rows_by_token`)."""
+    n = idx.shape[0]
+    block, trips = _row_blocks(live, n)
+    # XLA sinks a fusible producer of a loop's operand into the loop's body
+    # (seen compiling for the v5e: the whole [T*k, h] cotangent was made
+    # again in every trip); behind a barrier it is made once
+    src = jax.lax.optimization_barrier(src)
+
+    def move(i, out):
+        # the last block of a buffer that is no whole number of blocks
+        # starts early and writes some rows a second time, alike
+        start = jnp.minimum(i * block, n - block)
+        rows = _take_rows(src, jax.lax.dynamic_slice(idx, (start,), (block,)))
+        return jax.lax.dynamic_update_slice(out, rows, (start, 0))
+
+    return jax.lax.fori_loop(0, trips, move,
+                             jnp.zeros((n,) + src.shape[1:], src.dtype))
+
+
+def _live_rows_by_token(rows, inv, live):
+    """Row t*k + j of the result is the sorted row inv[t*k + j] of token
+    t's j-th assignment, zero where that went to an absent expert (a sorted
+    row at or past `live`). This is where the tail is dropped, in both
+    directions: on the gathered rows, inside the sum over a token's k
+    assignments that consumes them, not in a pass of its own. A `where` and
+    never a multiply: XLA's grouped-matmul kernel on the TPU leaves the rows
+    past the last group UNWRITTEN (5.2 was read there on the v5e, PR 27;
+    NaN is as likely; the CPU lowering zeroes them), and 0 * NaN is NaN."""
+    got = _take_rows(rows, inv)
+    return jnp.where((inv < live)[:, None], got, jnp.zeros((), got.dtype))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
 def _rows_by_expert(x2, order, inv, live, k: int):
-    """Dispatch: row a of the result is token order[a] // k of x2 [T, h].
-    The transpose of this gather is a scatter-add; `inv` (order's inverse
-    permutation) turns it into a gather and a sum over each token's k
-    assignments, which the TPU does at memory speed. `live` rows of the
-    sorted buffer belong to held experts."""
-    return jnp.take(x2, order // k, axis=0)
+    """Dispatch: row a of the result is token order[a] // k of x2 [T, h],
+    for the `live` rows that belong to held experts (rounded up to a block;
+    the tail has no value, see `_sorted_rows`). The transpose of this
+    gather is a scatter-add; `inv` (order's inverse permutation) turns it
+    into a gather and a sum over each token's k assignments, which the TPU
+    does at memory speed. The cotangent's tail comes out of the grouped
+    products unwritten and is dropped in that sum (`_live_rows_by_token`)."""
+    return _sorted_rows(x2, order // k, live)
 
 
 def _rows_by_expert_fwd(x2, order, inv, live, k):
@@ -183,8 +239,7 @@ def _rows_by_expert_fwd(x2, order, inv, live, k):
 
 def _rows_by_expert_bwd(k, res, g):
     order, inv, live = res
-    dx = jnp.take(_keep_rows(g, live), inv, axis=0)
-    dx = dx.reshape(-1, k, g.shape[-1])
+    dx = _live_rows_by_token(g, inv, live).reshape(-1, k, g.shape[-1])
     return (jnp.sum(dx.astype(jnp.float32), axis=1).astype(g.dtype),
             _int_zero(order), _int_zero(inv), _int_zero(live))
 
@@ -195,9 +250,12 @@ _rows_by_expert.defvjp(_rows_by_expert_fwd, _rows_by_expert_bwd)
 @jax.custom_vjp
 def _rows_by_token(out, order, inv, live):
     """Un-sort: row t*k + j of the result is the sorted row of token t's
-    j-th assignment, zero where that went to an absent expert. Forward
-    gathers by `inv`, backward by `order`."""
-    return jnp.take(_keep_rows(out, live), inv, axis=0)
+    j-th assignment, zero where that went to an absent expert: the tail the
+    grouped products leave unwritten is dropped here, on the gathered rows
+    (`_live_rows_by_token`). Forward gathers by `inv`; backward by `order`,
+    the live rows alone: the cotangent's tail needs no value, since only
+    the grouped products read it and they stop at the last group."""
+    return _live_rows_by_token(out, inv, live)
 
 
 def _rows_by_token_fwd(out, order, inv, live):
@@ -206,8 +264,8 @@ def _rows_by_token_fwd(out, order, inv, live):
 
 def _rows_by_token_bwd(res, g):
     order, inv, live = res
-    return (_keep_rows(jnp.take(g, order, axis=0), live), _int_zero(order),
-            _int_zero(inv), _int_zero(live))
+    return (_sorted_rows(g, order, live), _int_zero(order), _int_zero(inv),
+            _int_zero(live))
 
 
 _rows_by_token.defvjp(_rows_by_token_fwd, _rows_by_token_bwd)
@@ -235,9 +293,14 @@ def held_experts_ffn(x2, chosen, weights, w_gate, w_up, w_down, lo: int):
     y[t] = sum over t's chosen experts e held here of weights * E_e(x[t]).
 
     The T*k assignments are sorted by expert; those to absent experts fall
-    in a tail that no product reads and `_keep_rows` drops, so every
-    assignment to a held expert is computed whatever the load: nothing is
-    dropped and nothing is capped.
+    in a tail behind the `live` rows of the held ones. No pass runs over
+    the sorted [T*k, h] buffers for longer than `live` asks: the gathers
+    that write them stop at rows_covered(live) (`_sorted_rows`), the
+    grouped products stop at the last group, and the gathers that read
+    them back by token drop the tail on the rows they gathered, with a
+    `where` since the tail may hold anything (`_live_rows_by_token`). Every
+    assignment to a held expert is computed whatever the load, from none to
+    all T*k: nothing is dropped and nothing is capped.
     """
     T, k = chosen.shape
     E = w_gate.shape[0]
@@ -312,8 +375,12 @@ class DroplessMoELayer(Layer):
     `bias_speed * sign(mean load - load)` from this chip's own counts (the
     auxiliary-loss-free balancing of arXiv:2412.19437, 2.1.2), and
     `assign_count` [R] int32, the cumulative assignments per router output.
-    After a forward `self.chosen` holds the chosen experts [T, k], the way
-    MoELayer keeps `aux_loss`, for a comparison with a reference router.
+    A third counts work: `touched_count` int32, the cumulative sorted rows
+    the layer's passes covered (rows_covered of the held experts' rows), so
+    touched_count / (steps * T * k) is the share of the sorted buffer that
+    was moved at all (read it as a difference: int32 wraps). After a forward `self.chosen` holds the chosen
+    experts [T, k], the way MoELayer keeps `aux_loss`, for a comparison
+    with a reference router, and `self.rows_touched` that forward's rows.
 
     The layer has no `forward`: a decoder layer runs `apply_val` inside its
     own traced block (under jax.checkpoint, so that attention and the
@@ -362,7 +429,9 @@ class DroplessMoELayer(Layer):
             np.zeros(router_outputs, np.float32)))
         self.register_buffer("assign_count", Tensor(
             np.zeros(router_outputs, np.int32)))
+        self.register_buffer("touched_count", Tensor(np.zeros((), np.int32)))
         self.chosen = None
+        self.rows_touched = None
 
     def apply_val(self, x2, pvals, bias):
         """The layer at value level on x2 [T, h]: `pvals` in the order of
@@ -377,9 +446,14 @@ class DroplessMoELayer(Layer):
         move the selection bias toward balance. Values, inside or outside
         a trace."""
         self.chosen = chosen
+        n_held = self.w_gate.shape[0]
+        self.rows_touched = rows_covered(
+            jnp.sum(counts[self.lo:self.lo + n_held]), chosen.size)
         if not self.training:
             return
         c = counts.astype(jnp.float32)
         self.assign_count._value = self.assign_count._value + counts
+        self.touched_count._value = self.touched_count._value \
+            + self.rows_touched
         self.select_bias._value = self.select_bias._value \
             + self.bias_speed * jnp.sign(jnp.mean(c) - c)

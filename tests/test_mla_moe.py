@@ -148,6 +148,9 @@ def test_train_step_on_the_tiny_model():
         bias = np.asarray(m.select_bias.numpy())
         assert np.any(bias != 0) and np.max(np.abs(bias)) <= 8 * 0.001 + 1e-9
         assert m.chosen.shape == (2 * 32, cfg.num_experts_per_tok)
+        # fewer rows than a block: a step covers all of them or none
+        touched = int(m.touched_count.numpy())
+        assert 0 < touched <= 8 * 256 and touched % 256 == 0
 
 
 def test_eval_forward_leaves_the_buffers():
@@ -159,6 +162,7 @@ def test_eval_forward_leaves_the_buffers():
     m = model.model.moe_layers()[0]
     assert np.all(m.assign_count.numpy() == 0)
     assert np.all(m.select_bias.numpy() == 0)
+    assert m.touched_count.numpy() == 0 and int(m.rows_touched) == 2 * 48 * 4
 
 
 # ------------------------------------------------------ the expert layer
@@ -269,6 +273,182 @@ def test_the_bias_moves_toward_balance():
     mean = counts.mean()
     assert np.all(bias[counts > mean] == pytest.approx(-0.001))
     assert np.all(bias[counts < mean] == pytest.approx(0.001))
+
+
+# ------------------------------------- the sorted buffer's live rows and tail
+def _plain_held_experts_ffn(x2, chosen, weights, w_gate, w_up, w_down, lo):
+    """The formulation this layer had at PR 27, kept as the reference: a
+    gather of all T*k rows each way, a pass of its own that zeroes the
+    tail, and jax's own transposes."""
+    T, k = chosen.shape
+    E = w_gate.shape[0]
+    flat = chosen.reshape(-1)
+    held = (flat >= lo) & (flat < lo + E)
+    local = jnp.where(held, flat - lo, E)
+    order = jnp.argsort(local, stable=True)
+    inv = jnp.zeros_like(order).at[order].set(jnp.arange(order.shape[0]))
+    group_sizes = jnp.sum(local[:, None] == jnp.arange(E)[None, :], axis=0,
+                          dtype=jnp.int32)
+    xs = jnp.take(x2, order // k, axis=0)
+    a = jax.nn.silu(jax.lax.ragged_dot(xs, w_gate, group_sizes)) \
+        * jax.lax.ragged_dot(xs, w_up, group_sizes)
+    out = jax.lax.ragged_dot(a, w_down, group_sizes)
+    rows = jnp.arange(out.shape[0])[:, None]
+    out = jnp.where(rows < jnp.sum(group_sizes), out, 0.0)     # _keep_rows
+    sel = jnp.take(out, inv, axis=0).reshape(T, k, -1)
+    return jnp.sum(sel * weights[..., None], axis=1)
+
+
+# 1,250 tokens x 4 = 5,000 assignment rows: two whole blocks and a part
+N_TOKENS, N_K, N_HELD_LO, N_HELD = 1250, 4, 4, 4
+BLOCK = moe.ROW_BLOCK
+# rows of held experts -> rows the sorted-side passes cover
+LIVE_CASES = {0: 0, 1: BLOCK, BLOCK - 1: BLOCK, BLOCK: BLOCK,
+              BLOCK + 1: 2 * BLOCK, 2 * BLOCK: 2 * BLOCK,
+              2 * BLOCK + 1: N_TOKENS * N_K, N_TOKENS * N_K: N_TOKENS * N_K}
+
+
+def _assignments(live, seed=0, h=32, f=16):
+    """Inputs of held_experts_ffn (experts 4-7 of 16 held) in which exactly
+    `live` of the 5,000 assignments go to held experts."""
+    r = np.random.RandomState(seed)
+    n = N_TOKENS * N_K
+    absent = np.r_[0:N_HELD_LO, N_HELD_LO + N_HELD:16]
+    flat = absent[r.randint(0, len(absent), n)]
+    at = r.permutation(n)[:live]
+    flat[at] = N_HELD_LO + r.randint(0, N_HELD, live)
+    vals = [r.randn(N_TOKENS, h), r.uniform(0.1, 1.0, (N_TOKENS, N_K)),
+            r.randn(N_HELD, h, f) * 0.2, r.randn(N_HELD, h, f) * 0.2,
+            r.randn(N_HELD, f, h) * 0.2]
+    x2, weights, *w = (jnp.asarray(v, jnp.float32) for v in vals)
+    chosen = jnp.asarray(flat.reshape(N_TOKENS, N_K), jnp.int32)
+    cot = jnp.asarray(r.randn(N_TOKENS, h), jnp.float32)
+    return chosen, (x2, weights, *w), cot
+
+
+FFN_OUTPUTS = ("y", "d x2", "d weights", "d w_gate", "d w_up", "d w_down")
+
+
+def _value_and_grads(ffn, chosen, args, cot):
+    """FFN_OUTPUTS of `ffn` on _assignments' inputs, under the weight `cot`
+    on every output."""
+    def value(*a):
+        x2, weights, *w = a
+        y = ffn(x2, chosen, weights, *w, N_HELD_LO)
+        return jnp.sum(y * cot), y
+
+    (_, y), grads = jax.jit(jax.value_and_grad(
+        value, argnums=tuple(range(5)), has_aux=True))(*args)
+    return (y,) + grads
+
+
+@pytest.mark.parametrize("live", sorted(LIVE_CASES))
+def test_held_experts_equal_the_plain_formulation_at_every_load(live):
+    chosen, args, cot = _assignments(live)
+    got = _value_and_grads(moe.held_experts_ffn, chosen, args, cot)
+    want = _value_and_grads(_plain_held_experts_ffn, chosen, args, cot)
+    for name, g, w in zip(FFN_OUTPUTS, got, want):
+        np.testing.assert_allclose(g, w, atol=2e-5, err_msg=name)
+    # none of the held chosen: nothing comes back, nothing flows
+    assert (float(jnp.max(jnp.abs(want[0]))) > 0.01) == (live > 0)
+
+
+def _ragged_dot_that_leaves_the_tail_unwritten():
+    """jax.lax.ragged_dot as the v5e runs it (PERF.md finding 14): the
+    kernel stops at the last group, forward and backward. It reads no row
+    past it (whatever stands there is zeroed before the CPU's product sees
+    it) and writes none (they come back NaN)."""
+    real = jax.lax.ragged_dot
+
+    def tail(v, group_sizes, fill):
+        rows = jnp.arange(v.shape[0])[:, None]
+        return jnp.where(rows < jnp.sum(group_sizes), v, fill)
+
+    @jax.custom_vjp
+    def poisoned(lhs, rhs, group_sizes):
+        out = real(tail(lhs, group_sizes, 0.0), rhs, group_sizes)
+        return tail(out, group_sizes, jnp.nan)
+
+    def fwd(lhs, rhs, group_sizes):
+        return poisoned(lhs, rhs, group_sizes), (lhs, rhs, group_sizes)
+
+    def bwd(res, g):
+        lhs, rhs, group_sizes = res
+        _, vjp = jax.vjp(lambda a, b: real(a, b, group_sizes),
+                         tail(lhs, group_sizes, 0.0), rhs)
+        d_lhs, d_rhs = vjp(tail(g, group_sizes, 0.0))
+        return (tail(d_lhs, group_sizes, jnp.nan), d_rhs,
+                np.zeros(group_sizes.shape, jax.dtypes.float0))
+
+    poisoned.defvjp(fwd, bwd)
+    return poisoned
+
+
+@pytest.mark.parametrize("live", sorted(LIVE_CASES))
+def test_a_poisoned_tail_reaches_no_output_and_no_gradient(live, monkeypatch):
+    chosen, args, cot = _assignments(live, seed=1)
+    want = _value_and_grads(moe.held_experts_ffn, chosen, args, cot)
+    monkeypatch.setattr(jax.lax, "ragged_dot",
+                        _ragged_dot_that_leaves_the_tail_unwritten())
+    got = _value_and_grads(moe.held_experts_ffn, chosen, args, cot)
+    for name, g, w in zip(FFN_OUTPUTS, got, want):
+        assert np.all(np.isfinite(g)), name
+        np.testing.assert_allclose(g, w, atol=1e-6, err_msg=name)
+
+
+def test_the_poison_is_felt_where_the_tail_is_multiplied_away(monkeypatch):
+    """The guard guards: a combine that drops the tail by a multiply (0 *
+    NaN) is caught by the poisoned product, and the whole layer, router and
+    shared expert and all, is not."""
+    layer, _, _ = _layer_and_reference((4, 8))
+    x2 = jnp.asarray(np.random.RandomState(2).randn(700, 32), jnp.float32)
+    vals = [getattr(layer, n)._value for n in layer.names]
+
+    def loss(x, *p):
+        return jnp.sum(layer.apply_val(x, p, layer.select_bias._value)[0]
+                       ** 2)
+
+    argnums = tuple(range(1 + len(vals)))
+    want = jax.grad(loss, argnums)(x2, *vals)
+    monkeypatch.setattr(jax.lax, "ragged_dot",
+                        _ragged_dot_that_leaves_the_tail_unwritten())
+    got = jax.grad(loss, argnums)(x2, *vals)
+    for name, g, w in zip(("x2",) + layer.names, got, want):
+        assert np.all(np.isfinite(g)), name
+        np.testing.assert_allclose(g, w, atol=1e-5, err_msg=name)
+    monkeypatch.setattr(
+        moe, "_live_rows_by_token", lambda rows, inv, live: jnp.take(
+            rows, inv, axis=0) * (inv < live)[:, None].astype(rows.dtype))
+    assert not np.all(np.isfinite(loss(x2, *vals)))
+
+
+@pytest.mark.parametrize("live", sorted(LIVE_CASES))
+def test_rows_touched_is_live_rounded_up_to_a_block(live):
+    covered = LIVE_CASES[live]
+    chosen, (x2, *_), _ = _assignments(live, seed=2)
+    # what the loop wrote: every row it covered holds its token, the rest
+    # were never visited
+    src = jnp.abs(x2) + 1.0
+    idx = jnp.asarray(np.random.RandomState(3).randint(
+        0, N_TOKENS, N_TOKENS * N_K), jnp.int32)
+    got = np.asarray(moe._sorted_rows(src, idx, jnp.int32(live)))
+    np.testing.assert_array_equal(got[:covered], np.asarray(src)[
+        np.asarray(idx)[:covered]])
+    assert np.all(got[covered:] == 0)
+    assert int(moe.rows_covered(jnp.int32(live), N_TOKENS * N_K)) == covered
+    # what the layer says it wrote, and keeps count of in training alone
+    layer = moe.DroplessMoELayer(32, 16, 16, N_K, experts_held=(
+        N_HELD_LO, N_HELD_LO + N_HELD))
+    counts = jnp.bincount(chosen.reshape(-1), length=16).astype(jnp.int32)
+    layer.train()
+    layer.advance(chosen, counts)
+    layer.advance(chosen, counts)
+    assert int(layer.rows_touched) == covered
+    assert int(layer.touched_count.numpy()) == 2 * covered
+    layer.eval()
+    layer.advance(chosen, counts)
+    assert int(layer.rows_touched) == covered
+    assert int(layer.touched_count.numpy()) == 2 * covered
 
 
 # ------------------------------------------------- rotary, attention, kernel
