@@ -3,9 +3,11 @@ kind's driver, the per-layer readers, and the one result line.
 
 The contract (the driver reads the LAST line of stdout as one JSON object):
   correct, attempted, failed, metrics{name: {value, unit}}, device{platform,
-  kind, count, memory_peak_bytes [, busy_s, window_s]} [, breakdown].
+  kind, count, memory_peak_bytes [, busy_s, window_s]} [, breakdown],
+  compared{name: [number, limit]}.
 With --trace 0 the metrics are the cell's end-to-end metrics, with
---trace 1 its per-layer metrics.
+--trace 1 its per-layer metrics. `compared` comes last: every number that
+decided `correct` beside its limit, also the run's last lines on stderr.
 """
 from __future__ import annotations
 
@@ -43,6 +45,8 @@ class Record:
     t_window_end: float               # ... and the measured work here
     obs: dict = field(default_factory=dict)   # raw material for readers
     why_incorrect: list = field(default_factory=list)
+    # every number that decided `correct`: name -> (number, its limit)
+    compared: dict = field(default_factory=dict)
 
 
 class CompileLog:
@@ -210,6 +214,13 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
             if tr.get("breakdown"):
                 result["breakdown"] = tr["breakdown"]
     result["device"] = dev
+    compared = dict(record.compared,
+                    compiles_in_window=(comp["compiles_in_window"], 0))
+    result["compared"] = {k: [float(v), float(lim)]
+                          for k, (v, lim) in compared.items()}
+    for k, (v, lim) in result["compared"].items():
+        print(f"[compared] {k} {v:.6g} limit {lim:.6g}", file=sys.stderr,
+              flush=True)
     return result
 
 

@@ -155,6 +155,7 @@ def run(cell, opts) -> Record:
     ref = program_gpt.check_forward_loss(cell, model, cfg, opts.seed, log)
     if not ref["ok"]:
         why.append(ref["why"])
+    compared = dict(ref["compared"])
 
     kept = [s for seg in segments for s in chunk_seconds(seg, drop)]
     losses = [float(l) for seg in segments for l in seg["losses"]]
@@ -185,10 +186,12 @@ def run(cell, opts) -> Record:
 
     # correctness, part 2: finite losses that fall
     bad = [x for x in losses_all if not np.isfinite(x)]
+    compared["non_finite_losses"] = (len(bad), 0)
     if bad:
         why.append(f"{len(bad)} non-finite losses")
     if len(losses) >= 6:
         first3, last3 = np.median(losses[:3]), np.median(losses[-3:])
+        compared["loss_last3_over_first3"] = (last3 / first3, 1.0)
         if not last3 < first3:
             why.append(f"loss did not fall: median of first three "
                        f"{first3:.4f}, of last three {last3:.4f}")
@@ -209,4 +212,4 @@ def run(cell, opts) -> Record:
     return Record(attempted=steps, failed=len(bad) * k,
                   end_to_end=end_to_end,
                   t_window_start=t_window, t_window_end=t_window_end,
-                  obs=obs, why_incorrect=why)
+                  obs=obs, why_incorrect=why, compared=compared)

@@ -92,10 +92,11 @@ def run(cell, opts) -> Record:
     want = steps * batch * seq * top_k * n_moe
     lo, hi = cfgd["experts_held"]
     held = counted[:, lo:hi]
+    held_share = float(held.sum()) / max(float(counted.sum()), 1.0)
     log(f"[moe] {steps} steps: {int(counted.sum())} assignments counted in "
         f"{n_moe} expert layers, {want} = steps x tokens x {top_k} x layers "
         f"expected; to the {hi - lo} held experts {int(held.sum())} "
-        f"({held.sum() / max(counted.sum(), 1):.4f} of all; uniform "
+        f"({held_share:.4f} of all; uniform "
         f"{(hi - lo) / counted.shape[1]:.4f}); per layer max/mean of the "
         f"held {[round(float(r.max() / max(r.mean(), 1e-9)), 3) for r in held]}")
     if int(counted.sum()) != want:
@@ -110,6 +111,9 @@ def run(cell, opts) -> Record:
                                                   log)
     if not ref["ok"]:
         why.append(ref["why"])
+    compared = dict(ref["compared"],
+                    assignments_off_expected=(abs(int(counted.sum()) - want),
+                                              0))
 
     kept = [s for seg in segments for s in chunk_seconds(seg, drop)]
     losses = [float(l) for seg in segments for l in seg["losses"]]
@@ -139,10 +143,12 @@ def run(cell, opts) -> Record:
 
     # part 3: finite losses that fall
     bad = [x for x in losses_all if not np.isfinite(x)]
+    compared["non_finite_losses"] = (len(bad), 0)
     if bad:
         why.append(f"{len(bad)} non-finite losses")
     if len(losses) >= 6:
         first3, last3 = np.median(losses[:3]), np.median(losses[-3:])
+        compared["loss_last3_over_first3"] = (last3 / first3, 1.0)
         if not last3 < first3:
             why.append(f"loss did not fall: median of first three "
                        f"{first3:.4f}, of last three {last3:.4f}")
@@ -169,6 +175,7 @@ def run(cell, opts) -> Record:
                              for key, v in experts_cost.items()},
         "moe_load_max_over_mean": float(
             held.sum(0).max() / max(held.sum(0).mean(), 1e-9)),
+        "moe_held_share": 100.0 * held_share,
         "moe_held_load": held.tolist(),
         "hbm_window_peak_gb": window_peak / 1e9 if window_peak else None,
         "reference": ref,
@@ -176,4 +183,4 @@ def run(cell, opts) -> Record:
     return Record(attempted=steps, failed=len(bad) * k,
                   end_to_end=end_to_end,
                   t_window_start=t_window, t_window_end=t_window_end,
-                  obs=obs, why_incorrect=why)
+                  obs=obs, why_incorrect=why, compared=compared)

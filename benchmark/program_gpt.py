@@ -189,6 +189,8 @@ def check_forward_loss(cell, model, cfg, seed: int, log) -> dict:
         f"({gpt2_ref.LOGIT_TOL_SIGMAS} sigma)")
     return {"ok": ok, "program": got, "reference": want, "abs_err": err,
             "max_abs_logit_err": lerr, "sigma": sigma,
+            "compared": {"loss_abs_err": (err, gpt2_ref.LOSS_ATOL),
+                         "logit_max_abs_err": (lerr, tol)},
             "why": f"forward differs from the reference: loss {got:.5f} vs "
                    f"{want:.5f} (tolerance {gpt2_ref.LOSS_ATOL}), max "
                    f"|dlogit| {lerr:.4f} (tolerance {tol:.4f})"}

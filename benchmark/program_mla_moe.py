@@ -70,10 +70,25 @@ def build_train(cell, seed: int) -> dict:
         raise ValueError(f"optimizer {o['name']!r}: only AdamW is wired")
     crit = GPTPretrainingCriterion()
     model = MlaMoeForCausalLM(cfg, seed=_seed32(seed))
+    redraw_embedding(model, cell.config["embedding_initializer_range"], seed)
     optim = opt.AdamW(learning_rate=o["learning_rate"],
                       parameters=model.parameters())
     step = TrainStep(model, lambda lg, lb: crit(lg, lb), optim)
     return {"step": step, "model": model, "cfg": cfg}
+
+
+def redraw_embedding(model, std: float, seed: int) -> None:
+    """The token embedding drawn N(0, std) from `seed`, in a generator
+    stream of its own (1 and 2 are the ids'), so that every other weight
+    stays what the model's own stream drew: the configuration file's
+    `embedding_initializer_range` (under `assumed`: the benchmark's
+    statement about its weights; the model has no such option). With the
+    embedding as small as every other matrix all tokens of a layer route
+    alike (PERF.md finding 13)."""
+    emb = model.model.embed_tokens
+    draws = traffic_gen._rng(seed, 3).standard_normal(tuple(emb.shape),
+                                                      dtype=np.float32)
+    emb.set_value(draws * np.float32(std))
 
 
 def check_step_program(built: dict, log) -> None:
@@ -330,6 +345,14 @@ def compare_with_reference(model, ref_cfg: dict, x, y, log,
             "router_flip_share": own_share,
             "router_max_margin": max_margin,
             "grad_rel_err": grad_err, "max_grad_rel_err": grad_err[worst],
+            "compared": {
+                "router_same_input_flip_share":
+                    (same_share, ref_mod.ROUTER_SAME_INPUT_FLIP_TOL),
+                "router_max_margin": (max_margin, ref_mod.ROUTER_MARGIN_TOL),
+                "loss_abs_err": (err, ref_mod.LOSS_ATOL),
+                "logit_max_abs_err": (lerr, tol),
+                "grad_rel_err_worst": (grad_err[worst],
+                                       ref_mod.GRAD_REL_TOL)},
             "why": f"the program differs from the reference: on the "
                    f"reference's router input the program's router chooses "
                    f"otherwise on a share {same_share:.5f} of (token, "

@@ -59,6 +59,24 @@ and this chip's experts may get no token (PERF.md findings 13, 15, 16).
 Readings: TPU v5e, the cell's size, PR 27; "control" = the same run with a
 mutant of tests/test_mla_moe.py patched in.
 
+SINCE PR 30 the cell's embedding is N(0, 1) and AdamW runs at 1e-5: the
+model's own stream differs from token to token too, and near-ties are spread
+over every pass. No limit moved. Readings at that traffic (TPU v5e, the
+cell's size, PR 30): the program as it is, 25 runs on 17 seeds; each control
+at the cell's own load and window, patched in the same way:
+  (a1) 0 of 40,960 pairs in every run. Router in bfloat16, three seeds:
+       0.0928, 0.0974, 0.0981 (506-1,170 of each ids layer's 8,192 tokens,
+       no longer 5 of 32,768). Softmax for sigmoid: 0.890.
+  (a2) differs on 4.2-6.5 % of the pairs, largest margin 0.0029-0.0052.
+       Softmax for sigmoid: 0.0688. (The bfloat16 router: 0.0035-0.0041,
+       passes this one and fails (a1).)
+  (b)  |dloss| <= 0.00031, max |dlogit| 0.047-0.053 sigma. Softmax for
+       sigmoid: 0.91 sigma.
+  (c)  worst parameter 0.019-0.055 (the router's or a routed expert's
+       weights; attention's, the input's and the shared expert's
+       0.004-0.008). Half the gradient of the shared W_up: 0.500 on that
+       parameter, the others unmoved.
+
 Part (b), the forward on the ids, the reference computed on the program's
 choices: gpt2_ref.py's limits and their reasons hold unchanged:
   LOGIT_TOL_SIGMAS = 0.2, LOSS_ATOL = 0.02.

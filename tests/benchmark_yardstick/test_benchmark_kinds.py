@@ -13,7 +13,8 @@ from benchmark import harness, manifest as mf
 
 from conftest import PRETEND_TPU, REPO, TEST_CELLS, build_root
 
-RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device",
+               "compared"}
 DEVICE_KEYS = {"platform", "kind", "count", "memory_peak_bytes"}
 
 
@@ -33,6 +34,12 @@ def _check_line(result, cell_metrics):
     json.loads(json.dumps(result))                  # one JSON object
     assert set(result) - {"breakdown"} == RESULT_KEYS
     assert DEVICE_KEYS <= set(result["device"])
+    # every number that decided `correct` beside its limit, as the last key
+    assert list(result)[-1] == "compared"
+    assert {"loss_abs_err", "logit_max_abs_err", "non_finite_losses",
+            "compiles_in_window"} <= set(result["compared"])
+    for value, limit in result["compared"].values():
+        assert isinstance(value, float) and isinstance(limit, float)
     assert result["correct"] is True and result["failed"] == 0
     assert result["attempted"] > 0
     assert set(result["metrics"]) <= set(cell_metrics)

@@ -8,9 +8,11 @@ import os
 import time
 import types
 
+import numpy as np
 import pytest
 
-from benchmark import flops_mla_moe, harness, manifest as mf, program_mla_moe
+from benchmark import (flops_mla_moe, harness, manifest as mf,
+                       program_mla_moe, traffic_gen)
 from benchmark.readers import named_ops, program_scopes, window_counters
 
 from conftest import PRETEND_TPU, REPO, build_root
@@ -55,8 +57,8 @@ def moe_root(tmp_path_factory):
         json.dump(cfg, f)
     with open(os.path.join(bdir, "traffic", "train-b1-s8192.json")) as f:
         tr = json.load(f)
-    # 128 tokens a step: a learning rate at which the loss falls by more
-    # than one batch differs from the next
+    # 128 tokens a step: the cell's 1e-5 moves the loss by less than one
+    # batch differs from the next, so a learning rate at which it falls
     tr.update(global_batch=2, seq=64, drop_chunks=1, min_kept_chunks=2,
               trace_chunks=2, optimizer={"name": "AdamW",
                                          "learning_rate": 2e-4},
@@ -100,7 +102,7 @@ def test_the_cell_loads_through_the_manifest():
     assert {"mfu", "flash_mla_roofline", "moe_experts_roofline",
             "moe_experts_time_share", "moe_route_time_share",
             "unnamed_time_share", "moe_load_max_over_mean",
-            "hbm_window_peak_gb.train", "flash_fwd_ms_step",
+            "moe_held_share", "hbm_window_peak_gb.train", "flash_fwd_ms_step",
             "scope_time_share.attn"} <= names
     # its reader counts one head size; its reader reads the process's peak
     # after the reference ran
@@ -127,7 +129,15 @@ def test_the_configuration_keeps_the_catalog_rows_widths():
     assert cfg["num_hidden_layers"] - cfg["first_k_dense_replace"] >= 4
     assert cfg["vocab_size"] * 8 >= CATALOG["vocab_size"]
     assert "8 chips share each layer" in cfg["deployment"]
-    assert {"bias_update_speed", "initializer_range"} <= set(cfg["assumed"])
+    assert {"bias_update_speed", "initializer_range",
+            "embedding_initializer_range"} <= set(cfg["assumed"])
+    # the benchmark's statement about its weights (finding 13), and the
+    # rate of the published warm-up at the steps a window reaches
+    assert cfg["initializer_range"] == 0.02
+    assert cfg["embedding_initializer_range"] == 1.0
+    optimizer = mf.load_cell(CELL).traffic["optimizer"]
+    assert optimizer["name"] == "AdamW" and "why" in optimizer
+    assert optimizer["learning_rate"] == 1e-5
 
 
 def test_the_live_scope_family_is_still_the_five_groups():
@@ -257,6 +267,102 @@ def test_window_counters_reads_what_the_kind_gave():
     assert window_counters.read(peak, {"hbm_window_peak_gb": None}) is None
 
 
+def test_moe_held_share_is_a_metric_of_the_8k_cell_alone(manifest):
+    entry = next(m for m in manifest["per_layer"]
+                 if m["name"] == "moe_held_share")
+    assert entry["workloads"] == [CELL] and entry["unit"] == "%"
+    assert entry["moves"] == "train_tok_s_chip"
+    spec = next(m for m in mf.load_cell(CELL).per_layer
+                if m["name"] == "moe_held_share")
+    assert spec["reader"] == "window_counters"
+    assert spec["layer"] == "Model step, device"
+    assert window_counters.read(spec, {"moe_held_share": 12.5}) == 12.5
+    assert window_counters.read(spec, {}) is None
+    for w in manifest["workloads"]:
+        names = {m["name"] for m in mf.load_cell(w["name"]).per_layer}
+        assert ("moe_held_share" in names) == (w["name"] == CELL)
+
+
+# ----------------------------------------------- the weights the cell trains
+def _tiny_cell(moe_root, **config):
+    cell = mf.load_cell(TINY, moe_root)
+    cell.config.update(config)
+    return cell
+
+
+@pytest.mark.parametrize("seed", [3, 2 ** 31 + 11])
+def test_the_adapter_draws_the_embedding_at_its_own_range(moe_root, seed):
+    """`embedding_initializer_range` reaches the token embedding and no
+    other weight: each of the others is what the model's own stream drew."""
+    from paddle_tpu.models import MlaMoeForCausalLM
+
+    cell = _tiny_cell(moe_root)
+    assert cell.config["embedding_initializer_range"] == 1.0
+    built = program_mla_moe.build_train(cell, seed)
+    plain = MlaMoeForCausalLM(built["cfg"], seed=program_mla_moe._seed32(seed))
+    got = dict(built["model"].named_parameters())
+    want = dict(plain.named_parameters())
+    assert set(got) == set(want) and "model.embed_tokens" in got
+    for name, p in got.items():
+        w = np.asarray(p._value, np.float32)
+        if name == "model.embed_tokens":
+            assert w.shape == (512, 64)
+            assert np.std(w) == pytest.approx(1.0, rel=0.03), name
+            assert abs(np.mean(w)) < 0.03
+        else:
+            assert np.array_equal(w, np.asarray(want[name]._value,
+                                                np.float32)), name
+            if w.ndim > 1:
+                assert np.std(w) == pytest.approx(0.02, rel=0.1), name
+            else:
+                assert np.all(w == 1.0), name
+    # another seed, another table; the same seed, the same table
+    again = program_mla_moe.build_train(cell, seed)["model"]
+    other = program_mla_moe.build_train(cell, seed + 1)["model"]
+    table = np.asarray(got["model.embed_tokens"]._value)
+    assert np.array_equal(table, np.asarray(again.model.embed_tokens._value))
+    assert not np.array_equal(table,
+                              np.asarray(other.model.embed_tokens._value))
+
+
+@pytest.mark.parametrize("std,token_by_token", [(1.0, True), (0.02, False)])
+def test_routing_is_token_by_token_only_with_the_embeddings_range(
+        moe_root, std, token_by_token):
+    """PERF.md finding 13, pinned at step 0. The attention's widths are
+    raised so that its averaged output outweighs a 0.02 embedding as it does
+    at the published widths (0.02 x sqrt(heads x v x rank) = 14 here, 29
+    there). With the embedding at 0.02 the stream at the routers is one
+    vector common to all tokens: nearly every token of a layer picks the
+    same experts. At 1.0 the tokens choose for themselves."""
+    import jax
+
+    cell = _tiny_cell(moe_root, embedding_initializer_range=std,
+                      num_attention_heads=8, head_dim=8, v_head_dim=128,
+                      kv_lora_rank=512)
+    model = program_mla_moe.build_train(cell, 5)["model"]
+    x, y = traffic_gen.ZipfTokens(5, 512, 1.1).batch(0, 2, 64)
+    fn, args = program_mla_moe.forward_fn(model, x, y)
+    _, _, chosen = jax.jit(fn)(*args)
+    assert len(chosen) == 2
+    for picked in np.asarray(chosen):                 # [tokens, k] a layer
+        tokens, k = picked.shape
+        sets = {tuple(sorted(row)) for row in picked.tolist()}
+        fullest = np.bincount(picked.ravel(), minlength=16).max() / tokens
+        if token_by_token:
+            assert len(sets) > tokens // 3 and fullest < 0.75, (len(sets),
+                                                                fullest)
+        else:
+            assert len(sets) < tokens // 4 and fullest > 0.8, (len(sets),
+                                                               fullest)
+
+
+def test_adamw_gets_the_traffic_files_learning_rate(moe_root):
+    cell = _tiny_cell(moe_root)
+    cell.traffic["optimizer"] = mf.load_cell(CELL).traffic["optimizer"]
+    built = program_mla_moe.build_train(cell, 1)
+    assert built["step"].optimizer.get_lr() == 1e-5
+
+
 # ----------------------------------------------------------------- the kind
 def test_the_step_program_check_asks_the_block_for_no_option(monkeypatch):
     """On a TPU program_gpt's check reads `cfg.use_flash_attention`; the
@@ -277,10 +383,24 @@ def test_the_step_program_check_asks_the_block_for_no_option(monkeypatch):
     assert "2 Mosaic custom calls" in lines[0]
 
 
-def test_train_moe_kind_end_to_end_line(moe_root):
+def test_train_moe_kind_end_to_end_line(moe_root, capsys):
     result, text = _run(moe_root, trace=False, seconds=3.0)
     assert result["correct"] is True and result["failed"] == 0, text
     assert set(result["metrics"]) == {"train_tok_s_chip", "setup_s"}
+    assert list(result)[-1] == "compared"
+    compared = result["compared"]
+    assert set(compared) == {
+        "router_same_input_flip_share", "router_max_margin", "loss_abs_err",
+        "logit_max_abs_err", "grad_rel_err_worst", "assignments_off_expected",
+        "non_finite_losses", "loss_last3_over_first3", "compiles_in_window"}
+    assert compared["router_max_margin"][1] == 0.012
+    assert compared["grad_rel_err_worst"][1] == 0.2
+    assert compared["assignments_off_expected"] == [0.0, 0.0]
+    # ... and the same numbers are the run's last lines on stderr
+    last = capsys.readouterr().err.strip().splitlines()[-len(compared):]
+    assert [ln.split()[:2] for ln in last] == [["[compared]", k]
+                                               for k in compared]
+    assert last[-1] == "[compared] compiles_in_window 0 limit 0"
     assert result["attempted"] > 0
     assert "[reference] (a1) the program's router" in text
     assert "[reference] (a2)" in text and "[reference] (b)" in text
@@ -296,8 +416,11 @@ def test_train_moe_kind_traced_line(moe_root):
     cell = mf.load_cell(TINY, moe_root)
     assert set(result["metrics"]) <= {m["name"] for m in cell.per_layer}
     assert {"stall_share", "step_ms_p50", "mfu", "compile_s",
-            "moe_load_max_over_mean"} <= set(result["metrics"])
+            "moe_load_max_over_mean", "moe_held_share"} <= set(
+                result["metrics"])
     assert result["metrics"]["moe_load_max_over_mean"]["value"] >= 1.0
+    assert result["metrics"]["moe_held_share"]["unit"] == "%"
+    assert 0.0 < result["metrics"]["moe_held_share"]["value"] < 100.0
     # what only a device trace gives is left out, never made up
     for name in ("flash_mla_roofline", "moe_experts_roofline",
                  "moe_experts_time_share", "unnamed_time_share",
