@@ -9,7 +9,8 @@ Default (ISSUE 20): the compiled hot path — paddle_tpu.models.WideDeep
 under PsTrainStep (ONE jitted program per step, pre-gathered rows in /
 row-grads out) driven by PsPipeline double buffering over a bus-sharded
 PS, so step k computes while step k+1's unique keys prefetch and step
-k-1's merged grads push. tools/ps_bench.py measures the gap.
+k-1's merged grads push. What that gains on a chip is not measured: no
+cell of BENCHMARK.json runs the PS path yet.
 
     python examples/wide_deep_ps.py [--eager]
 """

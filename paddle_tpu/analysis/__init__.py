@@ -70,8 +70,8 @@ FLAGS_host_sync_check to record blocking syncs inside train-step spans.
 Gate: ``tools/check_static.py --baseline tools/static_baseline.json``
 runs everything over paddle_tpu/ in tier-1; new findings exit 1, stale
 baseline entries OR stale inline waivers exit 2. ``--changed-only`` /
-``--sarif`` / the parsed-AST cache serve CI; ``tools/bench_gate.py
---static-budget`` pins the full-run wall time.
+``--sarif`` / the parsed-AST cache serve CI (a warm cache parses no
+file: tests/test_static_analysis.py).
 """
 from __future__ import annotations
 

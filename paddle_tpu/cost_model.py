@@ -31,9 +31,9 @@ COLLECTIVE_LATENCY_S = 5e-6
 
 # THE peaks table: {substring of the PJRT device_kind: (peak bf16 FLOP/s,
 # HBM bytes/s)}, per chip, from the vendor's published figures (v5e: Google
-# Cloud "TPU v5e" — 197 TFLOP/s bf16, 819 GB/s). bench.py's utilization,
-# jit/aot.py's roofline estimate, the pipeline pricer and the autotuner's
-# noise floor all read it. A device that is not here has no published peak:
+# Cloud "TPU v5e" — 197 TFLOP/s bf16, 819 GB/s). jit/aot.py's roofline
+# estimate, the pipeline pricer and the autotuner's noise floor read it
+# (the benchmark keeps its own copy in benchmark/peaks.json). A device that is not here has no published peak:
 # device_peaks() raises, it does not assume one.
 DEVICE_PEAKS = {
     "v5 lite": (1.97e14, 8.19e11),   # v5e
@@ -376,9 +376,9 @@ def ps_pipeline_cost(*, batch: int, uniq_keys: int, dim: int,
 
     depth 1 serializes pull -> step -> push; depth >= 2 hides wire time
     behind compute, so the steady-state step is max(step, pull, push) and
-    the *exposed* remainders are what bench_gate watches. The model only
-    ranks codec/depth/capacity choices — absolute times come from
-    tools/ps_bench.py measurement."""
+    the *exposed* remainders are what `PsPipeline.run` reports. The model
+    only ranks codec/depth/capacity choices; absolute times are not
+    measured (no cell runs the PS path)."""
     if codec not in _PS_WIRE_ELEM_BYTES:
         raise ValueError(f"unknown PS wire codec {codec!r}; one of "
                          f"{sorted(_PS_WIRE_ELEM_BYTES)}")

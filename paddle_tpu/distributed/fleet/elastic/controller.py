@@ -40,8 +40,8 @@ The optimization target is goodput — useful tokens/s × availability —
 accounted by :class:`GoodputLedger`: every chip-second of the fleet is
 attributed to exactly one account (useful train tokens, useful serve
 tokens, save/reshard/compile/drain overhead, recompute, or idle), so the
-policy's value over the reactive baseline is a single gated number
-(tools/chaos_train.py fleet phase, tools/bench_gate.py --fleet-artifact).
+policy's value over the reactive baseline is a single number
+(tools/chaos_train.py fleet phase, on a virtual clock).
 """
 from __future__ import annotations
 

@@ -760,20 +760,6 @@ class GradCommunicator:
             n_coll += 1
         return reduced, new_res, wire_bytes, n_coll
 
-    def describe(self) -> list:
-        """Human/JSON-friendly bucket layout of the last sync (one row per
-        bucket) — what tools/grad_comm_bench.py prints so bucket-assignment
-        regressions are visible in the artifact, not just the counts."""
-        if not self._buckets:
-            return []
-        return [{
-            "bucket": b.index,
-            "dtype": str(b.dtype),
-            "n_params": len(b.param_indices),
-            "numel": b.size,
-            "mb": round(b.nbytes / _MB, 4),
-        } for b in self._buckets]
-
     def __repr__(self):
         return (f"GradCommunicator({self.config!r}, "
                 f"buckets={len(self._buckets or [])})")
@@ -830,8 +816,9 @@ def comm_plan(params, config: Optional[GradCommConfig] = None,
 
     Pure host-side accounting (no collectives run): how many collectives per
     step and how many bytes cross the wire under `config`, next to the
-    un-bucketed per-parameter baseline. Used by bench.py's JSON line and
-    tools/grad_comm_bench.py.
+    un-bucketed per-parameter baseline. The communicators' own counters
+    are held to it (tests/test_grad_comm.py,
+    tests/test_quantized_collectives.py).
     """
     config = config or GradCommConfig()
     params = [p for p in params if not p.stop_gradient]

@@ -46,7 +46,6 @@ the last flush.
 from __future__ import annotations
 
 import threading
-import time
 from collections import deque
 from typing import Dict, List, Optional
 
@@ -62,7 +61,7 @@ from .grad_comm import GradBucket, GradCommConfig, GradCommunicator
 
 __all__ = [
     "BucketFuture", "CollectiveLane", "GatherFuture",
-    "OverlappedGradCommunicator", "communicator_for", "overlap_report",
+    "OverlappedGradCommunicator", "communicator_for",
 ]
 
 _m_overlap_eff = _get_registry().gauge(
@@ -504,76 +503,3 @@ class OverlappedGradCommunicator(GradCommunicator):
         self.stats["path"] = path
         self._record_metrics(buckets, path=path)
         return futures
-
-
-# ---------------------------------------------------------------------------
-# measurement helper (tools/overlap_bench.py + bench.py's gpt JSON)
-# ---------------------------------------------------------------------------
-
-def _fake_params(shapes_dtypes, seed=0):
-    from ..framework.tensor import Tensor
-
-    rs = np.random.RandomState(seed)
-    params = []
-    for i, (shape, dt) in enumerate(shapes_dtypes):
-        p = Tensor(np.zeros(shape, dt))
-        p.stop_gradient = False
-        p.name = f"p{i}"
-        # scale BEFORE the cast: bf16 * python float promotes to float32,
-        # and the communicator refuses a grad dtype != param dtype
-        p.grad = Tensor((rs.standard_normal(shape) * 1e-2).astype(dt))
-        params.append(p)
-    return params
-
-
-def overlap_report(params, config: Optional[GradCommConfig] = None,
-                   world: int = 2, compute_s: float = 0.02,
-                   seed: int = 0) -> dict:
-    """Serial vs overlapped exposed-comm measurement for one model's
-    gradient sync (host emulation — the same caveat as
-    tools/grad_comm_bench.py: wall times are host encode/concat costs, not
-    ICI transfer). `params` provides shapes/dtypes only; grads are
-    synthesized on detached fakes, so live models are never mutated.
-    `compute_s` is the emulated backward duration the overlapped launches
-    get to hide under, spread across the per-bucket ready events."""
-    config = config or GradCommConfig()
-    shapes_dtypes = [(tuple(p._value.shape), np.dtype(p._value.dtype))
-                     for p in params if not p.stop_gradient]
-
-    # ---- serial: the whole sync is exposed
-    fakes = _fake_params(shapes_dtypes, seed=seed)
-    serial = GradCommunicator(GradCommConfig(
-        config.codec, config.comm_buffer_size, config.last_comm_buffer_size,
-        config.error_feedback))
-    serial.sync(fakes, world=world)        # warm caches/compiles
-    fakes = _fake_params(shapes_dtypes, seed=seed)
-    t0 = time.perf_counter()
-    serial.sync(fakes, world=world)
-    serial_exposed_s = time.perf_counter() - t0
-
-    # ---- overlapped: emulate backward producing grads in reverse order
-    fakes = _fake_params(shapes_dtypes, seed=seed)
-    comm = OverlappedGradCommunicator(GradCommConfig(
-        config.codec, config.comm_buffer_size, config.last_comm_buffer_size,
-        config.error_feedback, overlap=True))
-    comm.prepare(fakes, world=world)
-    per_param = compute_s / max(1, len(fakes))
-    for p in reversed(fakes):              # backward produces grads in
-        time.sleep(per_param)              # reverse traversal order
-        comm._on_grad_ready(p)
-    t0 = time.perf_counter()
-    comm.flush()
-    flush_wait_s = time.perf_counter() - t0
-    return {
-        "codec": config.codec,
-        "world": int(world),
-        "n_buckets": comm.stats["n_buckets"],
-        "serial_exposed_comm_ms": round(serial_exposed_s * 1e3, 3),
-        "overlapped_exposed_comm_ms": round(
-            comm.stats["exposed_comm_s"] * 1e3, 3),
-        "overlapped_flush_wait_ms": round(flush_wait_s * 1e3, 3),
-        "hidden_comm_ms": round(comm.stats["hidden_comm_s"] * 1e3, 3),
-        "overlap_efficiency": round(comm.stats["overlap_efficiency"], 4),
-        "buckets_launched_early": comm.stats["buckets_launched_early"],
-        "emulated_backward_ms": round(compute_s * 1e3, 3),
-    }

@@ -309,8 +309,8 @@ def pipeline_1f1b(
             bwd-only drain — instead of paying both phases on all
             M+2P-1 ticks. That cuts schedule cost from 4(M+2P-1) to
             4(M+P-1)-ish work units, at or below GPipe fill-drain's,
-            while keeping the O(P) stash (see tools/pipeline_throughput.py
-            for the measured accounting)."""
+            while keeping the O(P) stash (the test_pipeline_throughput
+            tests count the units off the traced program)."""
             fwd_m = t - stage
             bwd_m = t - (2 * P_deg - 1 - stage)
             fwd_on = (fwd_m >= 0) & (fwd_m < M)

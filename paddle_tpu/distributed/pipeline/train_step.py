@@ -25,8 +25,7 @@ rest of the training stack. This class is that composition:
 
 Bubble accounting: the segmented schedule runs 4M + 4P - 4 stage-work
 units per step against 4M useful ones — bubble = (P-1)/(M+P-1), exported
-as the ``pipeline_bubble_pct`` gauge and by :meth:`report` (bench.py's
-gpt JSON carries it; tools/bench_gate.py gates it).
+as the ``pipeline_bubble_pct`` gauge and by :meth:`report`.
 """
 from __future__ import annotations
 
@@ -347,9 +346,9 @@ class PipelineTrainStep(TrainStep):
         return super().__call__(inputs, labels)
 
     def report(self) -> dict:
-        """The pipeline account bench.py's gpt JSON carries: analytic
-        bubble %, schedule geometry, the planner verdict, and the
-        grad_comm wire stats of the newest step."""
+        """The pipeline's account of itself: analytic bubble %, schedule
+        geometry, the planner verdict, and the grad_comm wire stats of the
+        newest step."""
         mesh = self._pipe_mesh
         M = self._microbatches()
         P_deg = int(mesh.shape["pipe"])

@@ -2,11 +2,10 @@
 embedding pipeline (ISSUE 20).
 
 The eager Wide&Deep path (`distributed_lookup_table` per step) dispatches
-dozens of host ops and one PS round trip per mini-batch — measured ~3k
-examples/s against a compiled-step roofline of ~3.3M for the identical
-config (`artifacts/widedeep_aot_probe.json`). This module closes that gap
-with the heter-PS recipe the reference fleet ran (dense on accelerator,
-sparse on host), rebuilt on this repo's primitives:
+dozens of host ops and one PS round trip per mini-batch. This module
+replaces it with the heter-PS recipe the reference fleet ran (dense on
+accelerator, sparse on host), rebuilt on this repo's primitives; what it
+gains on a chip is not measured (no cell runs it: ROADMAP B6):
 
 * **PsTrainStep** — the dense hot loop as ONE jitted XLA program (the
   `jit.TrainStep` seam: FunctionalModule + optimizer.apply_gradients_tree
@@ -22,7 +21,7 @@ sparse on host), rebuilt on this repo's primitives:
   through a `HeterCache`) and a push worker commits step *k−1*'s row
   grads; `FLAGS_ps_pipeline_depth` bounds the in-flight window (depth 1 =
   bit-identical serial reference). Exposed pull/push wait — the part the
-  pipeline failed to hide — is measured per step and gated by bench_gate.
+  pipeline failed to hide — is measured per step (`PsPipeline.run`).
 
 * **BusShardedClient / PsShardService** — embedding tables sharded across
   hosts by the splitmix64 key-hash, served by request/reply actors on the

@@ -37,7 +37,7 @@ Entry points:
   transform, and commit the world-M checkpoint back at the same step
   (manifest-gated; the old-geometry checkpoint is replaced atomically).
   Counted on ``reshard_total{from_world,to_world}`` and timed into the
-  ``reshard_ms`` gauge (gated by tools/bench_gate.py).
+  ``reshard_ms`` gauge.
 - `CheckpointManager.load_sharded(..., allow_reshard=True)` and
   `ElasticController`'s scale-restart path call in here so a drifted
   geometry triggers the transform instead of refusing the resume.
@@ -61,7 +61,7 @@ from ...observability.metrics import get_registry as _get_registry
 __all__ = [
     "chunk_of", "rechunk_flat", "assemble_full_buckets",
     "reshard_zero3_states", "reshard_slot_states", "reshard_residual_maps",
-    "reshard_payloads", "reshard_checkpoint", "reshard_report",
+    "reshard_payloads", "reshard_checkpoint",
 ]
 
 # elastic-resharding telemetry: how often geometry-drifted resumes were
@@ -388,48 +388,3 @@ def reshard_checkpoint(manager, step: int, new_world: int, metadata=None):
         from_world=from_world, to_world=new_world, ms=round(ms, 3),
         shard_files=len(new_payloads))
     return manager.validate(step)
-
-
-# ---------------------------------------------------------------------------
-# measurement helper (bench.py + tools/bench_gate.py's reshard_ms gate)
-# ---------------------------------------------------------------------------
-
-def reshard_report(params, config=None, old_world: int = 4,
-                   new_world: int = 2, seed: int = 0) -> dict:
-    """Time the N→M zero3 shard transform on detached fakes of `params`'
-    shapes (host cost only — the transform IS host-side by design) and
-    verify bit-identity against the gather→rewrap reference in passing."""
-    from ..grad_comm import GradCommConfig, GradCommunicator
-    from .stage3 import Stage3ParamShards, _fake_params
-
-    config = config or GradCommConfig()
-    shapes_dtypes = [(tuple(p._value.shape), np.dtype(p._value.dtype))
-                     for p in params if not p.stop_gradient]
-    fakes = _fake_params(shapes_dtypes, seed=seed)
-    want = [np.asarray(p._value).copy() for p in fakes]
-    store = Stage3ParamShards(fakes, GradCommunicator(config), rank=0,
-                              world=old_world)
-    store.shard_()
-    state = store.state_dict()
-    t0 = time.perf_counter()
-    new_states = reshard_zero3_states([state], new_world)
-    ms = (time.perf_counter() - t0) * 1e3
-    # gather→rewrap reference: the transformed shards must reassemble to
-    # the original full parameters bit for bit
-    full = assemble_full_buckets(new_states)
-    ok = True
-    for b in store.buckets:
-        flat = full[b.index]
-        for pi, o, n, shape in zip(b.param_indices, b.offsets, b.numels,
-                                   b.shapes):
-            ok = ok and np.array_equal(
-                flat[o:o + n].reshape(shape).astype(want[pi].dtype),
-                want[pi])
-    _m_reshard_ms.set(round(ms, 3))
-    return {
-        "from_world": int(old_world), "to_world": int(new_world),
-        "n_buckets": len(store.buckets),
-        "param_bytes_full": int(store.stats["param_bytes_full"]),
-        "reshard_ms": round(ms, 3),
-        "bit_identical": bool(ok),
-    }

@@ -75,7 +75,7 @@ from ...observability.flight_recorder import get_flight_recorder
 from ...observability.metrics import get_registry as _get_registry
 from ...profiler import now_ns
 
-__all__ = ["FreedParamValue", "Stage3ParamShards", "zero3_gather_report"]
+__all__ = ["FreedParamValue", "Stage3ParamShards"]
 
 SHARDED, INFLIGHT, GATHERED = "sharded", "inflight", "gathered"
 
@@ -729,88 +729,3 @@ class Stage3ParamShards:
         return (f"Stage3ParamShards(rank={self.rank}/{self.world}, "
                 f"buckets={len(self.buckets)}, sharded={self.sharded}, "
                 f"resident={len(self.resident_buckets())})")
-
-
-# ---------------------------------------------------------------------------
-# measurement helper (tools/overlap_bench.py zero3 section + bench.py)
-# ---------------------------------------------------------------------------
-
-def _fake_params(shapes_dtypes, seed=0):
-    rs = np.random.RandomState(seed)
-    params = []
-    for i, (shape, dt) in enumerate(shapes_dtypes):
-        p = Tensor(rs.standard_normal(shape).astype(dt))
-        p.stop_gradient = False
-        p.name = f"p{i}"
-        params.append(p)
-    return params
-
-
-def zero3_gather_report(params, config: Optional[GradCommConfig] = None,
-                        world: int = 2, compute_s: float = 0.04,
-                        seed: int = 0) -> dict:
-    """Prefetched vs synchronous exposed-gather measurement for one
-    model's parameters (host emulation — the same caveat as
-    overlap_report: wall times are host assembly costs, not ICI transfer;
-    the artifact records the STRUCTURE of the win). `params` provides
-    shapes/dtypes only; detached fakes are sharded, so live models are
-    never touched. `compute_s` is the emulated forward window the
-    prefetches get to hide under, spread across the per-bucket steps."""
-    config = config or GradCommConfig()
-    shapes_dtypes = [(tuple(p._value.shape), np.dtype(p._value.dtype))
-                     for p in params if not p.stop_gradient]
-
-    # ---- synchronous: every gather fully exposed, one after another
-    fakes = _fake_params(shapes_dtypes, seed=seed)
-    store = Stage3ParamShards(fakes, GradCommunicator(config), rank=0,
-                              world=world)
-    store.shard_()
-    per_bucket = []
-    store.reset_exposed()
-    for b in store.buckets:
-        t0 = time.perf_counter()
-        try:
-            store.ensure_gathered(b.index)
-            per_bucket.append({"bucket": b.index, "nbytes": int(b.nbytes),
-                               "sync_ms": round(
-                                   (time.perf_counter() - t0) * 1e3, 3)})
-        finally:
-            store.free_bucket(b.index)
-    sync_exposed_ms = store.exposed_gather_s * 1e3
-    bytes_per_rank = store.param_bytes_per_rank()
-    param_bytes_full = int(store.stats["param_bytes_full"])
-    n_buckets = len(store.buckets)
-
-    # ---- prefetched: bucket k+1's gather launches before bucket k's
-    # emulated compute window; only the first gather (and any prefetch
-    # that outlives its window) is exposed
-    fakes = _fake_params(shapes_dtypes, seed=seed)
-    store2 = Stage3ParamShards(fakes, GradCommunicator(GradCommConfig(
-        config.codec, config.comm_buffer_size,
-        config.last_comm_buffer_size)), rank=0, world=world)
-    store2.shard_()
-    store2.reset_exposed()
-    per_layer = compute_s / max(1, n_buckets)
-    for i, b in enumerate(store2.buckets):
-        try:
-            store2.ensure_gathered(b.index)   # first: sync; later: waits
-            if i + 1 < n_buckets:
-                store2.prefetch_bucket(store2.buckets[i + 1].index)
-            time.sleep(per_layer)             # the layer's compute window
-        finally:
-            store2.free_bucket(b.index)       # free after use
-        for row in per_bucket:
-            if row["bucket"] == b.index:
-                row["prefetched"] = i > 0
-    prefetch_exposed_ms = store2.exposed_gather_s * 1e3
-
-    return {
-        "world": int(world),
-        "n_buckets": n_buckets,
-        "param_bytes_full": param_bytes_full,
-        "zero3_param_bytes_per_rank": int(bytes_per_rank),
-        "sync_exposed_gather_ms": round(sync_exposed_ms, 3),
-        "prefetch_exposed_gather_ms": round(prefetch_exposed_ms, 3),
-        "emulated_forward_ms": round(compute_s * 1e3, 3),
-        "per_bucket": per_bucket,
-    }

@@ -21,7 +21,7 @@ def _devices_of(kind: str):
     An accelerator place on a machine without one (CPU-only CI, reference
     scripts that say set_device("gpu")) still resolves, to the default
     backend — with one loud warning per kind. Code that must be on a chip
-    (chip_smoke.py, bench.py) checks the arrays' devices, never this."""
+    (chip_smoke.py, benchmark/) checks the arrays' devices, never this."""
     try:
         return tuple(jax.devices(kind))
     except RuntimeError:   # "Unknown backend tpu. Available backends ..."

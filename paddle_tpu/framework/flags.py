@@ -146,7 +146,7 @@ _FLAGS: Dict[str, Any] = {
     # requeue, re-admission, retire) record spans into the bounded trace
     # store + the flight-recorder ring; latency/TTFT histogram
     # observations carry the trace id as an exemplar. Off: zero spans,
-    # zero exemplars (the serve_bench tracing-overhead phase times both).
+    # zero exemplars.
     "FLAGS_serving_tracing": True,
     # bounded per-request trace store: max retained traces (oldest
     # evicted) and max spans kept per trace (overflow counted, not kept)

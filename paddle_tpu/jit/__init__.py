@@ -1113,9 +1113,9 @@ class TrainStep:
         ``compiled.memory_analysis()`` (argument/temp/output/alias bytes +
         the derived ``peak_hbm_bytes``). When `record`, the result lands in
         observability.memory's compiled-path registry keyed by this trace-
-        cache entry (the ``compiled_peak_hbm_bytes{entry=...}`` gauge) —
-        bench.py's ``peak_hbm_bytes_measured`` reads it from here. Returns
-        None before the first call or when the backend doesn't report."""
+        cache entry (the ``compiled_peak_hbm_bytes{entry=...}`` gauge).
+        Returns None before the first call or when the backend doesn't
+        report."""
         if self._last_ckey is None or self._last_ckey not in self._cache:
             return None
         try:

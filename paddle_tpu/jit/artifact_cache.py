@@ -50,8 +50,8 @@ _m_events = _get_registry().counter(
 
 def use_compile_cache() -> str:
     """Give this process a persistent XLA compilation cache and return its
-    directory. Entry points (chip_smoke.py, bench.py, examples, the chip
-    tools) call it before their first compile.
+    directory. Entry points (chip_smoke.py, benchmark/run.py, examples,
+    the chip tools) call it before their first compile.
 
     ``JAX_COMPILATION_CACHE_DIR`` set: jax already honours it, so no
     directory is set in code — whoever placed the cache keeps control of

@@ -271,7 +271,7 @@ def get_telemetry_server() -> Optional[TelemetryServer]:
 
 
 # ---------------------------------------------------------------------------
-# strict text-format parser (tests + bench_gate; stdlib only)
+# strict text-format parser (tests; stdlib only)
 # ---------------------------------------------------------------------------
 
 _NAME_RE = re.compile(r"[a-zA-Z_:][a-zA-Z0-9_:]*")

@@ -17,8 +17,8 @@ existed only inside one-off AOT probes. This module makes it a metric:
   output - aliased = the compiler's peak for one invocation) and
   ``record_compiled(entry, ...)`` keys it by trace-cache entry (the
   ``compiled_peak_hbm_bytes{entry=...}`` gauge), so every cached program's
-  footprint is inspectable. ``jit.TrainStep.memory_analysis()`` and
-  bench.py's ``peak_hbm_bytes_measured`` ride this.
+  footprint is inspectable. ``jit.TrainStep.memory_analysis()`` rides
+  this.
 - **rooflines** — ``load_rooflines()`` reads the recorded AOT estimates
   (artifacts/baseline_aot_estimates.json + the bench gpt estimate) and
   ``roofline_compare()`` reports measured/estimate ratios, the

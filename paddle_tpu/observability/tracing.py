@@ -36,8 +36,8 @@ breakdown (forward/backward/optimizer/comm/checkpoint/data) as spans, so
 train-step phases live on the same timeline store as serve requests.
 
 Everything is gated by ``FLAGS_serving_tracing``; when off, no contexts
-are minted and every helper no-ops on ctx=None (serve_bench times the
-on/off delta and bench_gate holds it inside the 20% band).
+are minted and every helper no-ops on ctx=None. What tracing costs a
+serving step on the chip is not measured (no serving cell yet).
 """
 from __future__ import annotations
 

@@ -114,9 +114,9 @@ def _tuned_blocks(shape, dtype, causal: bool, want, d_v: int = None):
 
 def flash_block_choice(shape, dtype="float32", causal=True,
                        block_size=None) -> dict:
-    """What dispatch would run for this [b, s, n, d] call — the record
-    bench.py carries so the trajectory shows WHICH tiles produced a
-    throughput number: {"block_q", "block_k", "source"}."""
+    """What dispatch would run for this [b, s, n, d] call, so that a
+    reading can say WHICH tiles produced it:
+    {"block_q", "block_k", "source"}."""
     bq, bk, source = _tuned_blocks(tuple(shape), dtype, bool(causal),
                                    block_size)
     return {"block_q": int(bq), "block_k": int(bk), "source": source}

@@ -33,10 +33,10 @@ Observability: ``serve_requests_total{outcome=}``, ``serve_queue_depth``,
 ``serve_spec_accepted_per_step{replica=}``, plus a ``/serving`` section
 on the telemetry exposition endpoint while a ``ReplicaSet`` is running.
 
-Bench: ``tools/serve_bench.py`` (open-loop QPS sweep vs the sequential
-single-request baseline + KV codec bytes + a replica-kill chaos phase +
-a Zipfian prefix-cache mix + a speculative-decode scenario)
--> ``artifacts/serve_bench.json``, gated by ``tools/bench_gate.py``.
+Speed: not measured. No cell of ``BENCHMARK.json`` serves yet (the KV
+pool lives on the host: ROADMAP B3); the counts this runtime owes (bytes a
+quantized block holds, zero lost requests under eviction, prefix hits,
+lossless speculation) are held by ``tests/test_serving*.py``.
 """
 from .engine import ReplicaBootBudgetExceeded, ServingEngine
 from .kv_cache import BlockTable, KVBlockPool, KVCacheOOM, KV_CODECS

@@ -2,7 +2,8 @@
 
 Mirrors the reference's strategy of testing distributed logic with local
 processes + gloo (SURVEY.md §4): here a single process with 8 XLA host devices
-stands in for an 8-chip TPU slice. chip_smoke.py / bench.py use the real chip.
+stands in for an 8-chip TPU slice. chip_smoke.py and benchmark/run.py use the
+real chip.
 """
 import os
 import threading

@@ -1,13 +1,11 @@
 """Shared toy pipeline model for the 1F1B tests and throughput bench.
 
 One definition of the stacked-tanh stage model (embed -> P stages of
-KPER scanned layers -> linear head + MSE), its pipe-sharded PartitionSpecs,
-and a contention-robust bench loop — used by tests/test_pipeline_1f1b.py,
-tests/test_pipeline_throughput.py, and tools/pipeline_throughput.py so the
-three can't drift apart.
+KPER scanned layers -> linear head + MSE), its pipe-sharded PartitionSpecs
+and the GPipe fill-drain baseline — used by the test_pipeline_1f1b,
+test_pipeline_throughput and test_multiproc_hybrid tests so the three
+can't drift apart.
 """
-import time
-
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
@@ -47,8 +45,7 @@ def loss_fn(p, y, lbl):
 def gpipe_value_and_grad(mesh, M, p, x, lbl, remat):
     """GPipe fill-drain train step: AD through pipeline_spmd, optionally
     with jax.checkpoint on the stage body (recompute parity with 1F1B).
-    The comparison baseline used by both the throughput test and the
-    bench tool."""
+    The comparison baseline of the throughput test."""
     from paddle_tpu.distributed.pipeline import pipeline_spmd
 
     body = jax.checkpoint(stage_fn) if remat else stage_fn
@@ -62,27 +59,3 @@ def gpipe_value_and_grad(mesh, M, p, x, lbl, remat):
         return loss_fn(p, y, lbl)
 
     return jax.value_and_grad(train_loss)(p)
-
-
-def bench_min(fn, args, steps):
-    """min-of-N per-step wall time: the minimum is robust to contention
-    bursts on a shared host (any single clean window gives the true
-    cost), unlike a mean over few iterations."""
-    return bench_min_interleaved([fn], args, steps)[0]
-
-
-def bench_min_interleaved(fns, args, steps):
-    """min-of-N for SEVERAL step fns, measured round-robin so a
-    multi-second contention burst (another process compiling, CI noisy
-    neighbor) degrades every config's samples instead of landing entirely
-    on whichever config happened to be mid-measurement — ratios between
-    the returned minima stay meaningful under load."""
-    for fn in fns:
-        jax.block_until_ready(fn(*args))  # compile + warm each
-    best = [float("inf")] * len(fns)
-    for _ in range(steps):
-        for i, fn in enumerate(fns):
-            t0 = time.perf_counter()
-            jax.block_until_ready(fn(*args))
-            best[i] = min(best[i], time.perf_counter() - t0)
-    return best

@@ -24,23 +24,31 @@ def test_three_stage_pipeline_order_and_results():
 
 
 def test_stages_overlap_in_time():
-    """Real concurrency: with 2 slow stages, total < serial sum."""
-    def slow(tag):
-        def fn(x):
-            time.sleep(0.05)
-            return x
-        return fn
+    """Real concurrency, shown by a rendezvous and not by a clock: stage b
+    holds item 0 until stage a has taken up item 1. An executor that ran
+    one item through both stages before admitting the next would leave b
+    waiting out its timeout."""
+    a_has_item_1 = threading.Event()
+    overlapped = []
+
+    def a(x):
+        if x == 1:
+            a_has_item_1.set()
+        return x
+
+    def b(x):
+        if x == 0:
+            overlapped.append(a_has_item_1.wait(30))
+        return x
 
     exe = FleetExecutor([
-        TaskNode(0, fn=slow("a"), downstream=[1]),
-        TaskNode(1, fn=slow("b")),
+        TaskNode(0, fn=a, downstream=[1]),
+        TaskNode(1, fn=b),
     ])
-    t0 = time.perf_counter()
-    exe.run(list(range(8)))
-    dt = time.perf_counter() - t0
+    outs = exe.run(list(range(8)))
     exe.shutdown()
-    # serial = 8 * 2 * 0.05 = 0.8s; pipelined ≈ 0.05 * 9 = 0.45
-    assert dt < 0.7, dt
+    assert sorted(outs) == list(range(8))
+    assert overlapped == [True]
 
 
 def test_fanout_graph():

@@ -4,11 +4,9 @@ Covers ISSUE 1's contract: bit-exact parity of bucketed-bf16 vs the seed's
 per-param sync on a 2-rank mesh, the int8 codec round-trip bound, the
 error-feedback convergence smoke, deterministic bucket assignment, and the
 in-suite regression guard that bucketing keeps the collective count
-O(buckets) instead of O(#params) (style: tests/test_eager_dispatch.py).
+O(buckets) instead of O(#params).
 """
-import json
 import math
-import os
 
 import jax
 import jax.numpy as jnp
@@ -26,7 +24,6 @@ import paddle_tpu.distributed.mesh as mesh_mod
 from paddle_tpu.distributed import fleet, grad_comm
 from paddle_tpu.framework.tensor import Tensor
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 rng = np.random.RandomState(0)
 
 
@@ -368,26 +365,36 @@ def test_comm_cost_terms():
         comm_cost(gb, world=8, codec="fp8")
 
 
-def test_grad_comm_bench_tool_and_artifact():
-    """tools/grad_comm_bench.py measures what it plans, and the committed
-    artifact records the collective-count win (style:
-    test_eager_dispatch_artifact_is_current)."""
-    import sys
+@pytest.mark.parametrize("codec", grad_comm.CODECS)
+def test_sync_executes_what_comm_plan_counts(codec, monkeypatch):
+    """On the test GPT config the eager sync issues exactly the
+    collectives and wire bytes `comm_plan` counts for the codec, fewer
+    collectives than parameters, and the codec's share of the fp32 wire."""
+    from paddle_tpu.models import GPTForCausalLM, gpt_presets
 
-    sys.path.insert(0, os.path.join(REPO, "tools"))
-    import grad_comm_bench
-
-    rec = grad_comm_bench.measure(steps=1)
-    assert rec["per_param_collectives"] == rec["n_params"]
-    for codec, row in rec["codecs"].items():
-        assert row["collectives_per_step"] == row["planned_collectives"]
-        assert row["comm_bytes_per_step"] == row["planned_comm_bytes"]
-        assert row["collectives_per_step"] < rec["n_params"]
-    assert (rec["codecs"]["int8"]["comm_bytes_per_step"]
-            < rec["codecs"]["bf16"]["comm_bytes_per_step"]
-            < rec["codecs"]["fp32"]["comm_bytes_per_step"])
-
-    d = json.load(open(os.path.join(REPO, "artifacts",
-                                    "grad_comm_bench.json")))
-    assert d["model"] == "gpt-test" and d["codecs"]["fp32"][
-        "collectives_per_step"] < d["per_param_collectives"]
+    model = GPTForCausalLM(gpt_presets("gpt-test"), seed=0)
+    n_params = _set_grads(model)
+    params = [p for p in model.parameters() if not p.stop_gradient]
+    calls = []
+    monkeypatch.setattr(coll, "all_reduce",
+                        lambda t, op=None, **kw: calls.append(1) or t)
+    cfg = grad_comm.GradCommConfig(codec=codec)
+    comm = grad_comm.GradCommunicator(cfg)
+    comm.sync(params, world=2)
+    plan = grad_comm.comm_plan(params, cfg)
+    assert len(calls) == comm.stats["collectives"] \
+        == plan["collectives_per_step"]
+    assert comm.stats["comm_bytes"] == plan["comm_bytes_per_step"]
+    assert plan["per_param_collectives"] == n_params
+    assert len(calls) < n_params
+    # wire bytes against fp32, from the counts alone: a byte or two an
+    # element plus the scales (4 B a bucket, or 4 B a block of 1024)
+    fp32 = grad_comm.comm_plan(params, grad_comm.GradCommConfig("fp32"))
+    numel, n_buckets = plan["total_grad_numel"], plan["n_buckets"]
+    assert fp32["comm_bytes_per_step"] == 4 * numel
+    scales = sum(grad_comm.scale_bytes(b.size, cfg.block_size)
+                 for b in comm.buckets_for(params))
+    assert plan["comm_bytes_per_step"] == {
+        "fp32": 4 * numel, "bf16": 2 * numel,
+        "int8": numel + 4 * n_buckets,
+        "int8_block": numel + scales, "fp8_block": numel + scales}[codec]

@@ -1059,91 +1059,3 @@ def test_fleet_strategy_telemetry_knobs():
         # over data=8 — the order-dependent TestRobustCheckpointCallback
         # tier-1 failures (PR 14's note, fixed + pinned in PR 15)
         mesh_mod.set_mesh(old_mesh)
-
-
-# -------------------------------------------------------------- bench gate
-class TestBenchGate:
-    """tools/bench_gate.py (ISSUE 6 satellite): the trajectory regression
-    gate — offline smoke passes on the recorded trajectory, a
-    synthetically degraded record fails, format drift exits 2."""
-
-    @pytest.fixture()
-    def bench_gate(self):
-        import importlib.util
-
-        spec = importlib.util.spec_from_file_location(
-            "bench_gate", os.path.join(REPO, "tools", "bench_gate.py"))
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod
-
-    @pytest.fixture()
-    def trajectory(self, tmp_path):
-        """Two same-class rounds, the newer inside the band of the older."""
-        rounds = tmp_path / "trajectory"
-        rounds.mkdir()
-        rec = {"value": 1000.0, "device_kind": "TPU v5 lite",
-               "peak_hbm_bytes_measured": 1000}
-        for n, value in ((1, 1000.0), (2, 990.0)):
-            (rounds / f"BENCH_r{n:02d}.json").write_text(json.dumps(
-                {"n": n, "rc": 0, "parsed": dict(rec, value=value)}))
-        return rounds
-
-    def test_offline_passes_on_recorded_trajectory(self, bench_gate,
-                                                   trajectory):
-        assert bench_gate.main(["--root", str(trajectory),
-                                "--offline"]) == 0
-
-    def test_degraded_candidate_fails(self, bench_gate, trajectory,
-                                      tmp_path):
-        traj = bench_gate.load_trajectory(str(trajectory))
-        degraded = dict(traj[-1][1])
-        degraded["value"] = degraded["value"] * 0.5  # half the tokens/s
-        p = tmp_path / "degraded.json"
-        p.write_text(json.dumps(degraded))
-        assert bench_gate.main(["--root", str(trajectory),
-                                "--candidate", str(p)]) == 1
-
-    def test_memory_and_comm_regressions_gate(self, bench_gate, tmp_path):
-        base = {"value": 1000.0, "fallback": "cpu",
-                "exposed_comm_ms": {"serial": 9.0, "overlapped": 1.0},
-                "peak_hbm_bytes_measured": 1000}
-        rounds = tmp_path / "rounds"
-        rounds.mkdir()
-        (rounds / "BENCH_r01.json").write_text(
-            json.dumps({"n": 1, "rc": 0, "parsed": base}))
-        ok = dict(base, value=990.0)
-        p_ok = tmp_path / "ok.json"
-        p_ok.write_text(json.dumps(ok))
-        assert bench_gate.main(["--root", str(rounds),
-                                "--candidate", str(p_ok)]) == 0
-        # 2x the peak HBM (> the 20% band, lower-is-better) regresses
-        worse_mem = dict(base, peak_hbm_bytes_measured=2000)
-        p_mem = tmp_path / "mem.json"
-        p_mem.write_text(json.dumps(worse_mem))
-        assert bench_gate.main(["--root", str(rounds),
-                                "--candidate", str(p_mem)]) == 1
-        # 3x the exposed comm regresses too
-        worse_comm = dict(
-            base, exposed_comm_ms={"serial": 9.0, "overlapped": 3.0})
-        p_comm = tmp_path / "comm.json"
-        p_comm.write_text(json.dumps(worse_comm))
-        assert bench_gate.main(["--root", str(rounds),
-                                "--candidate", str(p_comm)]) == 1
-
-    def test_device_class_mismatch_and_drift_exit_2(self, bench_gate,
-                                                    tmp_path):
-        rounds = tmp_path / "rounds"
-        rounds.mkdir()
-        (rounds / "BENCH_r01.json").write_text(json.dumps(
-            {"n": 1, "rc": 0, "parsed": {"value": 1000.0,
-                                         "fallback": "cpu"}}))
-        # a TPU candidate is never judged against a CPU baseline
-        tpu = tmp_path / "tpu.json"
-        tpu.write_text(json.dumps({"value": 10.0,
-                                   "device_kind": "TPU v5 lite"}))
-        assert bench_gate.main(["--root", str(rounds),
-                                "--candidate", str(tpu)]) == 2
-        empty = tmp_path / "empty"
-        empty.mkdir()
-        assert bench_gate.main(["--root", str(empty), "--offline"]) == 2

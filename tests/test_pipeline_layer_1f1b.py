@@ -131,14 +131,15 @@ def test_batchnorm_buffers_block_1f1b(pipe_mesh):
 
 def test_switch_compile_scales_subquadratically_to_p8():
     """VERDICT r3 weak #3: the heterogeneous path compiles all P stage
-    bodies on every rank via lax.switch — bound the risk at P=8. Measured
-    (XLA-CPU): first-call trace+compile 1.6s at P=2 -> 2.5s at P=8, a
-    1.56x growth for 4x the branches; this guard allows 4x before
-    failing (a quadratic blowup would be ~16x). Per-rank programs
-    (section_worker.cc style) stay unnecessary while this holds."""
-    import time
+    bodies on every rank via lax.switch — bound the risk at P=8, by the
+    size of the program the step lowers to (operations in its StableHLO
+    text), not by how long this machine took to compile it. Read on this
+    tree: 503 operations at P=2, 671 at P=4, 1,199 at P=8, a 2.38x growth
+    for 4x the branches; this guard allows 4x before failing (a quadratic
+    blowup would be ~16x). Per-rank programs (section_worker.cc style)
+    stay unnecessary while this holds."""
 
-    def first_call_seconds(P):
+    def program_ops(P):
         prev = mesh_mod.get_mesh()
         mesh_mod.set_mesh(mesh_mod.build_mesh(
             {"pipe": P}, devices=jax.devices()[:P]))
@@ -152,18 +153,14 @@ def test_switch_compile_scales_subquadratically_to_p8():
             rs = np.random.RandomState(0)
             x = paddle.to_tensor(rs.randn(16, HID).astype(np.float32))
             y = paddle.to_tensor(rs.randn(16, HID).astype(np.float32))
-            t0 = time.perf_counter()
-            loss = float(pp.train_batch((x, y), optim))
-            assert np.isfinite(loss)
-            return time.perf_counter() - t0
+            assert np.isfinite(float(pp.train_batch((x, y), optim)))
+            step = pp._train_step
+            text = step._cache[step._last_ckey].lower(
+                *step._last_abstract).as_text()
+            assert "stablehlo.case" in text      # the lax.switch over stages
+            return sum(" = " in line for line in text.splitlines())
         finally:
             mesh_mod.set_mesh(prev)
 
-    # min-of-2: each call rebuilds the model and jit fn (full retrace),
-    # so the min discards one-off contention spikes without hiding the
-    # compile cost being bounded
-    t2 = min(first_call_seconds(2), first_call_seconds(2))
-    t8 = min(first_call_seconds(8), first_call_seconds(8))
-    # measured numbers live in artifacts/pipeline_layer_switch_compile.json
-    # (committed once, not rewritten per test run)
-    assert t8 < 4.0 * t2, (t2, t8)
+    ops2, ops8 = program_ops(2), program_ops(8)
+    assert ops2 < ops8 < 4.0 * ops2, (ops2, ops8)

@@ -357,7 +357,7 @@ class TestLiveBytesWatermark:
 
 
 def test_pipeline_metrics_exported():
-    """The step exports the gauges bench/bench_gate consume."""
+    """The step exports its bubble and watermark gauges."""
     from paddle_tpu.observability.metrics import get_registry
 
     run_pipelined({"pipe": 2}, 4, steps=1)

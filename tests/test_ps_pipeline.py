@@ -15,9 +15,8 @@ Covers the tentpole contracts end to end:
   shard host; FLAGS_ps_degraded_ok serves zeros / drops-and-counts;
 - tracing spans per step (pull_launch/pull_wait/step/push_commit);
 - FLAGS_ps_* declared; wire-byte + cache-hit counters registered;
-- tools/ps_bench.py --quick runs as the tier-1 smoke.
+- the compiled step against the eager lookup step on the same batches.
 """
-import time
 
 import numpy as np
 import pytest
@@ -319,6 +318,57 @@ class TestPipeline:
             losses.append(float(loss))
         return losses
 
+    @pytest.mark.parametrize("shards", [1, 2])
+    def test_compiled_step_matches_eager_lookup_step(self, shards):
+        """The compiled PsTrainStep under the pipeline against the eager
+        path it replaced (distributed_lookup_table per batch, dense step
+        on the tape, row gradients pushed by the lookup's backward), on
+        the same batches and SGD tables: the same losses, the same rows in
+        the table, the same dense weights."""
+        from paddle_tpu.distributed.ps import distributed_lookup_table
+
+        batches = ctr_batches(6, BATCH, SLOTS, 500, alpha=1.0, seed=0)
+        ref = LocalPs()
+        ref.create_table(0, DIM, optimizer="sgd")
+        paddle.seed(0)
+        model = WideDeep(SLOTS, DIM)
+        opt = paddle.optimizer.Adam(learning_rate=1e-3,
+                                    parameters=model.parameters())
+        eager = []
+        for ids, labels in batches:
+            rows = distributed_lookup_table(
+                paddle.to_tensor(ids.astype(np.int64)), table_id=0,
+                client=ref, lr=0.1)
+            loss = wide_deep_loss(model(rows.reshape([ids.shape[0], -1])),
+                                  paddle.to_tensor(labels))
+            loss.backward()
+            opt.step()
+            opt.clear_grad()
+            eager.append(float(loss))
+
+        client, services, bus = make_sharded_ps(shards, base_task=9550)
+        try:
+            client.create_table(0, DIM, optimizer="sgd")
+            step = _model_step()
+            pipe = PsPipeline(client, 0, step, depth=1, lr_sparse=0.1)
+            stats = pipe.run(batches)
+            pipe.close()
+            np.testing.assert_allclose(stats["losses"], eager, rtol=0,
+                                       atol=1e-6)
+            keys = np.unique(np.concatenate(
+                [b[0].reshape(-1) for b in batches]).astype(np.uint64))
+            np.testing.assert_allclose(client.pull(0, keys),
+                                       ref.pull(0, keys), rtol=0, atol=1e-6)
+            for a, b in zip(model.parameters(), step.model.parameters()):
+                np.testing.assert_allclose(np.asarray(a._value),
+                                           np.asarray(b._value),
+                                           rtol=0, atol=1e-6)
+        finally:
+            client.close()
+            for s in services:
+                s.stop()
+            bus.close()
+
     def test_depth1_bit_identical_to_serial_reference(self):
         batches = ctr_batches(6, BATCH, SLOTS, 500, alpha=1.0, seed=0)
         ref = LocalPs()
@@ -344,27 +394,61 @@ class TestPipeline:
                 s.stop()
             bus.close()
 
-    def test_depth2_converges_within_band_and_hides_pull(self):
-        batches = ctr_batches(12, BATCH, SLOTS, 500, alpha=1.0, seed=0)
-        client, services, bus = make_sharded_ps(2, base_task=9600)
+    def _traced_pass(self, name, depth, n_batches, base_task):
+        """One pipeline pass with request tracing on; returns its stats
+        and its trace document (the spans in the order they were
+        recorded)."""
+        from paddle_tpu.framework.flags import _FLAGS
+        from paddle_tpu.observability.tracing import get_tracer
+
+        batches = ctr_batches(n_batches, BATCH, SLOTS, 500, alpha=1.0,
+                              seed=0)
+        client, services, bus = make_sharded_ps(2, base_task=base_task)
+        old = _FLAGS.get("FLAGS_serving_tracing", True)
+        _FLAGS["FLAGS_serving_tracing"] = True
         try:
             client.create_table(0, DIM)
-            step = _model_step()
-            pipe = PsPipeline(client, 0, step, depth=2, lr_sparse=0.1)
+            pipe = PsPipeline(client, 0, _model_step(), depth=depth,
+                              lr_sparse=0.1, name=name)
             stats = pipe.run(batches)
             pipe.close()
-            losses = stats["losses"]
-            assert losses[-1] < losses[0]  # staleness-1 downpour trains
-            assert stats["exposed_pull_ms"] < 10 * stats["step_ms"] + 50
+            store = get_tracer().store
+            doc = next(d for d in (store.get(t["trace_id"])
+                                   for t in store.index()["traces"])
+                       if d and d["name"] == name)
+            return stats, doc
         finally:
+            _FLAGS["FLAGS_serving_tracing"] = old
             client.close()
             for s in services:
                 s.stop()
             bus.close()
 
-    def test_quantized_wire_loss_parity_and_byte_ratio(self):
-        """int8_block wire at dim 32: <= ~0.3x fp32 bytes, loss within a
-        parity band of the fp32 wire (EF residuals at work)."""
+    @pytest.mark.parametrize("depth", [1, 2])
+    def test_pull_is_launched_ahead_of_the_step_only_at_depth2(self, depth):
+        """Double buffering by the order of the pass's own spans: at depth
+        2 the pull of batch k+1 is launched before step k runs (so the
+        step hides it), at depth 1 only after step k and its push; both
+        train. No clock is read."""
+        stats, doc = self._traced_pass(f"ps_pass_depth{depth}", depth, 12,
+                                       base_task=9600)
+        losses = stats["losses"]
+        assert len(losses) == 12 and losses[-1] < losses[0]
+        order = {(sp["name"], sp["fields"]["step"]): i
+                 for i, sp in enumerate(doc["spans"])
+                 if sp["name"] in ("pull_launch", "step")}
+        assert sum(1 for n, _ in order if n == "pull_launch") == 12
+        ahead = [order[("pull_launch", k + 1)] < order[("step", k)]
+                 for k in range(11)]
+        assert ahead == [depth == 2] * 11
+
+    @pytest.mark.parametrize("codec", ["int8_block", "fp8_block"])
+    def test_quantized_wire_loss_parity_and_byte_ratio(self, codec):
+        """A one-byte blockwise wire at dim 32: <= ~0.3x fp32 bytes, loss
+        within a parity band of the fp32 wire (EF residuals at work). At
+        depth 1, where a pass is deterministic: at depth 2 a push races
+        the next pull, one codec's last loss moves by 0.03 from run to run
+        and the band would judge the threads, not the wire."""
         dim, slots, pad = 32, 8, 512
         batches = ctr_batches(8, 32, slots, 2000, alpha=1.1, seed=0)
 
@@ -379,7 +463,7 @@ class TestPipeline:
                     learning_rate=1e-3, parameters=model.parameters())
                 step = PsTrainStep(model, opt, wide_deep_loss, dim=dim,
                                    pad_rows=pad)
-                pipe = PsPipeline(client, 0, step, depth=2, lr_sparse=0.1)
+                pipe = PsPipeline(client, 0, step, depth=1, lr_sparse=0.1)
                 stats = pipe.run(batches)
                 pipe.close()
                 return stats, client.pull_bytes + client.push_bytes
@@ -390,9 +474,9 @@ class TestPipeline:
                 bus.close()
 
         s32, b32 = run("fp32")
-        s8, b8 = run("int8_block")
+        s8, b8 = run(codec)
         assert b8 <= 0.31 * b32
-        assert abs(s8["losses"][-1] - s32["losses"][-1]) < 0.05
+        assert abs(s8["losses"][-1] - s32["losses"][-1]) < 0.02
 
     def test_pipeline_through_heter_cache(self):
         from paddle_tpu.distributed.ps.heter_cache import HeterCache
@@ -421,44 +505,19 @@ class TestPipeline:
             bus.close()
 
     def test_tracing_spans_name_each_stage(self):
-        from paddle_tpu.framework.flags import _FLAGS
-        from paddle_tpu.observability.tracing import get_tracer
-
-        batches = ctr_batches(3, BATCH, SLOTS, 200, alpha=1.0, seed=0)
-        client, services, bus = make_sharded_ps(2, base_task=9900)
-        old = _FLAGS.get("FLAGS_serving_tracing", True)
-        _FLAGS["FLAGS_serving_tracing"] = True
-        try:
-            client.create_table(0, DIM)
-            step = _model_step()
-            pipe = PsPipeline(client, 0, step, depth=2, lr_sparse=0.1,
-                              name="ps_pass_test")
-            pipe.run(batches)
-            pipe.close()
-            store = get_tracer().store
-            docs = [store.get(t["trace_id"])
-                    for t in store.index()["traces"]]
-            doc = next(d for d in docs
-                       if d and d["name"] == "ps_pass_test")
-            names = {s["name"] for s in doc["spans"]}
-            assert {"pull_launch", "pull_wait", "step",
-                    "push_commit"} <= names
-            # a span names its step and buffer -> a stall is attributable
-            sp = next(s for s in doc["spans"] if s["name"] == "pull_wait")
-            assert "step" in sp["fields"] and "buf" in sp["fields"]
-        finally:
-            _FLAGS["FLAGS_serving_tracing"] = old
-            client.close()
-            for s in services:
-                s.stop()
-            bus.close()
+        _, doc = self._traced_pass("ps_pass_test", 2, 3, base_task=9900)
+        names = {s["name"] for s in doc["spans"]}
+        assert {"pull_launch", "pull_wait", "step", "push_commit"} <= names
+        # a span names its step and buffer -> a stall is attributable
+        sp = next(s for s in doc["spans"] if s["name"] == "pull_wait")
+        assert "step" in sp["fields"] and "buf" in sp["fields"]
 
 
 # ---------------------------------------------------------------------------
-# flags / metrics / bench smoke
+# flags / metrics
 # ---------------------------------------------------------------------------
 
-class TestKnobsAndSmoke:
+class TestKnobs:
     def test_ps_flags_declared(self):
         from paddle_tpu.framework.flags import flag
 
@@ -494,17 +553,6 @@ class TestKnobsAndSmoke:
         cache.lookup([1, 2])      # misses
         cache.lookup([1, 2])      # hits
         assert child.get() == before + 2
-
-    def test_quick_bench_writes_gated_fields(self, tmp_path):
-        import tools.ps_bench as B
-
-        t0 = time.monotonic()
-        out = B.main(["--quick", "--out", str(tmp_path / "ps.json")])
-        took = time.monotonic() - t0
-        assert out["ps_examples_per_s"] > 0
-        assert "ps_exposed_pull_ms" in out
-        assert out["speedup_vs_eager"] > 1.0
-        assert took < 60  # tier-3 full budget guard; quick target ~10s
 
 
 class TestCostModel:

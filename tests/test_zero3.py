@@ -10,7 +10,6 @@ update, and BIT-identical losses vs the replicated os_g path on gpt-test
 for fp32/bf16/int8_block — plus the save/checkpoint/bench/cost wiring.
 """
 import gc
-import json
 import os
 
 import jax.numpy as jnp
@@ -31,15 +30,12 @@ from paddle_tpu.distributed.overlap import (
 from paddle_tpu.distributed.sharding import (
     Stage3ParamShards, group_sharded_parallel, save_group_sharded_model,
 )
-from paddle_tpu.distributed.sharding.stage3 import (
-    FreedParamValue, zero3_gather_report,
-)
+from paddle_tpu.distributed.sharding.stage3 import FreedParamValue
 from paddle_tpu.framework.tensor import Tensor
 from paddle_tpu.observability import get_registry
 from paddle_tpu.observability import memory as obs_mem
 from paddle_tpu.optimizer.fused import FusedFlatUpdater
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 rng = np.random.RandomState(0)
 
 X = rng.standard_normal((16, 8)).astype(np.float32)
@@ -548,60 +544,61 @@ class TestCostAndTooling:
         assert one["gather_time_s"] == 0.0
         assert one["param_bytes_per_rank"] == int(pb)
 
-    def test_zero3_gather_report_and_bench_artifact(self):
-        """The acceptance ratio on gpt-test shapes: prefetched exposed
-        gather <= 25% of the synchronous baseline, and the per-rank bytes
-        are half the full set at world=2 — both measured live and pinned
-        in the committed artifact."""
+    @pytest.mark.parametrize("fault", ["clean", "raises_mid_walk"])
+    def test_layer_ahead_walk_gathers_once_and_frees_all(self, fault):
+        """The layer-ahead discipline by its counts: walking the buckets
+        with the next one prefetched gathers each exactly once (the first
+        synchronously, the rest on the lane), holds half the parameter
+        bytes per rank at world=2, and leaves nothing resident and every
+        parameter a FreedParamValue, also when the walk raises with a
+        prefetch in flight."""
         net = _mlp()
-        rep = zero3_gather_report(
-            [p for p in net.parameters()],
-            grad_comm.GradCommConfig(comm_buffer_size=0.0002,
-                                     last_comm_buffer_size=0.0001),
-            world=2, compute_s=0.05)
-        assert rep["n_buckets"] >= 3
-        assert rep["prefetch_exposed_gather_ms"] < \
-            rep["sync_exposed_gather_ms"]
-        assert rep["zero3_param_bytes_per_rank"] <= \
-            rep["param_bytes_full"] / 2 + 2048
+        params = [p for p in net.parameters() if not p.stop_gradient]
+        full = sum(p._value.size * p._value.dtype.itemsize for p in params)
+        store = Stage3ParamShards(params, grad_comm.GradCommunicator(_cfg()),
+                                  rank=0, world=2)
+        store.shard_()
+        n = len(store.buckets)
+        assert n >= 3
+        assert store.param_bytes_per_rank() <= full / 2 + 2048
 
-        d = json.load(open(os.path.join(REPO, "artifacts",
-                                        "overlap_bench.json")))
-        z3 = d["zero3"]
-        assert z3["world"] == 2 and z3["n_buckets"] >= 2
-        assert z3["prefetch_exposed_gather_ms"] <= \
-            0.25 * z3["sync_exposed_gather_ms"], z3
-        assert z3["zero3_param_bytes_per_rank"] <= \
-            z3["param_bytes_full"] / 2 + 4096
+        def gathers():
+            by_mode = get_registry().snapshot()["zero3_gathers_total"]
+            return (by_mode.get("mode=sync", 0),
+                    by_mode.get("mode=prefetched", 0))
 
-    def test_bench_gate_gates_zero3_fields(self):
-        import importlib.util
+        def walk(raise_at=None):
+            for i, b in enumerate(store.buckets):
+                try:
+                    store.ensure_gathered(b.index)
+                    if i + 1 < n:
+                        store.prefetch_bucket(store.buckets[i + 1].index)
+                    if i == raise_at:
+                        raise RuntimeError("layer failed")
+                    assert store.resident_buckets() == [b.index]
+                finally:
+                    store.free_bucket(b.index)
 
-        spec = importlib.util.spec_from_file_location(
-            "bench_gate", os.path.join(REPO, "tools", "bench_gate.py"))
-        bg = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(bg)
-        base = {"value": 1000.0, "device_kind": "cpu", "fallback": "cpu",
-                "zero3_exposed_gather_ms": 1.0,
-                "zero3_param_bytes_per_rank": 250000}
-        trajectory = [("r1", base)]
-        ok = dict(base, zero3_exposed_gather_ms=1.1)
-        rows, compared, regressed = bg.gate(ok, trajectory, 0.20)
-        assert regressed == 0 and compared >= 3
-        # >20% slower exposed gather regresses
-        bad = dict(base, zero3_exposed_gather_ms=1.5)
-        rows, _, regressed = bg.gate(bad, trajectory, 0.20)
-        assert regressed == 1
-        row = {r["metric"]: r for r in rows}
-        assert row["zero3_exposed_gather_ms"]["verdict"] == "REGRESSED"
-        # params quietly un-sharding (bytes/rank doubling) regresses too
-        fat = dict(base, zero3_param_bytes_per_rank=500000)
-        _, _, regressed = bg.gate(fat, trajectory, 0.20)
-        assert regressed == 1
-        # records predating ISSUE 9 just SKIP the new fields
-        old = {"value": 1000.0, "device_kind": "cpu", "fallback": "cpu"}
-        rows, compared, regressed = bg.gate(old, trajectory, 0.20)
-        assert regressed == 0 and compared >= 1
+        sync0, pre0 = gathers()
+        if fault == "clean":
+            walk()
+            launched = n - 1
+        else:
+            with pytest.raises(RuntimeError, match="layer failed"):
+                try:
+                    walk(raise_at=1)
+                finally:
+                    store.free_bucket(store.buckets[2].index)
+            launched = 2
+        sync1, pre1 = gathers()
+        assert (sync1 - sync0, pre1 - pre0) == (1, launched)
+        assert store.resident_buckets() == []
+        assert all(isinstance(p._value, FreedParamValue) for p in params)
+        assert get_registry().snapshot()["zero3_gathered_buckets"] == 0
+        # and the store is whole: the next walk gathers every bucket again
+        walk()
+        assert gathers() == (sync1 + 1, pre1 + n - 1)
+        assert all(isinstance(p._value, FreedParamValue) for p in params)
 
     def test_exposed_gather_gauge_exported(self):
         net = _mlp()

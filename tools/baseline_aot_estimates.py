@@ -6,8 +6,9 @@ config is AOT-compiled with the TPU compiler (jax.experimental
 .topologies) at the bench shapes, recording per-device memory and a
 labeled roofline step-time bound from the compiler's own cost counters.
 
-Measurements still come from bench.py on the live chip; these rows exist
-so every config has TPU-compiler evidence, and so
+Measurements come from the benchmark's cells on the chip
+(BENCHMARK.json); these rows exist so every config has TPU-compiler
+evidence, and so
 regressions that only show up in TPU lowering (layout, fusion, kernel
 choice) are visible without hardware.
 

@@ -10,7 +10,7 @@ Joins two artifacts the telemetry layer produces —
 — into ONE report: per-phase wall time next to the matching counters, so
 the comm row shows not just "x ms" but "x ms, N collectives, B bytes/step"
 and the two accountings can be cross-checked against
-artifacts/grad_comm_bench.json.
+`grad_comm.comm_plan`.
 
 Usage:
     python tools/trace_report.py TRACE.json METRICS.json
@@ -19,10 +19,9 @@ Usage:
         # sync at world=2 + a checkpoint save) under Profiler+StepTimer,
         # exports trace + snapshot to --out (default /tmp), then reports.
 
-The demo's comm row must agree with tools/grad_comm_bench.py's artifact for
-the same codec (collectives/step and bytes/step) — that agreement is the
-acceptance check that the wall-time view and the counter view describe the
-same wire.
+The demo's comm row must agree with `grad_comm.comm_plan` for the same
+codec (collectives/step and bytes/step) — that agreement is the acceptance
+check that the wall-time view and the counter view describe the same wire.
 """
 from __future__ import annotations
 
@@ -283,7 +282,7 @@ def run_demo(out_dir: str, steps: int = 3, codec: str = "bf16",
     report = build_report(trace, snapshot, aggregated=aggregated,
                           memory=memory)
     # cross-check: the comm row's counters must equal the communicator's
-    # own per-step stats (same accounting as artifacts/grad_comm_bench.json)
+    # own per-step stats (the accounting grad_comm.comm_plan plans)
     per_step_coll = comm.stats["collectives"]
     per_step_bytes = comm.stats["comm_bytes"]
     report += (f"\ngrad_comm cross-check ({codec}, world={world}): "
