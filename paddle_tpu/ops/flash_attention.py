@@ -14,7 +14,7 @@ p and ds where they enter the second matmuls — are in the dtype q, k, v
 arrive in: bf16 callers get bf16 x bf16 dots, fp32 callers fp32 ones. What
 is float32 whatever comes in: every dot's accumulation
 (preferred_element_type), the scores and the mask, the softmax statistics
-m, l, lse and delta, exp, dp - delta, and the three accumulators. (On the
+m, l, lse and delta, exp, dp - delta, and the four accumulators. (On the
 chip this states what Mosaic did already: at default precision it feeds an
 fp32 operand to the MXU as one bf16 pass, so widening the blocks first
 bought no precision and cost no time — PERF.md, PR 26.)
@@ -29,11 +29,18 @@ heads are 192 / 128 wide): the scores contract d_qk and are scaled by
 d_qk wide. Nothing is padded to the wider of the two, in HBM or in VMEM.
 With d_qk == d_v the kernels are the ones they were.
 
-Backward uses the standard two-kernel flash decomposition:
-  dq kernel:  grid (b, n, q_blocks, kv_blocks), dq accumulates in scratch
-  dkv kernel: grid (b, n, kv_blocks, q_blocks), dk/dv accumulate in scratch,
-              on the transposed score block (see _dkv_kernel)
-with delta = rowsum(dO * O) precomputed outside (one fused elementwise pass).
+Backward is ONE kernel, grid (b, n, kv_blocks, q_blocks) with q innermost,
+on the transposed score block (see _bwd_kernel): s^T, p^T, dp^T and ds^T
+are made once a block pair and feed all three gradients. dk / dv
+accumulate in a scratch of one kv block; dq accumulates over the kv axis in
+a float32 scratch that spans the head's whole sequence, which the grid's
+order allows because it finishes one head before the next and a head's dq
+is small against VMEM (6.3 MB at s = 8192, 192 wide). The VMEM limit
+follows the shapes (_bwd_vmem_bytes) and flash_attention_supported refuses
+a sequence whose accumulator would not fit (_DQ_ACC_BYTES). delta =
+rowsum(dO * O) is precomputed outside (one fused elementwise pass). The
+call is named flash_bwd_dkv, the name of the dkv kernel it grew from: the
+benchmark's readers find the backward by it (ROADMAP C2k).
 """
 from __future__ import annotations
 
@@ -72,14 +79,21 @@ def _default_block(d: int, dtype, d_v: int = None) -> int:
     spread over the lanes), hardly with the block's area, so one (1024,
     1024) block beats the three of four (512, 512) blocks under the causal
     diagonal by 1.33-1.39x over forward + backward although it computes
-    the masked quarter too. 2048 does not fit VMEM in the backward
-    kernels, nor does 1024 with fp32 rows of d=256 (the TPU compiler,
-    without the chip): hence the bound on the row's bytes.
+    the masked quarter too. 2048 does not fit the forward's VMEM, nor
+    does 1024 with fp32 rows of d=256 (the TPU compiler, without the
+    chip): hence the bound on the row's bytes.
     Latent attention's q, k 192 / v 128 wide in bf16 (a 384-byte row) stay
     at 1024 by the same rule and by PR 27's sweep at s=8192, 32 heads (ms a
-    call, forward / dq + dkv, ten calls chained in one jit): 1024x1024
-    8.2 / 22.5; 512x1024 9.7 / 23.8; 1024x512 13.5 / 24.1; 512x512 13.8 /
-    25.3; 2048 runs out of VMEM in the dq kernel."""
+    call, forward, ten calls chained in one jit): 1024x1024 8.2; 512x1024
+    9.7; 1024x512 13.5; 512x512 13.8.
+    The one backward kernel (PR 32) shares the pair. Its sweep on the v5e
+    (ms a call, ten chained, block_q x block_k; b12's shape / b6's / 192 /
+    128 wide at s=8192): 1024x1024 1.22 / 1.74 / 14.6; 512x1024 1.22 /
+    1.77 / 15.0; 512x512 1.13 / 1.75 / 16.2; 256x1024 1.41 / 2.09 / 16.0;
+    1024x2048 - / 2.12 / 15.2; 2048x2048, which its computed VMEM limit
+    lets compile, - / 2.20 / 15.1. Inside the step 512x512 reads 0.3 ms
+    under 1024x1024 at s=1024 and 1.0 ms over it at s=2048
+    (`flash_bwd_ms_step`): one pair for both kernels and all shapes."""
     return 1024 if max(d, d_v or d) * jnp.dtype(dtype).itemsize <= 512 \
         else 512
 
@@ -122,16 +136,33 @@ def flash_block_choice(shape, dtype="float32", causal=True,
     return {"block_q": int(bq), "block_k": int(bk), "source": source}
 
 
+# The backward keeps one head's whole dq in VMEM as float32 (_bwd_kernel),
+# s x d_qk x 4 bytes. 24 MiB of it is s = 32,768 at 192 wide, 49,152 at 128,
+# 98,304 at 64. The dq block lies beside it twice in the inputs' dtype:
+# 72 MiB in fp32, 97 with the tiles and a block pair's float32
+# intermediates (_bwd_vmem_bytes), three quarters of the v5e's 128 MiB; the
+# TPU compiler takes that edge without the chip (tests/test_tpu_aot.py)
+_DQ_ACC_BYTES = 24 << 20
+
+
+def _dq_acc_fits(s: int, d: int) -> bool:
+    return s * d * 4 <= _DQ_ACC_BYTES
+
+
 def flash_attention_supported(q_shape, block: int = 512,
                               block_q: int = None,
                               block_k: int = None) -> bool:
     """True if the kernel can handle this [b, s, n, d] shape. With
     explicit ``block_q``/``block_k`` the check honors the independent
     tiles (s must divide by BOTH); with neither, the ladder must find a
-    block <= ``block``."""
+    block <= ``block``. Never for a sequence whose float32 dq accumulator
+    (the backward keeps a head's whole dq in VMEM) would pass
+    _DQ_ACC_BYTES."""
     if len(q_shape) != 4:
         return False
     s = int(q_shape[1])
+    if not _dq_acc_fits(s, int(q_shape[3])):
+        return False
     if block_q is not None or block_k is not None:
         bq = int(block_q or block)
         bk = int(block_k or block)
@@ -258,60 +289,65 @@ def _fwd(q, k, v, causal, block_q, block_k):
 # backward
 # ---------------------------------------------------------------------------
 
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-               acc_ref, *, scale, causal, block_q, block_k):
-    qi, ki = pl.program_id(2), pl.program_id(3)
-    nk = pl.num_programs(3)
-
-    @pl.when(ki == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    run = (qi * block_q + block_q - 1 >= ki * block_k) if causal else True
-
-    @pl.when(run)
-    def _compute():
-        q = _scaled(q_ref[0, 0, :, :], scale)                    # (BQ, d)
-        kb = k_ref[0, 0, :, :]                                   # (BK, d)
-        vb = v_ref[0, 0, :, :]
-        do = do_ref[0, 0, :, :]
-        lse = lse_ref[0, 0, :, :]                                # (BQ, 1)
-        delta = delta_ref[0, 0, :, :]
-        s_blk = jax.lax.dot_general(
-            q, kb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        if causal:
-            s_blk = _causal_mask(s_blk, qi, ki, block_q, block_k)
-        p = jnp.exp(s_blk - lse)                                 # (BQ, BK)
-        dp = jax.lax.dot_general(
-            do, vb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - delta)
-        acc_ref[...] += jax.lax.dot_general(
-            ds.astype(kb.dtype), kb, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-    @pl.when(ki == nk - 1)
-    def _finish():
-        dq_ref[0, 0, :, :] = (acc_ref[...] * scale).astype(dq_ref.dtype)
+def _pad(n: int, to: int) -> int:
+    return -(-n // to) * to
 
 
-def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                dk_ref, dv_ref, dk_acc, dv_acc, *, scale, causal,
-                block_q, block_k):
-    """Works on the TRANSPOSED score block s^T = k q^T, (BK, BQ), so that
-    dv += p^T dO and dk += ds^T q are plain row-by-column products and the
-    row statistics come as (1, BQ) rows, spread down the sublanes. From an
+def _bwd_vmem_bytes(s, d, d_v, block_q, block_k, dtype) -> int:
+    """The backward kernel's VMEM limit, from its shapes: the float32 dq^T
+    accumulator over the head's whole sequence, the dq^T output block (the
+    head's whole dq, double-buffered like every block), the q, k, v, dO,
+    dk, dv tiles (double-buffered) with the float32 dk / dv accumulators,
+    and s^T, p^T, dp^T, ds^T of one block pair in float32; 2 MiB for the
+    (1, BQ) statistics rows and what else is small. A last dim takes whole
+    128-lane tiles (192 takes 256). A bound, not the need: Mosaic keeps less of the intermediates at
+    once (the TPU compiler, without the chip, still compiles the kernel
+    under 5.8 MiB of the 22.6 at b12's shape, 17.5 of 36.1 at q, k 192 /
+    v 128 wide and s = 8192, 37.1 of 97.1 at the longest fp32 sequence
+    that flash_attention_supported lets through)."""
+    item = jnp.dtype(dtype).itemsize
+    wd, wv, wq = _pad(d, _LANES), _pad(d_v, _LANES), _pad(block_q, _LANES)
+    dq = (s // block_q) * _pad(d, 16) * wq * (4 + 2 * item)
+    tiles = 2 * item * (block_q + 2 * block_k) * (wd + wv)
+    accs = 4 * block_k * (wd + wv)
+    scores = 4 * 4 * block_k * wq
+    return dq + tiles + accs + scores + (2 << 20)
+
+
+def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *, scale,
+                causal, block_q, block_k):
+    """The whole backward of one (kv block, q block) pair: s^T, p^T, dp^T
+    and ds^T are made once and feed dv += p^T dO, dk += ds^T q and
+    dq^T[q block] += k^T ds^T: five products where a dq kernel and a dkv
+    kernel made seven, and the mask, exp and dp - delta once.
+
+    Works on the TRANSPOSED score block s^T = k q^T, (BK, BQ), so that the
+    dv and dk products are plain row-by-column products and the row
+    statistics come as (1, BQ) rows, spread down the sublanes. From an
     untransposed p both products contract dim 0 of both operands and lse
     and delta are (BQ, 1) columns spread over the lanes: 18-23 % slower at
-    block 512 on the v5e, 2-10 % at 1024 (PERF.md, PR 26)."""
+    block 512 on the v5e, 2-10 % at 1024 (PERF.md, PR 26).
+
+    dk and dv accumulate over the inner (q) axis in a scratch of one kv
+    block. dq accumulates over the OUTER (kv) axis, so its float32
+    accumulator holds every q block of the head, (nq, d, BQ): block qi is
+    zeroed at ki == 0, added to in ascending ki, and written at
+    ki == nk - 1 to the dq output, whose block is the head's whole dq^T
+    and stays in VMEM over both inner axes. dq^T and not dq: inside the
+    step the kernel reads 6-13 % less so on the v5e (PERF.md, finding
+    19)."""
     ki, qi = pl.program_id(2), pl.program_id(3)
-    nq = pl.num_programs(3)
+    nk, nq = pl.num_programs(2), pl.num_programs(3)
 
     @pl.when(qi == 0)
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    @pl.when(ki == 0)
+    def _init_dq():
+        dq_acc[qi] = jnp.zeros(dq_acc.shape[1:], dq_acc.dtype)
 
     run = (qi * block_q + block_q - 1 >= ki * block_k) if causal else True
 
@@ -331,78 +367,80 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         pt = jnp.exp(st - lse)
         dv_acc[...] += jax.lax.dot_general(
             pt.astype(do.dtype), do, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)                  # (BK, d)
+            preferred_element_type=jnp.float32)                  # (BK, d_v)
         dpt = jax.lax.dot_general(
             vb, do, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)                  # (BK, BQ)
-        dst = pt * (dpt - delta)
+        dst = (pt * (dpt - delta)).astype(q.dtype)
         # q was pre-scaled, so dk already carries `scale`
         dk_acc[...] += jax.lax.dot_general(
-            dst.astype(q.dtype), q, (((1,), (0,)), ((), ())),
+            dst, q, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)                  # (BK, d)
+        # dq = ds k contracts dim 0 of ds^T. Made as dq^T = k^T ds^T, (d, BQ):
+        # the transposed operand is then the small k tile and not the
+        # (BK, BQ) one, and the accumulator is lane-dense at any d
+        dq_acc[qi] += jax.lax.dot_general(
+            kb, dst, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)                  # (d, BQ)
 
     @pl.when(qi == nq - 1)
     def _finish():
         dk_ref[0, 0, :, :] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[0, 0, :, :] = dv_acc[...].astype(dv_ref.dtype)
 
+    @pl.when(ki == nk - 1)
+    def _finish_dq():
+        dq_ref[0, 0, qi] = (dq_acc[qi] * scale).astype(dq_ref.dtype)
+
 
 def _bwd(q, k, v, out, lse, do, causal, block_q, block_k):
     b, n, s, d = q.shape
     d_v = v.shape[-1]
+    nq = s // block_q
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1, keepdims=True)                      # (b, n, s, 1)
+                    axis=-1)                                     # (b, n, s)
 
-    def tile(rows, width, on_q, kv_major=False):
+    def tile(rows, width, on_q):
         """A (1, 1, rows, width) tile that follows the q block (`on_q`) or
-        the kv block; `kv_major` for the grid whose third axis is kv."""
-        if kv_major:
-            return pl.BlockSpec((1, 1, rows, width),
-                                lambda bi, hi, ki, qi: (
-                                    bi, hi, qi if on_q else ki, 0))
+        the kv block."""
         return pl.BlockSpec((1, 1, rows, width),
-                            lambda bi, hi, qi, ki: (
+                            lambda bi, hi, ki, qi: (
                                 bi, hi, qi if on_q else ki, 0))
 
     qb, dob = tile(block_q, d, True), tile(block_q, d_v, True)
-    kb_, vb_ = tile(block_k, d, False), tile(block_k, d_v, False)
-    rowb = pl.BlockSpec((1, 1, block_q, 1),
-                        lambda bi, hi, qi, ki: (bi, hi, qi, 0))
-
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, scale=1.0 / math.sqrt(d), causal=causal,
-                          block_q=block_q, block_k=block_k),
-        grid=(b, n, s // block_q, s // block_k),
-        in_specs=[qb, kb_, vb_, dob, rowb, rowb],
-        out_specs=qb,
-        out_shape=_sds((b, n, s, d), q.dtype, q),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        interpret=_interpret(),
-        name="flash_bwd_dq",
-    )(q, k, v, do, lse, delta)
-
-    # dkv: grid (b, n, kv_blocks, q_blocks) — q innermost. lse and delta go
-    # in as one (1, BQ) row per q block: a block that spans its array's last
-    # two dims whole is legal for every block_q
-    qb2, dob2 = tile(block_q, d, True, True), tile(block_q, d_v, True, True)
-    kb2, vb2 = tile(block_k, d, False, True), tile(block_k, d_v, False, True)
-    rowb2 = pl.BlockSpec((1, 1, 1, 1, block_q),
-                         lambda bi, hi, ki, qi: (bi, hi, qi, 0, 0))
-    rows = (b, n, s // block_q, 1, block_q)
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, scale=1.0 / math.sqrt(d),
+    kb, vb = tile(block_k, d, False), tile(block_k, d_v, False)
+    # lse and delta go in as one (1, BQ) row per q block: a block that
+    # spans its array's last two dims whole is legal for every block_q,
+    # and so is the head's dq^T as (nq, d, BQ), which the kernel indexes
+    # by q block on a leading dim
+    rowb = pl.BlockSpec((1, 1, 1, 1, block_q),
+                        lambda bi, hi, ki, qi: (bi, hi, qi, 0, 0))
+    rows = (b, n, nq, 1, block_q)
+    dqb = pl.BlockSpec((1, 1, nq, d, block_q),
+                       lambda bi, hi, ki, qi: (bi, hi, 0, 0, 0))
+    dq, dk, dv = pl.pallas_call(
+        functools.partial(_bwd_kernel, scale=1.0 / math.sqrt(d),
                           causal=causal, block_q=block_q, block_k=block_k),
-        grid=(b, n, s // block_k, s // block_q),
-        in_specs=[qb2, kb2, vb2, dob2, rowb2, rowb2],
-        out_specs=[kb2, vb2],
-        out_shape=[_sds((b, n, s, d), k.dtype, k),
+        grid=(b, n, s // block_k, nq),
+        in_specs=[qb, kb, vb, dob, rowb, rowb],
+        out_specs=[dqb, kb, vb],
+        out_shape=[_sds((b, n, nq, d, block_q), q.dtype, q),
+                   _sds((b, n, s, d), k.dtype, k),
                    _sds((b, n, s, d_v), v.dtype, v)],
-        scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((nq, d, block_q), jnp.float32),
+                        pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d_v), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_bwd_vmem_bytes(s, d, d_v, block_q, block_k,
+                                             q.dtype)),
         interpret=_interpret(),
+        # the instruction's name is what the benchmark's readers find the
+        # backward by (benchmark/layer_metrics/flash_bwd_ms_step.json)
         name="flash_bwd_dkv",
     )(q, k, v, do, lse.reshape(rows), delta.reshape(rows))
-    return dq, dk, dv
+    # dq left the kernel as (nq, d, BQ) a head: XLA folds this transpose
+    # into the [b, n, s, d] -> [b, s, n, d] one that follows
+    return jnp.swapaxes(dq, -1, -2).reshape(b, n, s, d), dk, dv
 
 
 # ---------------------------------------------------------------------------
@@ -451,6 +489,11 @@ def flash_attention_val(q, k, v, causal=True, block_size=None,
         raise ValueError(
             f"flash attention: q {q.shape} and k {k.shape} must agree, and "
             f"v {v.shape} with them in all but the head size")
+    if not _dq_acc_fits(s, d):
+        raise ValueError(
+            f"flash attention: the backward keeps a head's dq in VMEM, and "
+            f"{s} x {d} in float32 passes {_DQ_ACC_BYTES >> 20} MiB (check "
+            f"flash_attention_supported first)")
     if block_q is not None or block_k is not None:
         other = block_size or _default_block(d, q.dtype, d_v)
         bq = int(block_q or other)

@@ -196,27 +196,31 @@ def _eqns(jaxpr):
                     yield from _eqns(inner)
 
 
-def _kernel_bodies(dtype):
-    """The three pallas_call equations of forward + backward, by name."""
+def _grad_jaxpr(dtype, **blocks):
+    """The jaxpr of all three gradients at q, k, v [1, 64, 2, 64]."""
     q = jnp.zeros((1, 64, 2, 64), dtype)
     grads = jax.grad(
         lambda a, b, c: jnp.sum(flash_attention_val(
-            a, b, c, causal=True, block_q=32, block_k=16
-        ).astype(jnp.float32)), (0, 1, 2))
-    jaxpr = jax.make_jaxpr(grads)(q, q, q).jaxpr
-    return {e.params["name"]: e for e in _eqns(jaxpr)
+            a, b, c, causal=True, **blocks).astype(jnp.float32)), (0, 1, 2))
+    return jax.make_jaxpr(grads)(q, q, q).jaxpr
+
+
+def _kernel_bodies(dtype):
+    """The pallas_call equations of forward + backward, by name."""
+    return {e.params["name"]: e
+            for e in _eqns(_grad_jaxpr(dtype, block_q=32, block_k=16))
             if e.primitive.name == "pallas_call"}
 
 
 @pytest.mark.parametrize("kernel,n_dots", [("flash_fwd", 2),
-                                           ("flash_bwd_dq", 3),
-                                           ("flash_bwd_dkv", 4)])
+                                           ("flash_bwd_dkv", 5)])
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
 def test_kernel_dots_take_the_input_dtype(kernel, n_dots, dtype):
     """No dot in a kernel body widens its operands: for bf16 inputs none
     takes a float32 operand, and every one accumulates in float32. The
-    statistics (lse, delta) and the scratch accumulators are float32
-    whatever comes in."""
+    statistics (lse, delta) and the scratch accumulators, the backward's
+    whole-sequence dq accumulator among them, are float32 whatever comes
+    in."""
     call = _kernel_bodies(dtype)[kernel]
     dots = [e for e in _eqns(call.params["jaxpr"])
             if e.primitive.name == "dot_general"]
@@ -228,6 +232,66 @@ def test_kernel_dots_take_the_input_dtype(kernel, n_dots, dtype):
     mapping = call.params["grid_mapping"]
     scratch = refs[len(refs) - mapping.num_scratch_operands:]
     assert scratch and all(r.dtype == jnp.float32 for r in scratch), scratch
-    # lse and delta blocks: (BQ, 1) columns, (1, BQ) rows in the dkv kernel
+    if kernel == "flash_bwd_dkv":
+        # dq's accumulator holds every q block of the head: 64 rows here
+        assert math.prod(scratch[0].shape) == 64 * 64, scratch[0]
+    # lse and delta blocks: (BQ, 1) columns forward, (1, BQ) rows backward
     stats = [r for r in refs if 1 in r.shape[-2:]]
     assert stats and all(r.dtype == jnp.float32 for r in stats), stats
+
+
+def test_backward_is_one_kernel_of_five_products():
+    """s^T and dp^T are made once: the backward is ONE pallas_call, named
+    as the benchmark's readers find it, with five dot_generals (s^T, dv,
+    dp^T, dk, dq) where two kernels made seven."""
+    bwd = [e for e in _eqns(_grad_jaxpr(jnp.bfloat16, block_q=16,
+                                        block_k=32))
+           if e.primitive.name == "pallas_call"
+           and e.params["name"] != "flash_fwd"]
+    assert [e.params["name"] for e in bwd] == ["flash_bwd_dkv"]
+    assert sum(e.primitive.name == "dot_general"
+               for e in _eqns(bwd[0].params["jaxpr"])) == 5
+
+
+# dq accumulates across kv blocks (the OUTER grid axis) while q is the inner
+# one: s = 256 in 4 x 8 and 8 x 4 blocks, so every q block's accumulator is
+# revisited once per kv block, with block_q != block_k both ways round
+MANY_BLOCKS = [(64, 32), (32, 64)]
+WIDTHS = [(32, 32), (48, 32)]             # d_qk = d_v, and 192 / 128 in small
+
+
+def _rand_qkv(s, d_qk, d_v, seed):
+    rs = np.random.RandomState(seed)
+    mk = lambda d: jnp.asarray(rs.randn(1, s, 2, d), jnp.float32)
+    return mk(d_qk), mk(d_qk), mk(d_v)
+
+
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("d_qk,d_v", WIDTHS)
+@pytest.mark.parametrize("bq,bk", MANY_BLOCKS)
+@pytest.mark.parametrize("dtype,tol", [
+    (jnp.bfloat16, BF16_TOL), (jnp.float32, dict(rtol=1e-4, atol=1e-4))])
+def test_gradients_over_many_blocks(dtype, tol, causal, d_qk, d_v, bq, bk):
+    q, k, v = (x.astype(dtype) for x in _rand_qkv(256, d_qk, d_v, 8))
+    got, want = _flash_and_reference_grads(q, k, v, causal, bq, bk)
+    for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
+        assert a.dtype == dtype and a.shape == b.shape, name
+        np.testing.assert_allclose(_f32(a), np.asarray(b), err_msg=name,
+                                   **tol)
+
+
+def test_supported_up_to_the_dq_accumulators_budget():
+    """The backward keeps a head's whole dq in VMEM: a sequence whose
+    accumulator passes the budget is not supported, and callers take their
+    other path."""
+    from paddle_tpu.ops import flash_attention as fa
+
+    assert flash_attention_supported((1, 8192, 32, 192))   # the 8k cell
+    assert flash_attention_supported((6, 2048, 12, 64))
+    longest = fa._DQ_ACC_BYTES // (4 * 192)      # s x d_qk float32
+    assert flash_attention_supported((1, longest, 32, 192))
+    assert not flash_attention_supported((1, 2 * longest, 32, 192))
+    assert flash_attention_supported((1, 2 * longest, 32, 64))
+    assert not flash_attention_supported((1, 4 * longest, 32, 64))
+    assert not flash_attention_supported((1, 2 * longest, 32, 192),
+                                         block_q=512, block_k=512)
