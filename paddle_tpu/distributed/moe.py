@@ -15,11 +15,13 @@ distributed/utils.py as eager permutation semantics for compatibility.
 
 Two expert layers live here. `MoELayer` is that GShard layer: softmax top-2,
 a capacity factor (tokens over capacity are dropped), one-hot [T, E, C]
-dispatch. `DroplessMoELayer` is the DeepSeek-V3 style layer of one chip of
-an expert-parallel group: it is told which experts it holds, routes over
-all of them with sigmoid scores and a selection bias, and computes its own
-experts' part for every token routed to them as grouped matrix products
-over rows sorted by expert: no capacity, no dropped token, no one-hot.
+dispatch. `DroplessMoELayer` is the layer of one chip of an
+expert-parallel group: it is told which experts it holds, routes over all
+of them (sigmoid scores and a selection bias, DeepSeek-V3 style, or a
+softmax over all outputs with the chosen weights renormalised), and
+computes its own experts' part for every token routed to them as grouped
+matrix products over rows sorted by expert: no capacity, no dropped
+token, no one-hot.
 """
 from __future__ import annotations
 
@@ -287,6 +289,20 @@ def sigmoid_topk_route(x2, router_w, bias, top_k: int, scale: float):
         return chosen.astype(jnp.int32), w
 
 
+def softmax_topk_route(x2, router_w, top_k: int):
+    """Softmax routing of x2 [T, h] over the router's R outputs, all in
+    float32: p = softmax(x W_r) over all R; the top_k of p are chosen;
+    weights p[chosen] / sum(p[chosen]). No selection bias and no scale.
+    Returns (chosen [T, k] int32, weights [T, k] float32)."""
+    with jax.named_scope("moe_router"):
+        p = jax.nn.softmax(jnp.dot(
+            x2.astype(jnp.float32), router_w.astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST), axis=-1)
+        picked, chosen = jax.lax.top_k(p, top_k)
+        w = picked / (jnp.sum(picked, -1, keepdims=True) + 1e-20)
+        return chosen.astype(jnp.int32), w
+
+
 def held_experts_ffn(x2, chosen, weights, w_gate, w_up, w_down, lo: int):
     """The part of the routed result that experts [lo, lo + E) give, E the
     leading size of the weights [E, h, f] / [E, f, h] (SwiGLU experts):
@@ -337,14 +353,21 @@ def swiglu(x, w_gate, w_up, w_down):
 
 
 def dropless_moe_val(x2, p: dict, bias, *, top_k: int, scale: float,
-                     lo: int):
+                     lo: int, router: str = "sigmoid"):
     """x2 [T, h] through the whole layer at value level. `p` holds
     router_w [h, R], w_gate / w_up [E, h, f], w_down [E, f, h] and, where
     the layer has shared experts, shared_gate / shared_up [h, fs] and
-    shared_down [fs, h]. Returns (y [T, h], chosen [T, k], the counts of
-    assignments per router output [R] int32)."""
-    chosen, weights = sigmoid_topk_route(x2, p["router_w"], bias, top_k,
-                                         scale)
+    shared_down [fs, h], and where those have a gate, shared_gate_w [h, 1]:
+    the shared output is multiplied by sigmoid(x w), one number a token.
+    `router`: "sigmoid" (`sigmoid_topk_route` with `bias` and `scale`) or
+    "softmax" (`softmax_topk_route`, which takes neither). Returns (y
+    [T, h], chosen [T, k], the counts of assignments per router output [R]
+    int32)."""
+    if router == "softmax":
+        chosen, weights = softmax_topk_route(x2, p["router_w"], top_k)
+    else:
+        chosen, weights = sigmoid_topk_route(x2, p["router_w"], bias, top_k,
+                                             scale)
     y = held_experts_ffn(x2, chosen, weights.astype(jnp.float32),
                          p["w_gate"], p["w_up"], p["w_down"], lo)
     with jax.named_scope("moe_router"):
@@ -353,22 +376,32 @@ def dropless_moe_val(x2, p: dict, bias, *, top_k: int, scale: float,
             R, dtype=jnp.int32)[None, :], axis=0, dtype=jnp.int32)
     if "shared_gate" in p:
         with jax.named_scope("mlp"):
-            y = y + swiglu(x2, p["shared_gate"], p["shared_up"],
-                           p["shared_down"])
+            shared = swiglu(x2, p["shared_gate"], p["shared_up"],
+                            p["shared_down"])
+            if "shared_gate_w" in p:
+                gate = jax.nn.sigmoid(
+                    x2.astype(jnp.float32)
+                    @ p["shared_gate_w"].astype(jnp.float32))
+                shared = (shared.astype(jnp.float32) * gate).astype(x2.dtype)
+            y = y + shared
     return y, chosen, counts
 
 
 class DroplessMoELayer(Layer):
-    """One chip's share of a DeepSeek-V3 style expert layer.
+    """One chip's share of an expert layer, DeepSeek-V3 style or, with
+    `router="softmax"`, Qwen3-Next style.
 
     `router_outputs` experts exist (R); this layer holds those in
-    `experts_held` = [lo, hi). Every token is routed over all R (sigmoid
-    scores, top `experts_per_token` of score + selection bias, weights
-    normalised and scaled by `routed_scaling`), and the layer returns the
-    part of the result its own experts give, plus the shared expert's
-    (one SwiGLU of `shared_width`, 0 for none). What the absent experts
-    would add is left out; held on one chip the layer runs without the
-    exchange that brings other chips' tokens.
+    `experts_held` = [lo, hi). Every token is routed over all R (`router`
+    "sigmoid": sigmoid scores, top `experts_per_token` of score +
+    selection bias, weights normalised and scaled by `routed_scaling`;
+    "softmax": softmax over all R, the top `experts_per_token`, weights
+    renormalised, the bias unused and never moved, `routed_scaling` 1), and
+    the layer returns the part of the result its own experts give, plus the
+    shared expert's (one SwiGLU of `shared_width`, 0 for none; with
+    `shared_gated` multiplied by sigmoid(x w_sg), one number a token). What
+    the absent experts would add is left out; held on one chip the layer
+    runs without the exchange that brings other chips' tokens.
 
     Two buffers ride through jit.TrainStep the way batch-norm statistics
     do: `select_bias` [R] float32, moved after each training forward by
@@ -392,7 +425,8 @@ class DroplessMoELayer(Layer):
     def __init__(self, hidden_size, expert_width, router_outputs,
                  experts_per_token, experts_held=None, shared_width=0,
                  routed_scaling=1.0, bias_speed=0.001, init_std=0.02,
-                 seed=0, dtype="float32", rs=None):
+                 seed=0, dtype="float32", rs=None, router="sigmoid",
+                 shared_gated=False):
         """`rs`: a numpy Generator to draw the weights from, in place of
         one made from `seed` (a model draws all its layers from one)."""
         super().__init__()
@@ -403,6 +437,14 @@ class DroplessMoELayer(Layer):
         if not 0 <= lo < hi <= router_outputs:
             raise ValueError(f"experts_held {experts_held} is no range of "
                              f"the router's {router_outputs} outputs")
+        if router not in ("sigmoid", "softmax"):
+            raise ValueError(f"router {router!r}: sigmoid or softmax")
+        if router == "softmax" and routed_scaling != 1.0:
+            raise ValueError(f"routed_scaling {routed_scaling}: softmax "
+                             f"routing has no scale")
+        if shared_gated and not shared_width:
+            raise ValueError("shared_gated: the layer has no shared expert")
+        self.router = router
         self.lo = int(lo)
         self.top_k = int(experts_per_token)
         self.routed_scaling = float(routed_scaling)
@@ -425,6 +467,9 @@ class DroplessMoELayer(Layer):
             self.shared_up = param(h, shared_width)
             self.shared_down = param(shared_width, h)
             self.names = self.PARAMS + self.SHARED
+        if shared_gated:
+            self.shared_gate_w = param(h, 1)
+            self.names = self.names + ("shared_gate_w",)
         self.register_buffer("select_bias", Tensor(
             np.zeros(router_outputs, np.float32)))
         self.register_buffer("assign_count", Tensor(
@@ -439,7 +484,7 @@ class DroplessMoELayer(Layer):
         traced block (a decoder layer under jax.checkpoint)."""
         return dropless_moe_val(x2, dict(zip(self.names, pvals)), bias,
                                 top_k=self.top_k, scale=self.routed_scaling,
-                                lo=self.lo)
+                                lo=self.lo, router=self.router)
 
     def advance(self, chosen, counts):
         """Keep the router's choice; in training add the step's counts and
@@ -455,5 +500,7 @@ class DroplessMoELayer(Layer):
         self.assign_count._value = self.assign_count._value + counts
         self.touched_count._value = self.touched_count._value \
             + self.rows_touched
+        if self.router == "softmax":       # no bias selects: none moves
+            return
         self.select_bias._value = self.select_bias._value \
             + self.bias_speed * jnp.sign(jnp.mean(c) - c)

@@ -13,6 +13,9 @@ from .gpt import (  # noqa: F401
 from .mla_moe import (  # noqa: F401
     MlaMoeConfig, MlaMoeForCausalLM, MlaMoeModel,
 )
+from .gdn_moe import (  # noqa: F401
+    GdnMoeConfig, GdnMoeForCausalLM, GdnMoeModel,
+)
 from .bert import (  # noqa: F401
     BertConfig, BertForPretraining, BertModel, BertPretrainingCriterion,
     bert_presets,

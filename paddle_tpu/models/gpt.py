@@ -189,24 +189,29 @@ def _attention_val(q, k, v, cfg: GPTConfig):
 
 
 def _local_attention_val(q, k, v, use_flash: bool):
-    """Causal attention over the whole sequence on this device: q, k
-    [b, s, n, d_qk], v [b, s, n, d_v]. The Pallas flash kernel on a TPU
+    """Causal attention over the whole sequence on this device: q
+    [b, s, n, d_qk], k [b, s, n_kv, d_qk], v [b, s, n_kv, d_v], query head
+    h reading k / v head h // (n // n_kv). The Pallas flash kernel on a TPU
     where its shape gate allows, else the O(s^2) einsum with a float32
     softmax."""
     from ..framework.target import target_platform
 
+    n, n_kv = q.shape[2], k.shape[2]
     if use_flash and target_platform() == "tpu":
         from ..ops.flash_attention import flash_attention_sharded_ok
 
-        if flash_attention_sharded_ok(q.shape):
+        if flash_attention_sharded_ok(q.shape, n_kv):
             return _flash_sharded(q, k, v)
-    scale = 1.0 / math.sqrt(q.shape[-1])
-    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
-    ql, kl = logits.shape[-2], logits.shape[-1]
+    # the query heads as [n_kv, group], so that k and v are used as they are
+    b, ql, _, d = q.shape
+    qg = q.reshape(b, ql, n_kv, n // n_kv, d)
+    logits = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k) * (1.0 / math.sqrt(d))
+    kl = logits.shape[-1]
     causal = jnp.tril(jnp.ones((ql, kl), dtype=bool), k=kl - ql)
     logits = jnp.where(causal, logits, jnp.finfo(logits.dtype).min)
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1).astype(v.dtype)
-    return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+    ctx = jnp.einsum("bhgqk,bkhd->bqhgd", probs, v)
+    return ctx.reshape(b, ql, n, v.shape[-1])
 
 
 def _block_apply(pd: dict, x, cfg: GPTConfig):

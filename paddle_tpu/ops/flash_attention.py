@@ -23,6 +23,14 @@ Layout is [b, n, s, d] inside the kernels (head-major, contiguous (s, d)
 tiles per grid cell); the public entry takes the model's [b, s, n, d] and
 transposes (XLA fuses the transposes into the surrounding program).
 
+Grouped heads: k and v may have fewer heads than q (n_kv dividing n).
+Query head h reads k / v head h // (n // n_kv) through the BlockSpecs'
+index maps, forward and backward: no copy of k or v at n heads exists in
+HBM. The backward writes dk and dv per QUERY head and the heads of a group
+are summed outside the kernel, in float32 (the dq accumulator spans one
+head's sequence, so a group's heads cannot share a kernel instance). With
+n_kv == n the index maps and the kernels are the ones they were.
+
 q and k share one width d_qk and v has its own d_v (latent attention's
 heads are 192 / 128 wide): the scores contract d_qk and are scaled by
 1/sqrt(d_qk); o, dO, dv and the forward accumulator are d_v wide, dq and dk
@@ -35,7 +43,8 @@ are made once a block pair and feed all three gradients. dk / dv
 accumulate in a scratch of one kv block; dq accumulates over the kv axis in
 a float32 scratch that spans the head's whole sequence, which the grid's
 order allows because it finishes one head before the next and a head's dq
-is small against VMEM (6.3 MB at s = 8192, 192 wide). The VMEM limit
+is small against VMEM (6.3 MB at s = 8192, 192 wide; 8.4 MB at 256). The
+VMEM limit
 follows the shapes (_bwd_vmem_bytes) and flash_attention_supported refuses
 a sequence whose accumulator would not fit (_DQ_ACC_BYTES). delta =
 rowsum(dO * O) is precomputed outside (one fused elementwise pass). The
@@ -93,7 +102,19 @@ def _default_block(d: int, dtype, d_v: int = None) -> int:
     1024x2048 - / 2.12 / 15.2; 2048x2048, which its computed VMEM limit
     lets compile, - / 2.20 / 15.1. Inside the step 512x512 reads 0.3 ms
     under 1024x1024 at s=1024 and 1.0 ms over it at s=2048
-    (`flash_bwd_ms_step`): one pair for both kernels and all shapes."""
+    (`flash_bwd_ms_step`): one pair for both kernels and all shapes.
+    Grouped heads 256 wide in bf16 (PR 33): a 512-byte row sits on the
+    rule's edge and takes 1024, the largest tiles these kernels have (the
+    TPU compiler takes forward and backward at 16 over 2 heads, s=8192,
+    without the chip: tests/test_tpu_aot.py). What the chip said at 16
+    query heads over 2 key/value heads, s=8192, inside the step of the
+    qwen3_next cell (traced run, PR 33): the forward 3.84 and 3.99 ms a
+    call, the one backward 8.68, together 50.7 % of their roofline
+    (`flash_gqa_roofline`; 37 % at 192 / 128): the widest head is the
+    kernels' best shape. Against the float32 reference there, through the
+    group's sum of dk and dv: the layer's q, k, v weight gradients within
+    0.7 % of their norms, as every bf16 matrix of the block. 512 was not
+    measured at this width."""
     return 1024 if max(d, d_v or d) * jnp.dtype(dtype).itemsize <= 512 \
         else 512
 
@@ -248,9 +269,18 @@ def _sds(shape, dtype, like):
     return jax.ShapeDtypeStruct(shape, dtype, vma=jax.typeof(like).vma)
 
 
+def _kv_head(n: int, n_kv: int):
+    """The k / v head a query head reads, for the index maps: query head
+    hi reads head hi // (n // n_kv). With as many k / v heads as query
+    heads the map is the identity it always was."""
+    group = n // n_kv
+    return (lambda hi: hi) if group == 1 else (lambda hi: hi // group)
+
+
 def _fwd(q, k, v, causal, block_q, block_k):
     b, n, s, d = q.shape
     d_v = v.shape[-1]
+    kvh = _kv_head(n, k.shape[1])
     grid = (b, n, s // block_q, s // block_k)
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=1.0 / math.sqrt(d),
@@ -260,9 +290,9 @@ def _fwd(q, k, v, causal, block_q, block_k):
             pl.BlockSpec((1, 1, block_q, d),
                          lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
             pl.BlockSpec((1, 1, block_k, d),
-                         lambda bi, hi, qi, ki: (bi, hi, ki, 0)),
+                         lambda bi, hi, qi, ki: (bi, kvh(hi), ki, 0)),
             pl.BlockSpec((1, 1, block_k, d_v),
-                         lambda bi, hi, qi, ki: (bi, hi, ki, 0)),
+                         lambda bi, hi, qi, ki: (bi, kvh(hi), ki, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, block_q, d_v),
@@ -396,19 +426,25 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 def _bwd(q, k, v, out, lse, do, causal, block_q, block_k):
     b, n, s, d = q.shape
     d_v = v.shape[-1]
+    n_kv = k.shape[1]
+    kvh = _kv_head(n, n_kv)
     nq = s // block_q
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1)                                     # (b, n, s)
 
-    def tile(rows, width, on_q):
+    def tile(rows, width, on_q, head=lambda hi: hi):
         """A (1, 1, rows, width) tile that follows the q block (`on_q`) or
-        the kv block."""
+        the kv block, of the head `head` maps the query head to."""
         return pl.BlockSpec((1, 1, rows, width),
                             lambda bi, hi, ki, qi: (
-                                bi, hi, qi if on_q else ki, 0))
+                                bi, head(hi), qi if on_q else ki, 0))
 
     qb, dob = tile(block_q, d, True), tile(block_q, d_v, True)
-    kb, vb = tile(block_k, d, False), tile(block_k, d_v, False)
+    kb, vb = tile(block_k, d, False, kvh), tile(block_k, d_v, False, kvh)
+    # dk and dv leave the kernel per QUERY head (grouped heads: the heads
+    # of one group are summed below); with one k / v head a query head the
+    # tiles are the inputs' own
+    dkb, dvb = tile(block_k, d, False), tile(block_k, d_v, False)
     # lse and delta go in as one (1, BQ) row per q block: a block that
     # spans its array's last two dims whole is legal for every block_q,
     # and so is the head's dq^T as (nq, d, BQ), which the kernel indexes
@@ -423,7 +459,7 @@ def _bwd(q, k, v, out, lse, do, causal, block_q, block_k):
                           causal=causal, block_q=block_q, block_k=block_k),
         grid=(b, n, s // block_k, nq),
         in_specs=[qb, kb, vb, dob, rowb, rowb],
-        out_specs=[dqb, kb, vb],
+        out_specs=[dqb, dkb, dvb],
         out_shape=[_sds((b, n, nq, d, block_q), q.dtype, q),
                    _sds((b, n, s, d), k.dtype, k),
                    _sds((b, n, s, d_v), v.dtype, v)],
@@ -438,6 +474,14 @@ def _bwd(q, k, v, out, lse, do, causal, block_q, block_k):
         # backward by (benchmark/layer_metrics/flash_bwd_ms_step.json)
         name="flash_bwd_dkv",
     )(q, k, v, do, lse.reshape(rows), delta.reshape(rows))
+    if n_kv != n:
+        # a k / v head's gradient is the sum over the query heads that read
+        # it, made in float32 from the kernel's per-head parts
+        def over_group(g):
+            parts = g.reshape(b, n_kv, n // n_kv, s, g.shape[-1])
+            return jnp.sum(parts.astype(jnp.float32), axis=2).astype(g.dtype)
+
+        dk, dv = over_group(dk), over_group(dv)
     # dq left the kernel as (nq, d, BQ) a head: XLA folds this transpose
     # into the [b, n, s, d] -> [b, s, n, d] one that follows
     return jnp.swapaxes(dq, -1, -2).reshape(b, n, s, d), dk, dv
@@ -468,8 +512,11 @@ _flash_bnsd.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 def flash_attention_val(q, k, v, causal=True, block_size=None,
                         block_q=None, block_k=None):
-    """Causal flash attention on q, k [b, s, n, d_qk] and v [b, s, n, d_v]
-    → [b, s, n, d_v], the scores scaled by 1/sqrt(d_qk).
+    """Causal flash attention on q [b, s, n, d_qk], k [b, s, n_kv, d_qk]
+    and v [b, s, n_kv, d_v] → [b, s, n, d_v], the scores scaled by
+    1/sqrt(d_qk). Grouped heads: n_kv divides n and query head h reads
+    k / v head h // (n // n_kv), by the kernels' index maps: no copy of k
+    or v at n heads exists in HBM.
 
     Value-level (raw jax arrays); Tensor-level wrappers live in
     nn/functional/flash_attention.py. Fallback is the caller's job —
@@ -485,10 +532,16 @@ def flash_attention_val(q, k, v, causal=True, block_size=None,
     """
     b, s, n, d = q.shape
     d_v = int(v.shape[-1])
-    if k.shape != q.shape or v.shape[:3] != q.shape[:3]:
+    n_kv = int(k.shape[2]) if k.ndim == 4 else 0
+    if k.shape != (b, s, n_kv, d) or v.shape[:3] != k.shape[:3]:
         raise ValueError(
-            f"flash attention: q {q.shape} and k {k.shape} must agree, and "
-            f"v {v.shape} with them in all but the head size")
+            f"flash attention: q {q.shape} and k {k.shape} must agree in "
+            f"all but the head count, and v {v.shape} with k in all but "
+            f"the head size")
+    if n_kv < 1 or n % n_kv:
+        raise ValueError(
+            f"flash attention: {n} query heads are no whole groups over "
+            f"{n_kv} key/value heads")
     if not _dq_acc_fits(s, d):
         raise ValueError(
             f"flash attention: the backward keeps a head's dq in VMEM, and "
@@ -515,7 +568,7 @@ def flash_attention_val(q, k, v, causal=True, block_size=None,
     return jnp.transpose(out, (0, 2, 1, 3))
 
 
-def _mesh_flash_specs(shape):
+def _mesh_flash_specs(shape, n_kv: int = None):
     """(mesh_active, mesh, PartitionSpec) for running the kernel under the
     ambient framework mesh. mesh_active False → call directly (no mesh);
     True with spec None → a mesh IS active but the shape is unshardable
@@ -539,17 +592,21 @@ def _mesh_flash_specs(shape):
     for a in batch_axes:
         bdeg *= m.shape[a]
     ndeg = m.shape[head_ax] if head_ax else 1
-    if b % bdeg or n % ndeg:
+    if b % bdeg or n % ndeg or (n_kv or n) % ndeg:
         return True, None, None  # unshardable shape under this mesh
     if not flash_attention_supported((b // bdeg, s, n // ndeg, d)):
         return True, None, None  # per-shard shape defeats the kernel
     return True, m, P(batch_axes or None, None, head_ax, None)
 
 
-def flash_attention_sharded_ok(shape) -> bool:
-    """Can flash_attention_val_auto run this [b, s, n, d] shape — on the
-    ambient mesh if one is active, directly otherwise?"""
-    active, mesh, _spec = _mesh_flash_specs(tuple(shape))
+def flash_attention_sharded_ok(shape, n_kv: int = None) -> bool:
+    """Can flash_attention_val_auto run this [b, s, n, d] shape (k and v
+    with `n_kv` heads; None: n) — on the ambient mesh if one is active,
+    directly otherwise?"""
+    n = int(shape[2]) if len(shape) == 4 else 0
+    if n_kv is not None and (n_kv < 1 or n % n_kv):
+        return False
+    active, mesh, _spec = _mesh_flash_specs(tuple(shape), n_kv)
     if not active:
         return flash_attention_supported(tuple(shape))
     return mesh is not None
@@ -561,7 +618,7 @@ def flash_attention_val_auto(q, k, v, causal=True, block_size=None):
     sees an unpartitionable Mosaic call. Check flash_attention_sharded_ok
     first; raises ValueError (not an opaque Mosaic compile crash) when a
     mesh is active but the shape cannot be sharded onto it."""
-    active, mesh, spec = _mesh_flash_specs(q.shape)
+    active, mesh, spec = _mesh_flash_specs(q.shape, int(k.shape[2]))
     if not active:
         return flash_attention_val(q, k, v, causal=causal,
                                    block_size=block_size)

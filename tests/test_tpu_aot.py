@@ -135,6 +135,43 @@ q = SDS((1, s_max, 1, 128), f32)
 compile_for_one_chip(all_grads, q, q, q)
 print("FLASH-MLA-OK")
 
+# grouped heads 256 wide in bf16 at 8,192 tokens (16 query heads over 2
+# key/value heads, the qwen3_next configuration's; and all of a call's
+# query heads over one key/value head): a 512-byte row takes `_default_block`'s 1024, the largest tiles these kernels have
+# (forward 0.5 MB of q and 1 MB of k, v a step, an 8.4 MB dq accumulator a
+# head); k and v go in at their own head count, by the index maps
+for n, n_kv in ((16, 2), (8, 1)):
+    q, k = SDS((1, 8192, n, 256), bf16), SDS((1, 8192, n_kv, 256), bf16)
+    assert fa.flash_attention_supported(q.shape)
+    text = compile_for_one_chip(all_grads, q, k, k).as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2, \
+        text[:2000]
+    dq, dk, dv = jax.eval_shape(all_grads, q, k, k)
+    assert dq.shape == q.shape and dk.shape == dv.shape == k.shape
+print("FLASH-GQA-OK")
+
+# the chunked gated delta rule, forward and backward, at the qwen3_next
+# cell's shape (8,192 tokens, 16 key heads serving 32 value heads, 128
+# wide): an XLA program, no kernel. What is held for the whole sequence is
+# the inputs, T and one state a chunk: with U, W and the decayed copies of
+# Q and K made for all chunks ahead of the scan the temporaries were over
+# a gigabyte more (0.76 GB as it is)
+from paddle_tpu.ops.gated_delta_rule import gated_delta_rule_chunked
+
+
+def rule_grads(q, k, v, g, beta):
+    return jax.grad(
+        lambda *a: jnp.sum(gated_delta_rule_chunked(*a).astype(f32)),
+        argnums=(0, 1, 2, 3, 4))(q, k, v, g, beta)
+
+
+qk, gb = SDS((1, 8192, 16, 128), f32), SDS((1, 8192, 32), f32)
+compiled = compile_for_one_chip(rule_grads, qk, qk,
+                                SDS((1, 8192, 32, 128), bf16), gb, gb)
+temp = compiled.memory_analysis().temp_size_in_bytes
+assert temp < 1.2e9, temp
+print("GDN-RULE-OK")
+
 for k, n in ((768, 3072), (2048, 8192), (3072, 768), (8192, 2048)):
     for stochastic in (False, True):
         mosaic(lambda w: quantize_int8(w, stochastic=stochastic, seed=3),
@@ -211,7 +248,8 @@ def test_trainstep_with_flash_compiles_for_tpu():
 
 def test_pallas_families_compile_by_mosaic():
     out = _run_child(KERNELS_CHILD)
-    for tag in ("FLASH-OK", "FLASH-NAMES-OK", "FLASH-MLA-OK", "QUANTIZE-OK",
+    for tag in ("FLASH-OK", "FLASH-NAMES-OK", "FLASH-MLA-OK", "FLASH-GQA-OK",
+                "GDN-RULE-OK", "QUANTIZE-OK",
                 "QMM-OK", "CODEC-OK", "FUSED-UPDATE-OK"):
         assert tag in out, out[-2000:]
 
