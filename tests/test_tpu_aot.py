@@ -152,10 +152,21 @@ print("FLASH-GQA-OK")
 
 # the chunked gated delta rule, forward and backward, at the qwen3_next
 # cell's shape (8,192 tokens, 16 key heads serving 32 value heads, 128
-# wide): an XLA program, no kernel. What is held for the whole sequence is
-# the inputs, T and one state a chunk: with U, W and the decayed copies of
-# Q and K made for all chunks ahead of the scan the temporaries were over
-# a gigabyte more (0.76 GB as it is)
+# wide, v in bf16): compiled for a TPU the chunks follow one another in the
+# two Pallas kernels gdn_chunk_fwd / gdn_chunk_bwd (eight heads a grid
+# step; the backward's scoped VMEM is 16.1 MB by the compiler's count, over
+# the 16 MB default, so the call sets its limit), the solve around them is
+# XLA. What is held for
+# the whole sequence is the inputs, T and one state a chunk: 1.16 GB of
+# temporaries by the compiler's count (0.76 GB with the scan; the kernels
+# hand back dT, dq and dk per value head and o in float32 as whole arrays,
+# where the scan's backward made them a chunk at a time. On the chip the
+# step's peak FELL, 7.115 -> 7.089 GB: PERF.md, PR 34).
+# And at the shape and in the context of the benchmark's comparison (d):
+# one value head a key head, v in float32, under
+# default_matmul_precision("highest"), which the kernels' bfloat16 passes
+# must not inherit (Mosaic: "Bad lhs type")
+from paddle_tpu.observability import get_registry
 from paddle_tpu.ops.gated_delta_rule import gated_delta_rule_chunked
 
 
@@ -165,11 +176,25 @@ def rule_grads(q, k, v, g, beta):
         argnums=(0, 1, 2, 3, 4))(q, k, v, g, beta)
 
 
-qk, gb = SDS((1, 8192, 16, 128), f32), SDS((1, 8192, 32), f32)
-compiled = compile_for_one_chip(rule_grads, qk, qk,
-                                SDS((1, 8192, 32, 128), bf16), gb, gb)
-temp = compiled.memory_analysis().temp_size_in_bytes
-assert temp < 1.2e9, temp
+def rule_dispatched(source):
+    return get_registry().snapshot().get("kernel_dispatch_total", {}).get(
+        "kernel=gated_delta_rule,source=" + source, 0)
+
+
+gb = SDS((1, 8192, 32), f32)
+for n_key, values, precision, most in ((16, bf16, None, 1.2e9),
+                                        (32, f32, "highest", 1.25e9)):
+    qk = SDS((1, 8192, n_key, 128), f32)
+    with jax.default_matmul_precision(precision):
+        compiled = compile_for_one_chip(
+            rule_grads, qk, qk, SDS((1, 8192, 32, 128), values), gb, gb)
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') >= 2, \
+        text[:2000]
+    assert "gdn_chunk_fwd" in text and "gdn_chunk_bwd" in text
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < most, temp
+assert rule_dispatched("kernel") == 2 and rule_dispatched("scan") == 0
 print("GDN-RULE-OK")
 
 for k, n in ((768, 3072), (2048, 8192), (3072, 768), (8192, 2048)):
