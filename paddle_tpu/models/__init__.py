@@ -16,6 +16,9 @@ from .mla_moe import (  # noqa: F401
 from .gdn_moe import (  # noqa: F401
     GdnMoeConfig, GdnMoeForCausalLM, GdnMoeModel,
 )
+from .lfm2_moe import (  # noqa: F401
+    Lfm2MoeConfig, Lfm2MoeForCausalLM, Lfm2MoeModel,
+)
 from .bert import (  # noqa: F401
     BertConfig, BertForPretraining, BertModel, BertPretrainingCriterion,
     bert_presets,

@@ -194,15 +194,20 @@ def gated_attention(x, p: dict, cfg: GdnMoeConfig):
     return ctx.reshape(b, s, n * d) @ p["o_w"]
 
 
-def causal_conv_silu(x, w):
-    """silu of the causal depthwise convolution of x [b, s, ch] with w
+def causal_taps(x, w):
+    """The causal depthwise convolution of x [b, s, ch] float32 with w
     [ch, taps]: y_t = sum_j w[:, j] * x_{t - (taps - 1) + j}, zeros left of
-    the start. The sum and the SiLU in float32, the result in x's dtype."""
+    the start. No bias and no activation. Float32 in and out."""
     taps, s = w.shape[1], x.shape[1]
-    xf = jnp.pad(x.astype(jnp.float32), ((0, 0), (taps - 1, 0), (0, 0)))
+    xf = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
     wf = w.astype(jnp.float32)
-    y = sum(xf[:, j:j + s] * wf[:, j] for j in range(taps))
-    return jax.nn.silu(y).astype(x.dtype)
+    return sum(xf[:, j:j + s] * wf[:, j] for j in range(taps))
+
+
+def causal_conv_silu(x, w):
+    """silu of `causal_taps` of x [b, s, ch] with w [ch, taps]. The sum and
+    the SiLU in float32, the result in x's dtype."""
+    return jax.nn.silu(causal_taps(x.astype(jnp.float32), w)).astype(x.dtype)
 
 
 def _l2_norm(x, eps=1e-6):

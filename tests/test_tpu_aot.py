@@ -148,6 +148,13 @@ for n, n_kv in ((16, 2), (8, 1)):
         text[:2000]
     dq, dk, dv = jax.eval_shape(all_grads, q, k, k)
     assert dq.shape == q.shape and dk.shape == dv.shape == k.shape
+# ... and 64 wide at two sequences of 8,192 tokens, 32 query heads over 8
+# key/value heads: the lfm2_moe cell's attention layer (the narrowest head
+# with groups, at the longest sequence the 64-wide kernels have seen)
+q, k = SDS((2, 8192, 32, 64), bf16), SDS((2, 8192, 8, 64), bf16)
+assert fa.flash_attention_supported(q.shape)
+text = compile_for_one_chip(all_grads, q, k, k).as_text()
+assert text.count('custom_call_target="tpu_custom_call"') == 2, text[:2000]
 print("FLASH-GQA-OK")
 
 # the chunked gated delta rule, forward and backward, at the qwen3_next
