@@ -21,7 +21,9 @@ of them (sigmoid scores and a selection bias, DeepSeek-V3 style, or a
 softmax over all outputs with the chosen weights renormalised), and
 computes its own experts' part for every token routed to them as grouped
 matrix products over rows sorted by expert: no capacity, no dropped
-token, no one-hot.
+token, no one-hot. Dispatch and combine are a pair of custom_vjp
+primitives over k-major assignments whose residuals and backward passes
+stay on the sorted side (`held_experts_ffn`).
 """
 from __future__ import annotations
 
@@ -185,54 +187,73 @@ def _take_rows(v, idx):
     return v.at[idx].get(mode="promise_in_bounds")
 
 
+def _over_row_blocks(n_rows: int, live, step, carry):
+    """carry = step(start, block, carry) for the blocks of `block` rows
+    from `start` that hold the first `live` of `n_rows` sorted rows: a loop
+    whose trip count the device computes from `live`, so a pass written as
+    one costs what the step's held experts were given and not T*k. The last
+    block of a buffer that is no whole number of blocks starts early and
+    does some rows a second time, alike."""
+    block, trips = _row_blocks(live, n_rows)
+    return jax.lax.fori_loop(
+        0, trips, lambda i, c: step(
+            jnp.minimum(i * block, n_rows - block), block, c), carry)
+
+
 def _sorted_rows(src, idx, live):
-    """Row a of the result is src[idx[a]] for every a < rows_covered(live):
-    a loop over blocks of rows whose trip count the device computes from
-    `live`, so the pass costs what the step's held experts were given and
-    not T*k. The rows past that are zero and stand for NOTHING: what reads
-    a sorted buffer stops at the last group (the grouped products) or drops
-    the tail where it consumes it (`_live_rows_by_token`)."""
+    """Row a of the result is src[idx[a]] for every a < rows_covered(live)
+    (`_over_row_blocks`). The rows past that are zero and stand for
+    NOTHING: what reads a sorted buffer stops at the last group (the
+    grouped products) or drops the tail where it consumes it
+    (`_live_rows`)."""
     n = idx.shape[0]
-    block, trips = _row_blocks(live, n)
     # XLA sinks a fusible producer of a loop's operand into the loop's body
     # (seen compiling for the v5e: the whole [T*k, h] cotangent was made
     # again in every trip); behind a barrier it is made once
     src = jax.lax.optimization_barrier(src)
 
-    def move(i, out):
-        # the last block of a buffer that is no whole number of blocks
-        # starts early and writes some rows a second time, alike
-        start = jnp.minimum(i * block, n - block)
+    def move(start, block, out):
         rows = _take_rows(src, jax.lax.dynamic_slice(idx, (start,), (block,)))
         return jax.lax.dynamic_update_slice(out, rows, (start, 0))
 
-    return jax.lax.fori_loop(0, trips, move,
-                             jnp.zeros((n,) + src.shape[1:], src.dtype))
+    return _over_row_blocks(n, live, move,
+                            jnp.zeros((n,) + src.shape[1:], src.dtype))
+
+
+def _live_rows(rows, at, live):
+    """`rows` [n, ...] where they stand for the sorted rows `at` [n] that
+    belong to held experts (at < live), zero where they stand for the
+    tail's. This is where the tail is dropped, everywhere it is consumed,
+    inside the pass that consumes it and not in one of its own. A `where`
+    and never a multiply: XLA's grouped-matmul kernel on the TPU leaves the
+    rows past the last group UNWRITTEN (5.2 was read there on the v5e, PR
+    27; NaN is as likely; the CPU lowering zeroes them), and 0 * NaN is
+    NaN."""
+    keep = (at < live).reshape(at.shape + (1,) * (rows.ndim - 1))
+    return jnp.where(keep, rows, jnp.zeros((), rows.dtype))
 
 
 def _live_rows_by_token(rows, inv, live):
-    """Row t*k + j of the result is the sorted row inv[t*k + j] of token
+    """Row j*T + t of the result is the sorted row inv[j*T + t] of token
     t's j-th assignment, zero where that went to an absent expert (a sorted
-    row at or past `live`). This is where the tail is dropped, in both
-    directions: on the gathered rows, inside the sum over a token's k
-    assignments that consumes them, not in a pass of its own. A `where` and
-    never a multiply: XLA's grouped-matmul kernel on the TPU leaves the rows
-    past the last group UNWRITTEN (5.2 was read there on the v5e, PR 27;
-    NaN is as likely; the CPU lowering zeroes them), and 0 * NaN is NaN."""
-    got = _take_rows(rows, inv)
-    return jnp.where((inv < live)[:, None], got, jnp.zeros((), got.dtype))
+    row at or past `live`: `_live_rows`). The one token-side pass over
+    T*k rows each direction has: the forward of combine and the backward
+    of dispatch, each summing the k slabs [T, h] it returns."""
+    return _live_rows(_take_rows(rows, inv), inv, live)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
 def _rows_by_expert(x2, order, inv, live, k: int):
-    """Dispatch: row a of the result is token order[a] // k of x2 [T, h],
-    for the `live` rows that belong to held experts (rounded up to a block;
-    the tail has no value, see `_sorted_rows`). The transpose of this
-    gather is a scatter-add; `inv` (order's inverse permutation) turns it
-    into a gather and a sum over each token's k assignments, which the TPU
+    """Dispatch: row a of the result is token order[a] % T of x2 [T, h]
+    (the T*k assignments are k-major: flat index j*T + t), for the `live`
+    rows that belong to held experts (rounded up to a block; the tail has
+    no value, see `_sorted_rows`). The transpose of this gather is a
+    scatter-add; `inv` (order's inverse permutation) turns it into a gather
+    of [k*T, h] by token and a sum of its k slabs [T, h], which the TPU
     does at memory speed. The cotangent's tail comes out of the grouped
-    products unwritten and is dropped in that sum (`_live_rows_by_token`)."""
-    return _sorted_rows(x2, order // k, live)
+    products unwritten and is dropped on the gathered rows
+    (`_live_rows_by_token`)."""
+    return _sorted_rows(x2, order % x2.shape[0], live)
 
 
 def _rows_by_expert_fwd(x2, order, inv, live, k):
@@ -241,36 +262,75 @@ def _rows_by_expert_fwd(x2, order, inv, live, k):
 
 def _rows_by_expert_bwd(k, res, g):
     order, inv, live = res
-    dx = _live_rows_by_token(g, inv, live).reshape(-1, k, g.shape[-1])
-    return (jnp.sum(dx.astype(jnp.float32), axis=1).astype(g.dtype),
-            _int_zero(order), _int_zero(inv), _int_zero(live))
+    # k-major: the reshape moves nothing and the sum over k is one of slabs
+    dx = jnp.sum(_live_rows_by_token(g, inv, live).reshape(
+        k, -1, g.shape[-1]), axis=0, dtype=jnp.float32)
+    return (dx.astype(g.dtype), _int_zero(order), _int_zero(inv),
+            _int_zero(live))
 
 
 _rows_by_expert.defvjp(_rows_by_expert_fwd, _rows_by_expert_bwd)
 
 
 @jax.custom_vjp
-def _rows_by_token(out, order, inv, live):
-    """Un-sort: row t*k + j of the result is the sorted row of token t's
-    j-th assignment, zero where that went to an absent expert: the tail the
-    grouped products leave unwritten is dropped here, on the gathered rows
-    (`_live_rows_by_token`). Forward gathers by `inv`; backward by `order`,
-    the live rows alone: the cotangent's tail needs no value, since only
-    the grouped products read it and they stop at the last group."""
-    return _live_rows_by_token(out, inv, live)
+def _weighted_rows_by_token(out, w, order, inv, live):
+    """Combine: y[t] = sum over j of w[j, t] * out[inv[j*T + t]], in
+    float32 and cast to out's dtype, `out` [k*T, h] the sorted rows the
+    grouped products wrote, `w` [k, T] float32; an assignment to an absent
+    expert (a sorted row at or past `live`, which nothing wrote) adds zero
+    (`_live_rows_by_token`). Forward: one gather of [k*T, h] by token and a
+    weighted sum of its k slabs.
+
+    The residuals are `out`, `w` and the permutation, NOT the gathered rows:
+    under a caller's jax.checkpoint the recomputed forward's gather then
+    feeds nothing and XLA drops it. Backward runs on the SORTED side and
+    covers rows_covered(live) rows, in one loop over blocks
+    (`_over_row_blocks`): for the sorted row a of token t = order[a] % T,
+    d out[a] = w_sorted[a] * dy[t] (gathered out of dy [T, h] itself) and
+    dw_sorted[a] = sum over h of out[a] * dy[t] in float32, the rows of
+    `out` at or past `live` dropped by index (`_live_rows`); then d w is
+    dw_sorted gathered by `inv`, T*k scalars. No token-side [T*k, h] array
+    is made: the one [k*T, h] buffer is d out, whose tail needs no value,
+    since only the grouped products read it and they stop at the last
+    group."""
+    k = w.shape[0]
+    sel = _live_rows_by_token(out, inv, live).reshape(k, -1, out.shape[-1])
+    y = jnp.sum(sel.astype(jnp.float32) * w[..., None], axis=0)
+    return y.astype(out.dtype)
 
 
-def _rows_by_token_fwd(out, order, inv, live):
-    return _rows_by_token(out, order, inv, live), (order, inv, live)
+def _weighted_rows_by_token_fwd(out, w, order, inv, live):
+    return (_weighted_rows_by_token(out, w, order, inv, live),
+            (out, w, order, inv, live))
 
 
-def _rows_by_token_bwd(res, g):
-    order, inv, live = res
-    return (_sorted_rows(g, order, live), _int_zero(order), _int_zero(inv),
-            _int_zero(live))
+def _weighted_rows_by_token_bwd(res, dy):
+    out, w, order, inv, live = res
+    n, T = order.shape[0], w.shape[1]
+    w_flat = w.reshape(-1)
+    # as in `_sorted_rows`: made once, not in every trip
+    dy, out = jax.lax.optimization_barrier((dy, out))
+
+    def rows(start, block, carry):
+        d_out, dw = carry
+        at = jax.lax.dynamic_slice(order, (start,), (block,))
+        g = _take_rows(dy, at % T).astype(jnp.float32)
+        d_rows = (_take_rows(w_flat, at)[:, None] * g).astype(out.dtype)
+        mine = _live_rows(
+            jax.lax.dynamic_slice(out, (start, 0), (block, out.shape[1])),
+            start + jnp.arange(block, dtype=jnp.int32), live)
+        dots = jnp.sum(mine.astype(jnp.float32) * g, axis=-1)
+        return (jax.lax.dynamic_update_slice(d_out, d_rows, (start, 0)),
+                jax.lax.dynamic_update_slice(dw, dots, (start,)))
+
+    d_out, dw = _over_row_blocks(n, live, rows, (
+        jnp.zeros_like(out), jnp.zeros((n,), jnp.float32)))
+    dw = _live_rows(_take_rows(dw, inv), inv, live).reshape(w.shape)
+    return d_out, dw, _int_zero(order), _int_zero(inv), _int_zero(live)
 
 
-_rows_by_token.defvjp(_rows_by_token_fwd, _rows_by_token_bwd)
+_weighted_rows_by_token.defvjp(_weighted_rows_by_token_fwd,
+                               _weighted_rows_by_token_bwd)
 
 
 def sigmoid_topk_route(x2, router_w, bias, top_k: int, scale: float):
@@ -308,20 +368,27 @@ def held_experts_ffn(x2, chosen, weights, w_gate, w_up, w_down, lo: int):
     leading size of the weights [E, h, f] / [E, f, h] (SwiGLU experts):
     y[t] = sum over t's chosen experts e held here of weights * E_e(x[t]).
 
-    The T*k assignments are sorted by expert; those to absent experts fall
-    in a tail behind the `live` rows of the held ones. No pass runs over
-    the sorted [T*k, h] buffers for longer than `live` asks: the gathers
-    that write them stop at rows_covered(live) (`_sorted_rows`), the
-    grouped products stop at the last group, and the gathers that read
-    them back by token drop the tail on the rows they gathered, with a
-    `where` since the tail may hold anything (`_live_rows_by_token`). Every
-    assignment to a held expert is computed whatever the load, from none to
-    all T*k: nothing is dropped and nothing is capped.
+    The T*k assignments, laid out k-major (flat index j*T + t, so a
+    [k*T, h] buffer by token is k slabs [T, h] and a sum over k a sum of
+    slabs: no array carries k in its tiled minor axes), are sorted by
+    expert; those to absent experts fall in a tail behind the `live` rows
+    of the held ones. Two token-side passes over T*k rows of h are left, a
+    gather each: the combine's forward and the dispatch's backward
+    (`_live_rows_by_token`), which drop the tail on the rows they gathered,
+    with a `where` since the tail may hold anything. Everything else runs on
+    the sorted side for no longer than `live` asks: the dispatch's forward
+    and the combine's WHOLE backward (d out and the weights' gradient,
+    `_weighted_rows_by_token`) are loops that stop at rows_covered(live),
+    the grouped products stop at the last group, and the combine keeps the
+    sorted rows and not the gathered ones, so a caller's jax.checkpoint
+    recomputes no token-side gather. Every assignment to a held expert is
+    computed whatever the load, from none to all T*k: nothing is dropped
+    and nothing is capped.
     """
-    T, k = chosen.shape
+    k = chosen.shape[1]
     E = w_gate.shape[0]
     with jax.named_scope("moe_dispatch"):
-        flat = chosen.reshape(-1)
+        flat = chosen.T.reshape(-1)                 # k-major: j*T + t
         held = (flat >= lo) & (flat < lo + E)
         local = jnp.where(held, flat - lo, E)
         order = jnp.argsort(local, stable=True).astype(jnp.int32)
@@ -339,10 +406,7 @@ def held_experts_ffn(x2, chosen, weights, w_gate, w_up, w_down, lo: int):
              * u.astype(jnp.float32)).astype(x2.dtype)
         out = jax.lax.ragged_dot(a, w_down, group_sizes)
     with jax.named_scope("moe_combine"):
-        # rows of assignments to absent experts come back zero
-        sel = _rows_by_token(out, order, inv, live).reshape(T, k, -1)
-        y = jnp.sum(sel.astype(jnp.float32) * weights[..., None], axis=1)
-        return y.astype(x2.dtype)
+        return _weighted_rows_by_token(out, weights.T, order, inv, live)
 
 
 def swiglu(x, w_gate, w_up, w_down):
@@ -409,7 +473,9 @@ class DroplessMoELayer(Layer):
     auxiliary-loss-free balancing of arXiv:2412.19437, 2.1.2), and
     `assign_count` [R] int32, the cumulative assignments per router output.
     A third counts work: `touched_count` int32, the cumulative sorted rows
-    the layer's passes covered (rows_covered of the held experts' rows), so
+    the layer's passes covered (rows_covered of the held experts' rows:
+    what the dispatch's forward writes and, since the combine's backward
+    runs on the sorted side too, what that reads and writes), so
     touched_count / (steps * T * k) is the share of the sorted buffer that
     was moved at all (read it as a difference: int32 wraps). After a forward `self.chosen` holds the chosen
     experts [T, k], the way MoELayer keeps `aux_loss`, for a comparison

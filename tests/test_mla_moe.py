@@ -347,8 +347,16 @@ def test_held_experts_equal_the_plain_formulation_at_every_load(live):
     chosen, args, cot = _assignments(live)
     got = _value_and_grads(moe.held_experts_ffn, chosen, args, cot)
     want = _value_and_grads(_plain_held_experts_ffn, chosen, args, cot)
+    # the layer sorts its assignments k-major (flat index j*T + t), the
+    # plain formulation token-major, so the rows of one expert's group stand
+    # in another order and a float32 sum over them rounds otherwise: hence
+    # the rtol, and an atol of a few float32 ulps of the output's LARGEST
+    # entry, since a sum that cancels keeps the error of its partial sums
+    # (at every row live d w_down, entries up to 114: the two differ by
+    # 8.4e-5 and are 3.1e-5 and 7.6e-5 off the same sum in float64)
     for name, g, w in zip(FFN_OUTPUTS, got, want):
-        np.testing.assert_allclose(g, w, atol=2e-5, err_msg=name)
+        atol = max(2e-5, 1e-6 * float(jnp.max(jnp.abs(w))))
+        np.testing.assert_allclose(g, w, atol=atol, rtol=1e-5, err_msg=name)
     # none of the held chosen: nothing comes back, nothing flows
     assert (float(jnp.max(jnp.abs(want[0]))) > 0.01) == (live > 0)
 
@@ -396,7 +404,22 @@ def test_a_poisoned_tail_reaches_no_output_and_no_gradient(live, monkeypatch):
         np.testing.assert_allclose(g, w, atol=1e-6, err_msg=name)
 
 
-def test_the_poison_is_felt_where_the_tail_is_multiplied_away(monkeypatch):
+def _multiplied_away(rows, at, live):
+    """`moe._live_rows` as it must not be: 0 * NaN is NaN."""
+    keep = (at < live).reshape(at.shape + (1,) * (rows.ndim - 1))
+    return rows * keep.astype(rows.dtype)
+
+
+# a name of `moe` -> a stand-in that drops the tail by a multiply
+TAIL_MUTANTS = {
+    "_live_rows_by_token": lambda rows, inv, live: _multiplied_away(
+        jnp.take(rows, inv, axis=0), inv, live),
+    "_live_rows": _multiplied_away}
+
+
+@pytest.mark.parametrize("name", sorted(TAIL_MUTANTS))
+def test_the_poison_is_felt_where_the_tail_is_multiplied_away(name,
+                                                              monkeypatch):
     """The guard guards: a combine that drops the tail by a multiply (0 *
     NaN) is caught by the poisoned product, and the whole layer, router and
     shared expert and all, is not."""
@@ -413,13 +436,104 @@ def test_the_poison_is_felt_where_the_tail_is_multiplied_away(monkeypatch):
     monkeypatch.setattr(jax.lax, "ragged_dot",
                         _ragged_dot_that_leaves_the_tail_unwritten())
     got = jax.grad(loss, argnums)(x2, *vals)
-    for name, g, w in zip(("x2",) + layer.names, got, want):
-        assert np.all(np.isfinite(g)), name
-        np.testing.assert_allclose(g, w, atol=1e-5, err_msg=name)
-    monkeypatch.setattr(
-        moe, "_live_rows_by_token", lambda rows, inv, live: jnp.take(
-            rows, inv, axis=0) * (inv < live)[:, None].astype(rows.dtype))
+    for name_, g, w in zip(("x2",) + layer.names, got, want):
+        assert np.all(np.isfinite(g)), name_
+        np.testing.assert_allclose(g, w, atol=1e-5, err_msg=name_)
+    monkeypatch.setattr(moe, name, TAIL_MUTANTS[name])
     assert not np.all(np.isfinite(loss(x2, *vals)))
+
+
+# ------------------------------------- the combine alone, on the sorted side
+COMBINE_CASES = [(live, k) for k in (4, 6, 10) for live in sorted(LIVE_CASES)]
+
+
+def _combine_inputs(live, k, seed=0, h=32):
+    """Arguments of moe._weighted_rows_by_token (out, w, order, inv, live)
+    and a cotangent: k assignments a token, k-major, over the fewest tokens
+    that give LIVE_CASES' 5,000 rows (5,004 at k = 6, where the last case
+    is every row), exactly `live` of them to held experts."""
+    T = -(-N_TOKENS * N_K // k)
+    n = T * k
+    live = n if live == N_TOKENS * N_K else live
+    r = np.random.RandomState(seed)
+    local = np.full(n, N_HELD)
+    local[r.permutation(n)[:live]] = r.randint(0, N_HELD, live)
+    order = np.argsort(local, kind="stable").astype(np.int32)
+    inv = np.empty_like(order)
+    inv[order] = np.arange(n, dtype=np.int32)
+    out, w, cot = (jnp.asarray(v, jnp.float32) for v in (
+        r.randn(n, h), r.uniform(0.1, 1.0, (k, T)), r.randn(T, h)))
+    return (out, w, jnp.asarray(order), jnp.asarray(inv),
+            jnp.int32(live)), cot
+
+
+def _plain_combine(out, w, order, inv, live):
+    """The combine in plain jnp, for jax's own transposes: the tail zeroed
+    in a pass of its own, every row gathered, a sum over k."""
+    rows = jnp.arange(out.shape[0])[:, None]
+    sel = jnp.take(jnp.where(rows < live, out, 0.0), inv, axis=0)
+    return jnp.sum(sel.reshape(w.shape + (-1,)) * w[..., None], axis=0)
+
+
+def _combine_and_grads(combine, args, cot):
+    """(y, d out, d w) of `combine` under the cotangent `cot`."""
+    out, w, *perm = args
+    y, vjp = jax.vjp(lambda o, w_: combine(o, w_, *perm), out, w)
+    return (y,) + vjp(cot)
+
+
+@pytest.mark.parametrize("live,k", COMBINE_CASES)
+def test_the_combine_equals_its_plain_formulation(live, k):
+    args, cot = _combine_inputs(live, k)
+    live = int(args[-1])
+    y, d_out, d_w = _combine_and_grads(moe._weighted_rows_by_token, args,
+                                       cot)
+    want_y, want_d_out, want_d_w = _combine_and_grads(_plain_combine, args,
+                                                      cot)
+    np.testing.assert_allclose(y, want_y, atol=2e-5, rtol=1e-5)
+    # the cotangent's rows past `live` have no value: the grouped products
+    # stop at the last group
+    np.testing.assert_allclose(d_out[:live], want_d_out[:live], atol=2e-5,
+                               rtol=1e-5)
+    np.testing.assert_allclose(d_w, want_d_w, atol=2e-5, rtol=1e-5)
+    assert (float(jnp.max(jnp.abs(want_d_w))) > 0.01) == (live > 0)
+
+
+@pytest.mark.parametrize("live,k", COMBINE_CASES)
+def test_an_assignment_to_an_absent_expert_has_a_zero_weight_gradient(live,
+                                                                      k):
+    args, cot = _combine_inputs(live, k, seed=1)
+    _, _, d_w = _combine_and_grads(moe._weighted_rows_by_token, args, cot)
+    absent = np.asarray(args[3] >= args[4]).reshape(d_w.shape)
+    assert absent.sum() == d_w.size - int(args[4])
+    assert np.all(np.asarray(d_w)[absent] == 0.0)
+    assert np.all(np.asarray(d_w)[~absent] != 0.0)
+
+
+@pytest.mark.parametrize("live,k", COMBINE_CASES)
+def test_a_poisoned_tail_reaches_neither_gradient_of_the_combine(
+        live, k, monkeypatch):
+    """`out`'s rows at or past `live` as the TPU's grouped product leaves
+    them: they reach neither the output nor d w (the sorted-side dot drops
+    them by index), and d out's covered rows never read them."""
+    (out, *rest), cot = _combine_inputs(live, k, seed=2)
+    live = int(rest[-1])
+    want = _combine_and_grads(moe._weighted_rows_by_token, (out, *rest), cot)
+    poisoned = out.at[live:].set(jnp.nan)
+    got = _combine_and_grads(moe._weighted_rows_by_token, (poisoned, *rest),
+                             cot)
+    covered = int(moe.rows_covered(live, out.shape[0]))
+    for name, g, w in zip(("y", "d out", "d w"), got, want):
+        if name == "d out":
+            g, w = g[:covered], w[:covered]
+        assert np.all(np.isfinite(g)), name
+        np.testing.assert_array_equal(g, w, err_msg=name)
+    # the guard guards: the dot with the tail multiplied away is caught
+    monkeypatch.setattr(moe, "_live_rows", _multiplied_away)
+    d_w = _combine_and_grads(moe._weighted_rows_by_token, (poisoned, *rest),
+                             cot)[2]
+    # (where the loop covers a row of the tail at all)
+    assert np.all(np.isfinite(d_w)) == (covered == live)
 
 
 @pytest.mark.parametrize("live", sorted(LIVE_CASES))

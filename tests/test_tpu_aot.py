@@ -286,6 +286,58 @@ def test_pallas_families_compile_by_mosaic():
         assert tag in out, out[-2000:]
 
 
+# One expert layer's recomputation and backward at the `qwen3_next` cell's
+# shapes, as the models run it (the whole layer under jax.checkpoint): what
+# the compiled program moves on the token side. The forward of a linear
+# loss's gradient is dead, so what compiles is the recomputed forward and
+# the backward. PR 36: the combine keeps its residuals and its backward on
+# the sorted side, so ONE gather of T*k rows is left (the dispatch's
+# backward; the recomputed combine's feeds nothing and is dropped), no
+# float32 cotangent of T*k rows is made, and with the assignments k-major
+# no array has k in its tiled minor two axes.
+EXPERT_LAYER_CHILD = r"""
+import re
+import sys
+sys.path.insert(0, %r)
+import jax
+import jax.numpy as jnp
+
+from paddle_tpu.distributed import moe
+from paddle_tpu.jit.aot import compile_for_one_chip
+
+SDS = jax.ShapeDtypeStruct
+f32, bf16 = jnp.float32, jnp.bfloat16
+T, k, h, E, f = 8192, 10, 2048, 32, 512
+
+
+def loss(x, weights, w_gate, w_up, w_down, chosen, cot):
+    layer = jax.checkpoint(lambda x_, *w: x_ + moe.held_experts_ffn(
+        x_, chosen, *w, 0))
+    y = layer(x, weights, w_gate, w_up, w_down)
+    return jnp.sum(y.astype(f32) * cot)
+
+
+text = compile_for_one_chip(
+    jax.grad(loss, argnums=(0, 1, 2, 3, 4)), SDS((T, h), bf16),
+    SDS((T, k), f32), SDS((E, h, f), bf16), SDS((E, h, f), bf16),
+    SDS((E, f, h), bf16), SDS((T, k), jnp.int32), SDS((T, h), f32)).as_text()
+gathers = re.findall(
+    r"= \w+\[%%d,%%d\]\S* gather\(.*op_name=\"([^\"]*)\"" %% (T * k, h), text)
+assert len(gathers) == 1, gathers
+# booked where the dispatch is; the combine's backward is the block loop
+assert "/moe_dispatch/" in gathers[0], gathers
+assert "/moe_combine/while/body/gather" in text
+assert "rematted_computation/moe_combine" not in text
+assert "f32[%%d,%%d]" %% (T * k, h) not in text
+assert "[%%d,%%d,%%d]" %% (T, k, h) not in text
+print("EXPERT-LAYER-OK")
+""" % REPO
+
+
+def test_the_expert_layer_backward_keeps_to_the_sorted_side():
+    assert "EXPERT-LAYER-OK" in _run_child(EXPERT_LAYER_CHILD)
+
+
 PLANNER_CHILD = r"""
 import sys
 sys.path.insert(0, %r)
