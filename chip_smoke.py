@@ -21,7 +21,8 @@ weights random from a seed), in ONE process:
 
 Every phase is a plain function that raises on failure. Stdout ends with two
 lines: `[result] {...}` (jax version, cache directory, per-phase walls,
-compilation, losses, tokens) and then, last, the verdict and nothing else:
+compile seconds by phase from the program's own counters, losses, tokens)
+and then, last, the verdict and nothing else:
 {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}.
 """
 from __future__ import annotations
@@ -254,40 +255,17 @@ def four_chip_phase(platform: str, one_chip: dict, **size) -> dict:
     return out
 
 
-class CompileLog:
-    """What JAX itself reports about compilation, summed between marks:
-    programs compiled, seconds spent (tracing + lowering + backend compile
-    or cache retrieval), persistent-cache hits and misses."""
+def compile_seconds() -> dict:
+    """The program's own compile seconds so far, by phase, over every span
+    (`jit_compile_seconds_total`, observability/host_spans.py): tracing,
+    lowering, backend compile or cache load, and the loads' part of it."""
+    from paddle_tpu.observability import get_registry
 
-    _DURATIONS = ("/jax/core/compile/jaxpr_trace_duration",
-                  "/jax/core/compile/jaxpr_to_mlir_module_duration",
-                  "/jax/core/compile/backend_compile_duration")
-
-    def __init__(self):
-        import jax
-
-        self.programs = self.hits = self.misses = 0
-        self.seconds = 0.0
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _duration(self, name, secs, **_):
-        if name in self._DURATIONS:
-            self.seconds += secs
-            self.programs += name.endswith("backend_compile_duration")
-
-    def _event(self, name, **_):
-        self.hits += name == "/jax/compilation_cache/cache_hits"
-        self.misses += name == "/jax/compilation_cache/cache_misses"
-
-    def mark(self) -> tuple:
-        return (self.programs, self.seconds, self.hits, self.misses)
-
-    def since(self, mark: tuple) -> dict:
-        return {"programs": self.programs - mark[0],
-                "seconds": round(self.seconds - mark[1], 2),
-                "cache_hits": self.hits - mark[2],
-                "cache_misses": self.misses - mark[3]}
+    out = dict.fromkeys(("trace", "lower", "backend", "cache_load"), 0.0)
+    family = get_registry().get("jit_compile_seconds_total")
+    for labels, child in (family.items() if family is not None else ()):
+        out[labels["phase"]] += child.value
+    return out
 
 
 def main() -> None:
@@ -318,17 +296,17 @@ def main() -> None:
 
     phases = {}
     platform = device["platform"]
-    compiles = CompileLog()
     todo = [("train", lambda: train_phase(platform)),
             ("serve", lambda: serve_phase(platform))]
     if device["count"] >= 4:
         todo.append(("four_chips",
                      lambda: four_chip_phase(platform, phases["train"])))
     for name, fn in todo:
-        t0, mark = time.perf_counter(), compiles.mark()
+        t0, mark = time.perf_counter(), compile_seconds()
         phases[name] = fn()
         phases[name]["wall_s"] = round(time.perf_counter() - t0, 2)
-        phases[name]["compilation"] = compiles.since(mark)
+        phases[name]["compile_s"] = {
+            k: round(v - mark[k], 2) for k, v in compile_seconds().items()}
         print(f"[{name}] {phases[name]}", flush=True)
     if device["count"] < 4:
         phases["four_chips"] = f"skipped: {device['count']} chips"

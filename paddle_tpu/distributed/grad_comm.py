@@ -57,7 +57,7 @@ from ..observability.metrics import get_registry as _get_registry
 # wire-traffic telemetry (ISSUE 3 sweep; ISSUE 8 adds the `path` label):
 # what sync() actually put on the wire, per codec AND per execution path
 # (eager host sync vs inside a compiled step), plus how full the buckets
-# ran — the counters tools/trace_report.py joins against the step-time
+# ran — the counters a /metrics scrape sets against the step-time
 # breakdown's comm row. The path label is the satellite fix: the traced
 # path used to be indistinguishable from (and mis-accounted as) the eager
 # one in /metrics.

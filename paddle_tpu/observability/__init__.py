@@ -15,14 +15,17 @@ tentpole):
   checkpoint commits, NaN trips and watchdog stalls;
   ``FLAGS_enable_rpc_profiler`` additionally streams per-collective events
   into it (the reference's RPC profiler, reinterpreted).
-- ``host_spans`` (host_spans.py): the always-installed span sink — every
-  profiler.RecordEvent end adds to ``host_span_seconds_total{span}`` and
+- ``host_spans`` (host_spans.py): the program's span stream in the
+  registry. The always-installed span sink — every profiler.RecordEvent
+  end adds to ``host_span_seconds_total{span}`` and
   ``host_span_calls_total{span}``, so set-up phases and step spans can be
-  read after the fact (the flight recorder's ring evicts).
+  read after the fact (the flight recorder's ring evicts); the caller's
+  time between top-level spans, ``host_outside_seconds_total{before}``;
+  and JAX's compile events booked to the span that caused them,
+  ``jit_compile_seconds_total{phase, span}``.
 - ``StepTimer`` (step_timer.py): per-step data / forward / backward /
   optimizer / comm / checkpoint breakdown assembled from nested
-  RecordEvent spans; ``breakdown_from_trace`` recomputes it offline from a
-  chrome trace (tools/trace_report.py).
+  RecordEvent spans.
 
 And the distributed plane on top (ISSUE 6 tentpole):
 
@@ -37,7 +40,7 @@ And the distributed plane on top (ISSUE 6 tentpole):
   CollectiveTimeoutError exhaustion, ReplicaGuard).
 - ``memory`` (memory.py): live-tensor bytes on the eager path, XLA
   ``memory_analysis`` peaks keyed by trace-cache entry on the compiled
-  path, compared against the recorded cost-model rooflines.
+  path.
 - ``TelemetryServer`` (exposition.py): stdlib HTTP endpoint per rank —
   /metrics (Prometheus text), /snapshot (rank-0 aggregate), /events,
   /flightrecorder; ``FLAGS_telemetry_http_port`` turns it on job-wide.
@@ -72,7 +75,7 @@ from .metrics import (  # noqa: F401
     DEFAULT_BUCKETS, Counter, Gauge, Histogram, MetricsRegistry, get_registry,
 )
 from .step_timer import (  # noqa: F401
-    PHASES, StepTimer, breakdown_from_trace, format_breakdown, phase_of,
+    PHASES, StepTimer, format_breakdown, phase_of,
 )
 from .tracing import (  # noqa: F401
     Span, TraceContext, TraceStore, Tracer, get_tracer, tracing_enabled,
@@ -83,8 +86,7 @@ __all__ = [
     "DEFAULT_BUCKETS",
     "EventLog", "SEVERITIES", "get_event_log", "set_event_log",
     "add_event_sink", "remove_event_sink",
-    "StepTimer", "PHASES", "phase_of", "breakdown_from_trace",
-    "format_breakdown",
+    "StepTimer", "PHASES", "phase_of", "format_breakdown",
     "rpc_profiler_enabled", "enable_rpc_event_log",
     "MetricsAggregator", "merge_payloads", "merge_typed_snapshots",
     "note_step_time",

@@ -171,8 +171,7 @@ def merge_payloads(payloads: List[dict]) -> dict:
 def aggregated_to_plain(merged_metrics: dict) -> dict:
     """Flatten a merged typed snapshot back to the plain snapshot() shape
     (counters/gauges as values, histograms as stats dicts) so existing
-    consumers — tools/trace_report.py's joins — read an aggregate exactly
-    like a local snapshot. Labelled families keep their {label: value}
+    consumers read an aggregate exactly like a local snapshot. Labelled families keep their {label: value}
     sub-dicts; unlabelled collapse to the bare value."""
     out = {}
     for name, fam in merged_metrics.items():

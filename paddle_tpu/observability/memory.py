@@ -19,18 +19,12 @@ existed only inside one-off AOT probes. This module makes it a metric:
   ``compiled_peak_hbm_bytes{entry=...}`` gauge), so every cached program's
   footprint is inspectable. ``jit.TrainStep.memory_analysis()`` rides
   this.
-- **rooflines** — ``load_rooflines()`` reads the recorded AOT estimates
-  (artifacts/baseline_aot_estimates.json + the bench gpt estimate) and
-  ``roofline_compare()`` reports measured/estimate ratios, the
-  cross-check tools/trace_report.py prints.
 
 Everything degrades to None/{} rather than raising: memory accounting
 must never be the thing that kills a job.
 """
 from __future__ import annotations
 
-import json
-import os
 import threading
 from typing import Dict, Optional
 
@@ -41,7 +35,6 @@ __all__ = [
     "live_tensor_bytes", "device_memory_stats", "sample",
     "LiveBytesWatermark", "sample_watermarks",
     "analyze_compiled", "record_compiled", "compiled_memory",
-    "load_rooflines", "roofline_compare", "memory_report",
 ]
 
 _m_live = get_registry().gauge(
@@ -235,52 +228,3 @@ def compiled_memory() -> Dict[str, dict]:
     """{entry: analysis} of every recorded compiled program."""
     with _compiled_lock:
         return {k: dict(v) for k, v in _compiled.items()}
-
-
-# ---------------------------------------------------------------------------
-# rooflines
-# ---------------------------------------------------------------------------
-
-def _repo_root() -> str:
-    return os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-
-
-def load_rooflines(path: Optional[str] = None) -> Dict[str, int]:
-    """Recorded cost-model peak-HBM estimates, {config_name: bytes}. Reads
-    artifacts/baseline_aot_estimates.json (every entry carrying
-    peak_hbm_bytes); missing file -> {}."""
-    path = path or os.path.join(_repo_root(), "artifacts",
-                                "baseline_aot_estimates.json")
-    try:
-        with open(path) as f:
-            data = json.load(f)
-    except (OSError, json.JSONDecodeError):
-        return {}
-    out = {}
-    for name, rec in data.items():
-        if isinstance(rec, dict) and rec.get("peak_hbm_bytes"):
-            out[name] = int(rec["peak_hbm_bytes"])
-    return out
-
-
-def roofline_compare(measured_bytes: Optional[int],
-                     roofline_bytes: Optional[int],
-                     name: str = "") -> dict:
-    """Measured vs cost-model peak: ratio > 1 means the program uses more
-    HBM than the roofline predicted (fragmentation, un-donated buffers);
-    far below 1 means the estimate is stale."""
-    out = {"name": name, "measured_bytes": measured_bytes,
-           "roofline_bytes": roofline_bytes, "ratio": None}
-    if measured_bytes and roofline_bytes:
-        out["ratio"] = round(measured_bytes / roofline_bytes, 4)
-    return out
-
-
-def memory_report() -> dict:
-    """The whole accounting in one dict (trace_report's memory section)."""
-    return {
-        "sample": sample(),
-        "compiled": compiled_memory(),
-        "rooflines": load_rooflines(),
-    }

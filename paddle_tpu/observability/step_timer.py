@@ -21,18 +21,13 @@ Attribution is by span name (exact phase name, an alias like "fwd", or a
 inside another phase span the overlap is counted in both and `other` is
 clamped at zero; the built-in instrumentation emits phases as siblings, so
 in practice rows add up.
-
-`breakdown_from_trace` computes the same rows offline from an exported
-chrome trace (tools/trace_report.py): spans named "step" delimit windows,
-phase spans inside each window fill the row.
 """
 from __future__ import annotations
 
 import time
 from typing import Dict, List, Optional, Sequence
 
-__all__ = ["StepTimer", "PHASES", "phase_of", "breakdown_from_trace",
-           "format_breakdown"]
+__all__ = ["StepTimer", "PHASES", "phase_of", "format_breakdown"]
 
 PHASES = ("data", "forward", "backward", "optimizer", "comm", "checkpoint")
 
@@ -185,35 +180,3 @@ def format_breakdown(agg: dict, extra: Optional[Dict[str, Dict]] = None) -> str:
                  f"{per_step:>12.2f}"
                  f"{100.0:>8.1f}%  ({agg['steps']} steps)")
     return "\n".join(lines)
-
-
-def breakdown_from_trace(trace: dict, phases: Sequence[str] = PHASES) -> dict:
-    """Recompute per-step rows from an exported chrome trace.
-
-    Spans named "step" (emitted by instrumented training loops) delimit the
-    windows; phase-named spans inside each window fill the row. Without
-    "step" spans the whole trace is one window.
-    """
-    events = trace.get("traceEvents", trace if isinstance(trace, list) else [])
-    spans = [e for e in events if e.get("ph") == "X"]
-    step_spans = sorted((e for e in spans if e.get("name") == "step"),
-                        key=lambda e: e["ts"])
-    if not step_spans:
-        t0 = min((e["ts"] for e in spans), default=0.0)
-        t1 = max((e["ts"] + e.get("dur", 0.0) for e in spans), default=0.0)
-        step_spans = [{"ts": t0, "dur": t1 - t0}]
-    rows = []
-    for s in step_spans:
-        w0, w1 = s["ts"], s["ts"] + s.get("dur", 0.0)
-        row = {ph: 0.0 for ph in phases}
-        for e in spans:
-            ph = phase_of(e.get("name", ""), phases)
-            if ph is None:
-                continue
-            mid = e["ts"] + e.get("dur", 0.0) / 2.0
-            if w0 <= mid <= w1:
-                row[ph] += e.get("dur", 0.0) / 1e6   # chrome ts/dur are us
-        row["total"] = (w1 - w0) / 1e6
-        row["other"] = max(0.0, row["total"] - sum(row[ph] for ph in phases))
-        rows.append(row)
-    return aggregate_rows(rows, phases)

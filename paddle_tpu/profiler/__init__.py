@@ -8,10 +8,17 @@ dispatch hook in call_op (the operator.cc:1264 RecordEvent analog).
 
 Events form a parent-linked span TREE (the HostTracer event-tree analog):
 each RecordEvent carries an id and the id of the enclosing RecordEvent on
-the same thread, so chrome traces and tools/trace_report.py can reconstruct
-nesting instead of guessing from time overlap. Every span end is also
-streamed to registered span sinks (observability.StepTimer subscribes to
-build per-step phase breakdowns), profiler active or not.
+the same thread, so a chrome trace can reconstruct nesting instead of
+guessing from time overlap. Every span end is also streamed to registered
+span sinks (observability.StepTimer subscribes to build per-step phase
+breakdowns), profiler active or not.
+
+The first sink is always installed (observability/host_spans.py): per-span
+totals and the caller's time between top-level spans, in the metrics
+registry. Beside it, installed once at this module's import, a
+``jax.monitoring`` listener books JAX's own compile events to the span open
+when they fire (``innermost_span()``): set-up's compile seconds by the
+phase of the program that caused them.
 
 One span stream, one clock. RecordEvent is the program's one span type: it
 also opens a ``jax.profiler.TraceAnnotation`` named ``pt.<name>``, so any
@@ -30,16 +37,18 @@ import threading
 import time
 from typing import Callable, List, Optional
 
+import jax.monitoring
 from jax.profiler import TraceAnnotation
 
 from ..framework import autograd
-from ..observability.host_spans import on_span as _host_span_totals
+from ..observability import host_spans as _host_spans
+from ..observability.host_spans import innermost_span, open_spans as _stack
 
 __all__ = [
     "Profiler", "RecordEvent", "ProfilerTarget", "ProfilerState",
     "make_scheduler", "export_chrome_tracing", "load_profiler_result",
     "SummaryView", "add_span_sink", "remove_span_sink", "now_ns",
-    "record_span",
+    "record_span", "innermost_span",
 ]
 
 # a RecordEvent named `x` is the host event `pt.x` in a jax.profiler trace
@@ -101,15 +110,16 @@ def _log_profiler_fault(message: str):
     except Exception:   # lint-ok: C003 last-resort guard; event log itself unavailable
         pass
 
-# per-thread stack of open RecordEvent ids — the parent linkage source
-_span_tls = threading.local()
+# the per-thread stack of open RecordEvents, `(id, name)`, is
+# host_spans.open_spans(): the parent linkage source
 _event_ids = itertools.count(1)
 
 # span sinks: called as sink(name, start_ns, end_ns, tid) on EVERY
 # RecordEvent end, whether or not a profiler is recording
 # (observability.StepTimer registers here). The first is always installed:
-# the registry's per-span totals, which outlive the flight recorder's ring.
-_span_sinks: List[Callable] = [_host_span_totals]
+# the registry's per-span totals and gaps, which outlive the flight
+# recorder's ring.
+_span_sinks: List[Callable] = [_host_spans.on_span]
 
 
 def add_span_sink(sink: Callable) -> Callable:
@@ -124,16 +134,29 @@ def remove_span_sink(sink: Callable):
         pass
 
 
-def _stack() -> list:
-    s = getattr(_span_tls, "stack", None)
-    if s is None:
-        s = _span_tls.stack = []
-    return s
-
-
 def _current_span_id() -> Optional[int]:
-    s = getattr(_span_tls, "stack", None)
-    return s[-1] if s else None
+    s = _stack()
+    return s[-1][0] if s else None
+
+
+def _on_compile_start(event, value, **kw):
+    try:
+        _host_spans.on_compile_start(event, value, **kw)
+    except Exception:
+        _log_profiler_fault(f"compile listener failed for {event!r}")
+
+
+def _on_compile_seconds(event, secs, **kw):
+    # JAX calls this inside its own compile: a fault here would fail the
+    # user's jit call, so it is recorded instead (rule C003, as for sinks)
+    try:
+        _host_spans.on_compile_seconds(event, secs, **kw)
+    except Exception:
+        _log_profiler_fault(f"compile listener failed for {event!r}")
+
+
+jax.monitoring.register_scalar_listener(_on_compile_start)
+jax.monitoring.register_event_duration_secs_listener(_on_compile_seconds)
 
 
 def _emit(name, start_ns, end_ns, eid, parent_id):
@@ -177,7 +200,7 @@ class RecordEvent:
     def begin(self):
         self._id = next(_event_ids)
         self._parent_id = _current_span_id()
-        _stack().append(self._id)
+        _stack().append((self._id, self.name))
         self._annotation = TraceAnnotation(TRACE_PREFIX + self.name)
         self._annotation.__enter__()
         self._t0 = now_ns()
@@ -188,10 +211,13 @@ class RecordEvent:
         t1 = now_ns()
         self._annotation.__exit__(None, None, None)
         s = _stack()
-        if s and s[-1] == self._id:
+        if s and s[-1][0] == self._id:
             s.pop()
-        elif self._id in s:        # misnested explicit begin()/end(): unwind
-            del s[s.index(self._id):]
+        else:                      # misnested explicit begin()/end(): unwind
+            for i, (eid, _) in enumerate(s):
+                if eid == self._id:
+                    del s[i:]
+                    break
         _emit(self.name, self._t0, t1, self._id, self._parent_id)
         self._t0 = None
 

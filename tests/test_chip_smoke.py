@@ -92,6 +92,19 @@ def test_last_stdout_line_is_the_verdict_and_nothing_else(
     assert detail["phases"]["train"]["losses"] == [2.0, 1.0]
     assert detail["phases"]["four_chips"] == "skipped: 1 chips"
     assert "[four_chips] skipped: 1 chips" in lines
+    # compile seconds by phase, read off the program's own counters
+    assert set(detail["phases"]["train"]["compile_s"]) == {
+        "trace", "lower", "backend", "cache_load"}
+
+
+def test_compile_seconds_are_the_programs_own_counters():
+    """chip_smoke keeps no jax.monitoring listener of its own: what it
+    reports between two marks is `jit_compile_seconds_total`'s growth."""
+    mark = chip_smoke.compile_seconds()
+    jax.jit(lambda x: x * 59 + 1)(jax.numpy.ones(59))
+    now = chip_smoke.compile_seconds()
+    assert now["trace"] > mark["trace"] and now["backend"] > mark["backend"]
+    assert now["cache_load"] == mark["cache_load"]
 
 
 def test_compile_cache_placed_from_outside(monkeypatch, config_updates):

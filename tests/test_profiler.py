@@ -3,6 +3,7 @@ import json
 import os
 
 import numpy as np
+import pytest
 
 import paddle_tpu as paddle
 import paddle_tpu.nn as nn
@@ -531,3 +532,210 @@ def test_the_lowered_train_step_names_its_layers():
     assert some_path_holds("optimizer")
     assert not some_path_holds("optimizer", "jvp")
     assert not some_path_holds("attn", "mlp")       # siblings, not nested
+
+
+# --------------------------------------------- compile events and gaps (PR 37)
+def _child(family, **labels):
+    from paddle_tpu.observability import get_registry
+
+    fam = get_registry().get(family)
+    for lb, c in (fam.items() if fam is not None else ()):
+        if lb == labels:
+            return c.value
+    return 0.0
+
+
+def _compiled(span, phases=("trace", "lower", "backend", "cache_load")):
+    return {p: _child("jit_compile_seconds_total", phase=p, span=span)
+            for p in phases}
+
+
+def _grew(before, after):
+    return {k: after[k] - before[k] for k in before}
+
+
+def test_innermost_span_names_the_base_of_the_innermost_open_span():
+    assert profiler.innermost_span() is None
+    with RecordEvent("i37outer"):
+        with RecordEvent("i37inner:bucket2"):
+            assert profiler.innermost_span() == "i37inner"
+        assert profiler.innermost_span() == "i37outer"
+    assert profiler.innermost_span() is None
+
+
+def test_a_jit_inside_a_span_books_trace_lower_and_backend_under_it():
+    import jax
+    import jax.numpy as jnp
+
+    before = _compiled("i37x")
+    with RecordEvent("i37x"):
+        jax.jit(lambda x: jnp.sin(x) * 37)(jnp.ones(37))
+    grew = _grew(before, _compiled("i37x"))
+    assert grew["trace"] > 0 and grew["lower"] > 0 and grew["backend"] > 0
+    assert grew["cache_load"] == 0      # no persistent cache in the tests
+
+
+def test_a_compile_with_no_span_open_books_outside_the_program():
+    import jax
+    import jax.numpy as jnp
+
+    outside = "outside the program"
+    before = _compiled(outside, ("trace", "backend"))
+    jax.jit(lambda x: jnp.cos(x) * 41)(jnp.ones(41))
+    grew = _grew(before, _compiled(outside, ("trace", "backend")))
+    assert grew["trace"] > 0 and grew["backend"] > 0
+
+
+def test_a_trace_inside_a_trace_is_booked_once():
+    """Tracing an outer jit traces the inner one (and its primitives) inside
+    it: JAX reports each, and only the outermost is the wall's."""
+    import jax
+    import jax.numpy as jnp
+
+    seen = []
+
+    def listener(event, secs, fun_name=None, **_):
+        if event == "/jax/core/compile/jaxpr_trace_duration":
+            seen.append((fun_name, secs))
+
+    inner = jax.jit(lambda x: jnp.tanh(x) * 43)
+
+    @jax.jit
+    def outer(x):
+        return inner(x) + inner(2 * x)
+
+    x = jnp.ones(43)
+    jax.monitoring.register_event_duration_secs_listener(listener)
+    try:
+        before = _compiled("i37nest", ("trace",))
+        with RecordEvent("i37nest"):
+            outer(x)
+        grew = _grew(before, _compiled("i37nest", ("trace",)))
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listener)
+    assert len(seen) > 1 and seen[-1][0] == "outer"
+    assert grew["trace"] == pytest.approx(seen[-1][1], rel=1e-6)
+    assert seen[-1][1] < sum(s for _, s in seen)
+
+
+def test_a_warm_persistent_cache_books_cache_load(tmp_path):
+    import jax
+    import jax.numpy as jnp
+    from jax._src import compilation_cache
+
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes")
+    old = {k: getattr(jax.config, k) for k in keys}
+    jax.config.update(keys[0], str(tmp_path))
+    jax.config.update(keys[1], 0.0)
+    jax.config.update(keys[2], -1)
+    compilation_cache.reset_cache()
+    try:
+        def f(x):
+            return jnp.exp(x) * 47
+
+        jax.jit(f)(jnp.ones(47))           # compiles and writes the cache
+        jax.clear_caches()
+        before = _compiled("i37warm")
+        with RecordEvent("i37warm"):
+            jax.jit(f)(jnp.ones(47))       # traces, lowers, loads
+        grew = _grew(before, _compiled("i37warm"))
+    finally:
+        for k, v in old.items():
+            jax.config.update(k, v)
+        compilation_cache.reset_cache()
+    assert grew["trace"] > 0 and grew["lower"] > 0
+    assert 0 < grew["cache_load"] <= grew["backend"]
+
+
+def test_train_steps_first_call_books_under_first_call_and_later_none():
+    step, call = _gpt_test_step()
+    first = _compiled("jit_step.first_call")
+    call()
+    grew = _grew(first, _compiled("jit_step.first_call"))
+    assert grew["trace"] > 0 and grew["lower"] > 0 and grew["backend"] > 0
+    dispatch = _compiled("jit_step.dispatch")
+    call()
+    call()
+    assert _compiled("jit_step.dispatch") == dispatch
+
+
+def test_a_later_call_that_compiles_books_under_dispatch_and_is_noted():
+    """A second input shape is a new entry: its first call. An input jax.jit
+    tells apart that the entry's key does not (a weak type) recompiles on a
+    later call: under `jit_step.dispatch`, the flight recorder naming it."""
+    import jax.numpy as jnp
+    from jax._src.lax.lax import _convert_element_type
+
+    from paddle_tpu.observability import get_flight_recorder
+
+    step, call = _gpt_test_step()
+    call()
+    ids = np.random.RandomState(1).randint(0, 100, (2, 17))
+    first = _compiled("jit_step.first_call", ("backend",))
+    step(inputs=(paddle.to_tensor(ids[:, :-1], dtype="int64"),),
+         labels=(paddle.to_tensor(ids[:, 1:], dtype="int64"),))
+    assert _grew(first, _compiled("jit_step.first_call",
+                                  ("backend",)))["backend"] > 0
+
+    weak = _convert_element_type(jnp.asarray(ids[:, :-1]), np.dtype("int32"),
+                                 weak_type=True)
+    rec = get_flight_recorder()
+    noted = len(rec.entries(kind="recompile"))
+    before = _compiled("jit_step.dispatch", ("trace", "backend"))
+    step(inputs=(paddle.Tensor(weak, _internal=True),),
+         labels=(paddle.to_tensor(ids[:, 1:], dtype="int64"),))
+    grew = _grew(before, _compiled("jit_step.dispatch", ("trace", "backend")))
+    assert grew["trace"] > 0 and grew["backend"] > 0
+    new = rec.entries(kind="recompile")[noted:]
+    assert [e["name"] for e in new] == ["jit(pure_step)"]
+    assert new[0]["seconds"] > 0
+
+
+def test_the_gap_before_a_top_level_span_is_booked_to_it_and_none_nested():
+    import time
+
+    def gap(name):
+        return _child("host_outside_seconds_total", before=name)
+
+    with RecordEvent("i37first"):
+        pass
+    time.sleep(0.02)
+    with RecordEvent("i37second"):
+        time.sleep(0.001)
+        with RecordEvent("i37nested"):
+            pass
+        time.sleep(0.005)
+        profiler.record_span("i37recorded", profiler.now_ns(),
+                             profiler.now_ns())
+    assert 0.02 <= gap("i37second") < 0.5
+    assert gap("i37nested") == 0 and gap("i37recorded") == 0
+    # a span that ended before the last top-level one ended books nothing
+    t = profiler.now_ns()
+    with RecordEvent("i37third"):
+        pass
+    profiler.record_span("i37late", t - 10**9, t)
+    assert gap("i37late") == 0
+
+
+def test_a_compile_listener_that_raises_is_recorded_not_propagated(
+        monkeypatch):
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.observability import get_event_log
+    from paddle_tpu.observability import host_spans
+
+    def broken(*a, **k):
+        raise RuntimeError("i37 broken listener")
+
+    log = get_event_log()
+    n = len(log.events(kind="profiler"))
+    monkeypatch.setattr(host_spans, "on_compile_seconds", broken)
+    monkeypatch.setattr(host_spans, "on_compile_start", broken)
+    out = jax.jit(lambda x: x * 53)(jnp.ones(53))    # still compiles, runs
+    assert float(out[0]) == 53.0
+    faults = [r for r in log.events(kind="profiler")[n:]
+              if "compile listener failed" in r.get("message", "")]
+    assert faults and "i37 broken listener" in faults[0]["error"]
