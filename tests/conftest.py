@@ -6,6 +6,8 @@ stands in for an 8-chip TPU slice. chip_smoke.py and benchmark/run.py use the
 real chip.
 """
 import os
+import shutil
+import tempfile
 import threading
 
 os.environ["XLA_FLAGS"] = (
@@ -72,6 +74,59 @@ def pytest_collection_modifyitems(config, items):
     for item in items:
         if "requires_cpu_multiprocess" in item.keywords:
             item.add_marker(skip)
+
+
+# -- one persistent compile cache a run, for the modules that opt in -------
+# A module marked `pytestmark = pytest.mark.shared_compile_cache` compiles
+# through a persistent XLA cache for its duration: the programs its cases
+# compile alike (a reference comparison's pieces, from one planted fault to
+# the next) are compiled once a run. The run's first process makes the
+# directory and hands it to its xdist workers in the environment; it
+# removes it at the end, so no later run reads an entry. `--dist loadfile`
+# runs a module whole on one worker, and the fixture restores the settings
+# it found, so a module without the mark never reads the cache.
+_COMPILE_CACHE_ENV = "PADDLE_TPU_TEST_COMPILE_CACHE"
+_COMPILE_CACHE_KEYS = ("jax_compilation_cache_dir",
+                       "jax_persistent_cache_min_compile_time_secs",
+                       "jax_persistent_cache_min_entry_size_bytes")
+# a program that compiles in less is compiled again, not written: writing
+# every small one costs more than it saves (of 0, 0.1, 0.3 and 1 s, 0.3 s
+# gave the marked modules the least time)
+_COMPILE_CACHE_MIN_SECS = 0.3
+_MADE_COMPILE_CACHE = None
+
+
+def pytest_configure(config):
+    global _MADE_COMPILE_CACHE
+    if _COMPILE_CACHE_ENV not in os.environ:
+        _MADE_COMPILE_CACHE = tempfile.mkdtemp(prefix="jax-compile-cache-")
+        os.environ[_COMPILE_CACHE_ENV] = _MADE_COMPILE_CACHE
+
+
+def pytest_unconfigure(config):
+    if _MADE_COMPILE_CACHE is not None:
+        shutil.rmtree(_MADE_COMPILE_CACHE, ignore_errors=True)
+        os.environ.pop(_COMPILE_CACHE_ENV, None)
+
+
+@pytest.fixture(autouse=True, scope="module")
+def _shared_compile_cache(request):
+    if request.node.get_closest_marker("shared_compile_cache") is None:
+        yield
+        return
+    from jax._src import compilation_cache
+
+    old = {k: getattr(jax.config, k) for k in _COMPILE_CACHE_KEYS}
+    for key, value in zip(_COMPILE_CACHE_KEYS, (
+            os.environ[_COMPILE_CACHE_ENV], _COMPILE_CACHE_MIN_SECS, -1)):
+        jax.config.update(key, value)
+    compilation_cache.reset_cache()
+    try:
+        yield
+    finally:
+        for key, value in old.items():
+            jax.config.update(key, value)
+        compilation_cache.reset_cache()
 
 
 @pytest.fixture

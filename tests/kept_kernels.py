@@ -15,7 +15,8 @@ from paddle_tpu.jit.functional import FunctionalModule
 
 def pallas_calls(fn, *args) -> list:
     """The names of the Pallas calls in fn's jaxpr at `args`, those inside
-    sub-jaxprs (a checkpoint, a scan) included, sorted."""
+    sub-jaxprs (a jitted function's `pjit`, a checkpoint, a scan) included,
+    sorted."""
     names = []
 
     def walk(jaxpr):
@@ -50,7 +51,8 @@ def layer_fn(layer):
 
 def layer_grads(layer, x, seed=1):
     """(the gradient function of sum(layer(x) * cot) by x and the
-    parameters, its arguments), cot drawn from `seed`."""
+    parameters, jitted: one trace and one compile, its arguments), cot
+    drawn from `seed`."""
     f, pvals = layer_fn(layer)
     out = jax.eval_shape(f, x, pvals)
     cot = jax.random.normal(jax.random.PRNGKey(seed), out.shape, jnp.float32)
@@ -58,7 +60,7 @@ def layer_grads(layer, x, seed=1):
     def loss(xv, pv):
         return jnp.sum(f(xv, pv).astype(jnp.float32) * cot)
 
-    return jax.grad(loss, argnums=(0, 1)), (x, pvals)
+    return jax.jit(jax.grad(loss, argnums=(0, 1))), (x, pvals)
 
 
 def made_residuals(layer, x) -> list:

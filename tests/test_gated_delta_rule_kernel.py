@@ -28,8 +28,8 @@ def _value_and_grads(over_chunks, args, cot):
         return jnp.sum(o.astype(jnp.float32) * cot), o
 
     with jax.default_matmul_precision("highest"):
-        (_, o), grads = jax.value_and_grad(
-            weighed, argnums=(0, 1, 2, 3, 4), has_aux=True)(*args)
+        (_, o), grads = jax.jit(jax.value_and_grad(
+            weighed, argnums=(0, 1, 2, 3, 4), has_aux=True))(*args)
     return o.astype(jnp.float32), [t.astype(jnp.float32) for t in grads]
 
 
@@ -146,9 +146,9 @@ def test_a_bf16_state_on_the_kernels_path_is_seen_by_the_rule_alone(
     got_o, got = _value_and_grads(rule.kernels_over_chunks, args, cot)
 
     with jax.default_matmul_precision("highest"):
-        (_, want_o), want = jax.value_and_grad(
+        (_, want_o), want = jax.jit(jax.value_and_grad(
             lambda *a: _recurrence(*a, cot), argnums=(0, 1, 2, 3, 4),
-            has_aux=True)(*args)
+            has_aux=True))(*args)
     worst = max([_rel(got_o[0], want_o)]
                 + [_rel(a, b) for a, b in zip(got, want)])
     limit = 4e-5                # benchmark/program_gdn_moe.py, part (d)

@@ -24,6 +24,10 @@ from paddle_tpu.ops.flash_attention import (_default_block,
                                             flash_attention_supported,
                                             flash_attention_val)
 
+# the reference comparisons' programs, alike from one case to the next,
+# compile once a run (tests/conftest.py)
+pytestmark = pytest.mark.shared_compile_cache
+
 # The tests' size. initializer_range 0.1: sqrt(h) * std ~ 0.8 as at the
 # published widths (sqrt(2048) * 0.02 = 0.9), so that the blocks move the
 # residual stream as they do there and a wrong block shows in the logits
@@ -120,11 +124,11 @@ def test_the_chunked_rule_equals_the_recurrence_forward_and_gradients(case):
         return jnp.sum(o * cot), o
 
     with jax.default_matmul_precision("highest"):
-        (_, got_o), got = jax.value_and_grad(
-            chunked, argnums=(0, 1, 2, 3, 4), has_aux=True)(*args)
-        (_, want_o), want = jax.value_and_grad(
+        (_, got_o), got = jax.jit(jax.value_and_grad(
+            chunked, argnums=(0, 1, 2, 3, 4), has_aux=True))(*args)
+        (_, want_o), want = jax.jit(jax.value_and_grad(
             lambda *a: _recurrence(*a, cot), argnums=(0, 1, 2, 3, 4),
-            has_aux=True)(*args)
+            has_aux=True))(*args)
     assert got_o.shape == (1, s, 4, 8)
     np.testing.assert_allclose(got_o[0], want_o, atol=2e-6)
     for name, a, b in zip("q k v g beta".split(), got, want):
@@ -163,9 +167,10 @@ def test_unit_lower_inverse_and_its_gradient():
         want = np.linalg.inv(np.eye(c) - np.asarray(a, np.float64))
         np.testing.assert_allclose(t, want, atol=1e-5)
         w = jnp.asarray(np.random.RandomState(0).randn(2, c, c), jnp.float32)
-        got = jax.grad(lambda m: jnp.sum(rule.unit_lower_inverse(m) * w))(a)
-        plain = jax.grad(lambda m: jnp.sum(jnp.linalg.inv(
-            jnp.eye(c) - m) * w))(a)
+        got = jax.jit(jax.grad(lambda m: jnp.sum(
+            rule.unit_lower_inverse(m) * w)))(a)
+        plain = jax.jit(jax.grad(lambda m: jnp.sum(jnp.linalg.inv(
+            jnp.eye(c) - m) * w)))(a)
     np.testing.assert_allclose(got, plain, atol=2e-4, rtol=2e-4)
 
 
@@ -203,7 +208,7 @@ def test_gradients_agree_with_jax_grad_of_the_reference_loss():
         return crit(paddle.Tensor(out, _internal=True),
                     paddle.to_tensor(y, dtype="int64"))._value
 
-    got = dict(zip(fm.param_names, jax.grad(program_loss)(
+    got = dict(zip(fm.param_names, jax.jit(jax.grad(program_loss))(
         fm.param_values())))
     top, get_layer = adapter.reference_weights(model)
     layers = [get_layer(i) for i in range(cfg.num_hidden_layers)]
@@ -212,7 +217,7 @@ def test_gradients_agree_with_jax_grad_of_the_reference_loss():
         out = ref.forward(x, w[0], lambda i: w[1][i], _ref_cfg(cfg))
         return ref.next_token_loss(out["logits"], y)
 
-    g_top, g_layers = jax.grad(ref_loss)((top, layers))
+    g_top, g_layers = jax.jit(jax.grad(ref_loss))((top, layers))
     want = {"model.embed_tokens": g_top["embed_tokens"],
             "model.final_norm_w": g_top["norm"],
             "model.lm_head_w": g_top["lm_head"]}
@@ -321,17 +326,23 @@ def test_each_mixer_against_the_reference(kind):
     _, get_layer = adapter.reference_weights(model)
     pr = {k: jnp.asarray(v) for k, v in get_layer(i).items()}
     x = jnp.asarray(np.random.RandomState(2).randn(2, 80, 64), jnp.float32)
-    with jax.default_matmul_precision("highest"):
+
+    def program(x, p):
         if kind == "gated_attention":
-            got = gdn_moe.gated_attention(x, p, cfg)
-            want = jnp.stack([ref.attention(x[j], pr, _ref_cfg(cfg), 16)
+            return gdn_moe.gated_attention(x, p, cfg)
+        return gdn_moe.gated_delta_net(x, p, cfg)
+
+    def reference(x, pr):
+        if kind == "gated_attention":
+            return jnp.stack([ref.attention(x[j], pr, _ref_cfg(cfg), 16)
                               for j in range(2)])
-        else:
-            got = gdn_moe.gated_delta_net(x, p, cfg)
-            normed = ref.rms_norm(x, pr["input_layernorm"], cfg.rms_norm_eps)
-            want = jnp.stack([ref.gated_delta_net(normed[j], pr,
-                                                  _ref_cfg(cfg))
-                              for j in range(2)])
+        normed = ref.rms_norm(x, pr["input_layernorm"], cfg.rms_norm_eps)
+        return jnp.stack([ref.gated_delta_net(normed[j], pr, _ref_cfg(cfg))
+                          for j in range(2)])
+
+    with jax.default_matmul_precision("highest"):
+        got = jax.jit(program)(x, p)
+        want = jax.jit(reference)(x, pr)
     assert float(jnp.std(want)) > 0.05
     np.testing.assert_allclose(got, want, atol=2e-5)
 
@@ -358,8 +369,9 @@ def _attention_pair(n, n_kv, blocks=(16, 32), s=64, d=32, d_v=16):
         return jnp.sum(o * w), o
 
     with jax.default_matmul_precision("highest"):
-        return [jax.value_and_grad(f, argnums=(0, 1, 2), has_aux=True)(
-            q, k, v) for f in (flash, einsum, repeated)]
+        return [jax.jit(jax.value_and_grad(f, argnums=(0, 1, 2),
+                                           has_aux=True))(q, k, v)
+                for f in (flash, einsum, repeated)]
 
 
 @pytest.mark.parametrize("heads", [(4, 4), (8, 2), (8, 1), (6, 3)])
@@ -488,8 +500,12 @@ def test_the_two_head_slices_add_up_to_the_uncut_attention_layer():
     full = {"q_proj": p["q_w"], "k_proj": p["k_w"], "v_proj": p["v_w"],
             "o_proj": p["o_w"], "q_norm": p["q_norm_w"],
             "k_norm": p["k_norm_w"]}
+    one_slice = _cfg(num_attention_heads=group, num_key_value_heads=1)
     with jax.default_matmul_precision("highest"):
-        whole = ref.attention(x[0], full, _ref_cfg(cfg), 16)
+        whole = jax.jit(lambda x, full: ref.attention(
+            x[0], full, _ref_cfg(cfg), 16))(x, full)
+        program = jax.jit(lambda x, mine: gdn_moe.gated_attention(
+            x, mine, one_slice)[0])
         parts = []
         for j in range(n_kv):
             q_cols = slice(j * group * 2 * d, (j + 1) * group * 2 * d)
@@ -497,9 +513,7 @@ def test_the_two_head_slices_add_up_to_the_uncut_attention_layer():
             mine = dict(p, q_w=p["q_w"][:, q_cols], k_w=p["k_w"][:, kv_cols],
                         v_w=p["v_w"][:, kv_cols],
                         o_w=p["o_w"][j * group * d:(j + 1) * group * d])
-            parts.append(gdn_moe.gated_attention(
-                x, mine, _cfg(num_attention_heads=group,
-                              num_key_value_heads=1))[0])
+            parts.append(program(x, mine))
     np.testing.assert_allclose(sum(parts), whole, atol=2e-5)
     for part in parts:                              # each slice counts
         assert float(jnp.std(part)) > 0.3 * float(jnp.std(whole))

@@ -13,6 +13,10 @@ from paddle_tpu.ops import gated_delta_rule as rule
 
 from test_gdn_moe import _cfg, _compare, _ids, _model
 
+# the reference comparisons' programs, alike from one case to the next,
+# compile once a run (tests/conftest.py)
+pytestmark = pytest.mark.shared_compile_cache
+
 
 def _softmax_mutant(dtype=jnp.float32, renormalise=True, sigmoid=False):
     def route(x2, router_w, top_k):

@@ -27,6 +27,10 @@ from paddle_tpu.ops.flash_attention import (_default_block,
                                             flash_attention_val,
                                             flash_block_choice)
 
+# the reference comparisons' programs, alike from one case to the next,
+# compile once a run (tests/conftest.py)
+pytestmark = pytest.mark.shared_compile_cache
+
 # The tests' size: the cell's pattern (one dense conv layer, then a period
 # of attention and three convs), 8 query heads over 2 (the published group
 # of 4). initializer_range 0.1: sqrt(h) * std ~ 0.8 as at the published
@@ -130,9 +134,9 @@ def _reference_gradients(model, cfg, x, y, split_table=False):
         return ref.next_token_loss(h @ jnp.asarray(head).T, y)
 
     if split_table:
-        return jax.grad(two_tables, argnums=(0, 1))(
+        return jax.jit(jax.grad(two_tables, argnums=(0, 1)))(
             top["embed_tokens"], top["embed_tokens"])
-    g_top, g_layers = jax.grad(ref_loss)((top, layers))
+    g_top, g_layers = jax.jit(jax.grad(ref_loss))((top, layers))
     want = {"model.embed_tokens": g_top["embed_tokens"],
             "model.final_norm_w": g_top["embedding_norm"]}
     for i, g in enumerate(g_layers):
@@ -151,7 +155,7 @@ def _program_gradients(model, x, y):
         return crit(paddle.Tensor(out, _internal=True),
                     paddle.to_tensor(y, dtype="int64"))._value
 
-    return dict(zip(fm.param_names, jax.grad(program_loss)(
+    return dict(zip(fm.param_names, jax.jit(jax.grad(program_loss))(
         fm.param_values())))
 
 
@@ -286,14 +290,21 @@ def test_each_mixer_against_the_reference(kind):
     _, get_layer = adapter.reference_weights(model)
     pr = {k: jnp.asarray(v) for k, v in get_layer(i).items()}
     x = jnp.asarray(np.random.RandomState(2).randn(2, 80, 64), jnp.float32)
-    with jax.default_matmul_precision("highest"):
+
+    def program(x, p):
         if kind == "full_attention":
-            got = lfm2_moe.qk_norm_attention(x, p, cfg)
-            want = jnp.stack([ref.attention(x[j], pr, _ref_cfg(cfg), 16)
+            return lfm2_moe.qk_norm_attention(x, p, cfg)
+        return lfm2_moe.short_conv(x, p)
+
+    def reference(x, pr):
+        if kind == "full_attention":
+            return jnp.stack([ref.attention(x[j], pr, _ref_cfg(cfg), 16)
                               for j in range(2)])
-        else:
-            got = lfm2_moe.short_conv(x, p)
-            want = jnp.stack([ref.short_conv(x[j], pr) for j in range(2)])
+        return jnp.stack([ref.short_conv(x[j], pr) for j in range(2)])
+
+    with jax.default_matmul_precision("highest"):
+        got = jax.jit(program)(x, p)
+        want = jax.jit(reference)(x, pr)
     assert float(jnp.std(want)) > 0.05
     np.testing.assert_allclose(got, want, atol=2e-5)
 
@@ -334,8 +345,9 @@ def test_query_head_h_reads_key_value_head_h_over_4():
             q, jnp.repeat(k, 4, 2), jnp.repeat(v, 4, 2), False) * w)
 
     with jax.default_matmul_precision("highest"):
-        got, want, plain = [jax.value_and_grad(f, argnums=(0, 1, 2))(q, k, v)
-                            for f in (flash, fallback, repeated)]
+        got, want, plain = [
+            jax.jit(jax.value_and_grad(f, argnums=(0, 1, 2)))(q, k, v)
+            for f in (flash, fallback, repeated)]
     for other in (want, plain):
         assert float(got[0]) == pytest.approx(float(other[0]), abs=1e-3)
         for a, b in zip(got[1], other[1]):
@@ -397,9 +409,12 @@ def test_the_four_shares_add_up_to_the_uncut_layer():
                 getattr(share.moe, n).set_value(np.asarray(
                     getattr(whole.moe, n)._value)[held[0]:held[1]])
             share.eval()
-            parts.append(share(paddle.Tensor(x, _internal=True))._value
-                         - alike)
-        want, _ = ref.block(x, full, _ref_cfg(whole_cfg), "conv", False)
+            fm = FunctionalModule(share)
+            out, _ = jax.jit(fm.call)(fm.param_values(), fm.buffer_values(),
+                                      jax.random.PRNGKey(0), (x,))
+            parts.append(out - alike)
+        want, _ = jax.jit(lambda x, full: ref.block(
+            x, full, _ref_cfg(whole_cfg), "conv", False))(x, full)
     np.testing.assert_allclose(sum(parts) + alike, want, atol=1e-4)
     for part in parts:                       # every share's experts count
         assert float(jnp.std(part)) > 0.01

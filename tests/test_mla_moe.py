@@ -23,6 +23,10 @@ from paddle_tpu.models import (GPTPretrainingCriterion, MlaMoeConfig,
 from paddle_tpu.ops.flash_attention import (_default_block,
                                             flash_attention_val)
 
+# the reference comparisons' programs, alike from one case to the next,
+# compile once a run (tests/conftest.py)
+pytestmark = pytest.mark.shared_compile_cache
+
 # The tests' size. initializer_range 0.1: sqrt(h) * std ~ 0.8 as at the
 # published widths (sqrt(2048) * 0.02 = 0.9), so that the blocks move the
 # residual stream as they do there and a wrong block shows in the logits
@@ -101,7 +105,7 @@ def test_gradients_agree_with_jax_grad_of_the_reference_loss():
         return crit(paddle.Tensor(out, _internal=True),
                     paddle.to_tensor(y, dtype="int64"))._value
 
-    got = dict(zip(fm.param_names, jax.grad(program_loss)(
+    got = dict(zip(fm.param_names, jax.jit(jax.grad(program_loss))(
         fm.param_values())))
     top, get_layer = adapter.reference_weights(model)
     layers = [get_layer(i) for i in range(cfg.num_hidden_layers)]
@@ -110,7 +114,7 @@ def test_gradients_agree_with_jax_grad_of_the_reference_loss():
         out = ref.forward(x, w[0], lambda i: w[1][i], _ref_cfg(cfg))
         return ref.next_token_loss(out["logits"], y)
 
-    g_top, g_layers = jax.grad(ref_loss)((top, layers))
+    g_top, g_layers = jax.jit(jax.grad(ref_loss))((top, layers))
     want = {"model.embed_tokens": g_top["embed_tokens"],
             "model.final_norm_w": g_top["norm"],
             "model.lm_head_w": g_top["lm_head"]}
@@ -434,10 +438,10 @@ def test_the_poison_is_felt_where_the_tail_is_multiplied_away(name,
                        ** 2)
 
     argnums = tuple(range(1 + len(vals)))
-    want = jax.grad(loss, argnums)(x2, *vals)
+    want = jax.jit(jax.grad(loss, argnums))(x2, *vals)
     monkeypatch.setattr(jax.lax, "ragged_dot",
                         _ragged_dot_that_leaves_the_tail_unwritten())
-    got = jax.grad(loss, argnums)(x2, *vals)
+    got = jax.jit(jax.grad(loss, argnums))(x2, *vals)
     for name_, g, w in zip(("x2",) + layer.names, got, want):
         assert np.all(np.isfinite(g)), name_
         np.testing.assert_allclose(g, w, atol=1e-5, err_msg=name_)
@@ -593,10 +597,10 @@ def test_latent_attention_against_plain_einsum_code():
                         jnp.float32)
          for n, s in mla_moe._attn_shapes(cfg).items()}
     x = jnp.asarray(r.randn(2, 12, cfg.hidden_size), jnp.float32)
-    got = mla_moe.latent_attention(x, p, cfg)
+    got = jax.jit(lambda x, p: mla_moe.latent_attention(x, p, cfg))(x, p)
     rp = {adapter._LAYER_NAMES[n]: v for n, v in p.items()}
-    want = jnp.stack([ref.attention(x[j], rp, _ref_cfg(cfg), 4)
-                      for j in range(2)])
+    want = jax.jit(lambda x, rp: jnp.stack([
+        ref.attention(x[j], rp, _ref_cfg(cfg), 4) for j in range(2)]))(x, rp)
     np.testing.assert_allclose(got, want, atol=2e-5)
 
 
@@ -614,12 +618,19 @@ def test_flash_kernel_with_two_widths_forward_and_three_gradients(blocks):
             for _ in range(2))
     v = jnp.asarray(r.randn(2, 64, 2, 16), jnp.float32)
     w = jnp.asarray(r.randn(2, 64, 2, 16), jnp.float32)
-    out, vjp = jax.vjp(lambda *a: flash_attention_val(
-        *a, block_q=blocks[0], block_k=blocks[1]), q, k, v)
-    want, vjp_w = jax.vjp(_plain_attention, q, k, v)
+
+    def out_and_vjp(f):
+        def run(q, k, v, w):
+            out, vjp = jax.vjp(f, q, k, v)
+            return out, vjp(w)
+        return jax.jit(run)(q, k, v, w)
+
+    out, grads = out_and_vjp(lambda *a: flash_attention_val(
+        *a, block_q=blocks[0], block_k=blocks[1]))
+    want, grads_w = out_and_vjp(_plain_attention)
     assert out.shape == v.shape
     np.testing.assert_allclose(out, want, atol=2e-5)
-    for g, gw, like in zip(vjp(w), vjp_w(w), (q, k, v)):
+    for g, gw, like in zip(grads, grads_w, (q, k, v)):
         assert g.shape == like.shape
         np.testing.assert_allclose(g, gw, atol=5e-5)
 

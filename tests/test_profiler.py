@@ -649,6 +649,19 @@ def test_a_warm_persistent_cache_books_cache_load(tmp_path):
     assert 0 < grew["cache_load"] <= grew["backend"]
 
 
+def test_a_module_without_the_cache_mark_compiles_with_no_persistent_cache(
+        request):
+    """The run's shared compile cache (tests/conftest.py) is a marked
+    module's alone: whatever module ran before on this worker, this one
+    starts with none, so the `cache_load == 0` of
+    test_a_jit_inside_a_span_books_trace_lower_and_backend_under_it holds
+    by construction."""
+    import jax
+
+    assert request.node.get_closest_marker("shared_compile_cache") is None
+    assert not jax.config.jax_compilation_cache_dir
+
+
 def test_train_steps_first_call_books_under_first_call_and_later_none():
     step, call = _gpt_test_step()
     first = _compiled("jit_step.first_call")
